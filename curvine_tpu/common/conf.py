@@ -9,10 +9,7 @@ import dataclasses
 import logging
 import os
 
-try:
-    import tomllib
-except ModuleNotFoundError:          # Python < 3.11
-    import tomli as tomllib
+import tomllib
 from dataclasses import dataclass, field
 
 MB = 1024 * 1024

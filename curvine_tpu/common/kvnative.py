@@ -13,15 +13,11 @@ from __future__ import annotations
 import ctypes
 import logging
 import os
-import subprocess
 
 import msgpack
 
 log = logging.getLogger(__name__)
 
-_CSRC = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__)))), "csrc")
-_SO = os.path.join(_CSRC, "build", "libcurvine_kv.so")
 _lib = None
 _tried = False
 
@@ -33,27 +29,12 @@ def _load():
     if _tried:
         return _lib
     _tried = True
-    if not os.path.exists(_SO) and os.path.exists(
-            os.path.join(_CSRC, "Makefile")):
-        # dev convenience only (deploy images prebuild csrc); an
-        # exclusive lock keeps concurrent processes from interleaving
-        # writes into the shared build/ directory
-        try:
-            import fcntl
-            os.makedirs(os.path.join(_CSRC, "build"), exist_ok=True)
-            with open(os.path.join(_CSRC, "build", ".kvbuild.lock"),
-                      "w") as lf:
-                fcntl.flock(lf, fcntl.LOCK_EX)
-                if not os.path.exists(_SO):    # re-check under the lock
-                    subprocess.run(
-                        ["make", "-C", _CSRC, "build/libcurvine_kv.so"],
-                        capture_output=True, timeout=120, check=True)
-        except Exception as e:  # noqa: BLE001 — fall back to pure Python
-            log.debug("native kv build failed: %s", e)
-    if not os.path.exists(_SO):
+    from curvine_tpu.common import native
+    so = native.build("libcurvine_kv.so")
+    if so is None:
         return None
     try:
-        lib = ctypes.CDLL(_SO)
+        lib = ctypes.CDLL(so)
         lib.kv_errmsg.restype = ctypes.c_char_p
         lib.kv_open.restype = ctypes.c_void_p
         lib.kv_open.argtypes = [ctypes.c_char_p, ctypes.c_int,

@@ -1,13 +1,18 @@
-"""ctypes bindings for the native C++ helpers (csrc/native.cc).
+"""ctypes bindings for the native C++ helpers (csrc/native.cc), and the
+one place the four native libraries get built.
 
-The .so is built on demand (make in csrc/); every function has a pure-
-Python fallback so nothing hard-depends on a compiler at runtime."""
+Each .so is built on demand (make in csrc/); every function here has a
+pure-Python fallback so nothing hard-depends on a compiler at runtime.
+A failed build is logged at WARNING — callers that must not run on the
+fallback (chip_smoke.py) check `build()` / `available()` and raise."""
 
 from __future__ import annotations
 
 import ctypes
+import fcntl
 import logging
 import os
+import shutil
 import subprocess
 import zlib
 
@@ -15,20 +20,37 @@ log = logging.getLogger(__name__)
 
 _CSRC = os.path.join(os.path.dirname(os.path.dirname(
     os.path.dirname(os.path.abspath(__file__)))), "csrc")
-_SO = os.path.join(_CSRC, "build", "libcurvine_native.so")
+_BUILD = os.path.join(_CSRC, "build")
 
 _lib = None
 _tried = False
 
 
-def _stale() -> bool:
-    """A prebuilt .so older than its source misses newly added symbols
-    (which would silently disable whole native paths) — rebuild it."""
-    try:
-        src = os.path.join(_CSRC, "native.cc")
-        return os.path.getmtime(_SO) < os.path.getmtime(src)
-    except OSError:
-        return False
+def build(so_name: str) -> str | None:
+    """Path of csrc/build/<so_name>, brought up to date with `make`
+    first (make compares the .so with its sources, so a stale prebuilt
+    library that lacks newer symbols is rebuilt, and a current one costs
+    one no-op make). None when it is absent and cannot be built.
+    CURVINE_NO_AUTOBUILD=1 (deploy images ship prebuilt libraries) only
+    looks. Concurrent processes serialise on a lock file so they never
+    interleave writes into the shared build directory."""
+    so = os.path.join(_BUILD, so_name)
+    if (os.environ.get("CURVINE_NO_AUTOBUILD") != "1"
+            and shutil.which("make")
+            and os.path.exists(os.path.join(_CSRC, "Makefile"))):
+        try:
+            os.makedirs(_BUILD, exist_ok=True)
+            with open(os.path.join(_BUILD, ".build.lock"), "w") as lf:
+                fcntl.flock(lf, fcntl.LOCK_EX)
+                subprocess.run(["make", "-C", _CSRC, f"build/{so_name}"],
+                               capture_output=True, timeout=300,
+                               check=True)
+        except subprocess.CalledProcessError as e:
+            log.warning("native build of %s failed: %s", so_name,
+                        e.stderr.decode(errors="replace")[-2000:])
+        except (OSError, subprocess.TimeoutExpired) as e:
+            log.warning("native build of %s failed: %s", so_name, e)
+    return so if os.path.exists(so) else None
 
 
 def _load():
@@ -36,16 +58,10 @@ def _load():
     if _tried:
         return _lib
     _tried = True
-    if (not os.path.exists(_SO) or _stale()) and os.path.exists(
-            os.path.join(_CSRC, "Makefile")):
+    so = build("libcurvine_native.so")
+    if so is not None:
         try:
-            subprocess.run(["make", "-C", _CSRC], capture_output=True,
-                           timeout=120, check=True)
-        except Exception as e:  # noqa: BLE001 — fall back to pure Python
-            log.debug("native build failed: %s", e)
-    if os.path.exists(_SO):
-        try:
-            lib = ctypes.CDLL(_SO)
+            lib = ctypes.CDLL(so)
             lib.cv_crc32c.restype = ctypes.c_uint32
             lib.cv_crc32c.argtypes = [ctypes.c_char_p, ctypes.c_size_t,
                                       ctypes.c_uint32]
@@ -73,7 +89,7 @@ def _load():
             except AttributeError:
                 lib._has_gf = False
             _lib = lib
-            log.info("native helpers loaded: %s", _SO)
+            log.info("native helpers loaded: %s", so)
         except OSError as e:
             log.warning("native load failed: %s", e)
     return _lib

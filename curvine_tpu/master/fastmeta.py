@@ -25,17 +25,11 @@ from __future__ import annotations
 
 import ctypes
 import logging
-import os
-import shutil
-import subprocess
 
 import msgpack
 
 log = logging.getLogger(__name__)
 
-_CSRC = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__)))), "csrc")
-_SO = os.path.join(_CSRC, "build", "libcurvine_meta.so")
 _lib = None
 _tried = False
 
@@ -48,21 +42,11 @@ def _load():
     if _tried:
         return _lib
     _tried = True
-    # auto-build keeps dev/test friction at zero; production deploys ship
-    # the prebuilt .so (or set CURVINE_NO_AUTOBUILD=1) so master startup
-    # never waits on a compiler
-    if (not os.path.exists(_SO)
-            and os.environ.get("CURVINE_NO_AUTOBUILD") != "1"
-            and shutil.which("g++")
-            and os.path.exists(os.path.join(_CSRC, "Makefile"))):
-        try:
-            subprocess.run(["make", "-C", _CSRC], capture_output=True,
-                           timeout=120, check=True)
-        except Exception as e:  # noqa: BLE001 — stay gracefully absent
-            log.debug("meta mirror build failed: %s", e)
-    if not os.path.exists(_SO):
+    from curvine_tpu.common import native
+    so = native.build("libcurvine_meta.so")
+    if so is None:
         return None
-    lib = ctypes.CDLL(_SO)
+    lib = ctypes.CDLL(so)
     lib.mm_new.restype = ctypes.c_void_p
     lib.mm_new.argtypes = [ctypes.c_int, ctypes.c_char_p, ctypes.c_char_p]
     lib.mm_free.argtypes = [ctypes.c_void_p]

@@ -192,14 +192,22 @@ class Connection:
         self.closed = True
         if self._writer is not None:
             await self._writer.aclose()
-        if self._reader_task:
-            self._reader_task.cancel()
-        if self._sock is not None:
-            try:
-                self._sock.close()
-            except OSError:
-                pass
-            self._sock = None
+        task, self._reader_task = self._reader_task, None
+        try:
+            if task is not None and task is not asyncio.current_task():
+                # the cancelled read loop must have left the selector
+                # BEFORE the fd closes: a reconnect in the same tick can
+                # be handed the same fd number, and the stale reader
+                # registration then kills its sock_connect with ENOENT
+                task.cancel()
+                await asyncio.wait([task])
+        finally:
+            if self._sock is not None:
+                try:
+                    self._sock.close()
+                except OSError:
+                    pass
+                self._sock = None
 
     # ---------------- send plumbing ----------------
 

@@ -15,13 +15,13 @@ from curvine_tpu.common.conf import ClusterConf
 from curvine_tpu.common.types import FileStatus, SetAttrOpts
 
 
-class _LoopThread:
+class LoopThread:
     """One shared asyncio loop running on a daemon thread."""
 
-    def __init__(self) -> None:
+    def __init__(self, name: str = "curvine-sdk") -> None:
         self.loop = asyncio.new_event_loop()
         self.thread = threading.Thread(target=self.loop.run_forever,
-                                       daemon=True, name="curvine-sdk")
+                                       daemon=True, name=name)
         self.thread.start()
 
     def run(self, coro, timeout: float | None = 120) -> Any:
@@ -36,7 +36,7 @@ class CurvineFile:
     """File-like object (binary). Mode 'rb' wraps FsReader (seekable);
     'wb'/'ab' wrap FsWriter (sequential)."""
 
-    def __init__(self, lt: _LoopThread, inner, mode: str):
+    def __init__(self, lt: LoopThread, inner, mode: str):
         self._lt = lt
         self._inner = inner
         self.mode = mode
@@ -92,7 +92,7 @@ class CurvineFileSystem:
         self.conf = conf or ClusterConf.load(conf_path)
         if master:
             self.conf.client.master_addrs = [master]
-        self._lt = _LoopThread()
+        self._lt = LoopThread()
         from curvine_tpu.client import CurvineClient
 
         async def make():

@@ -12,16 +12,11 @@ from __future__ import annotations
 import ctypes
 import json
 import logging
-import os
-import subprocess
 
 from curvine_tpu.common import errors as err
 
 log = logging.getLogger(__name__)
 
-_CSRC = os.path.join(os.path.dirname(os.path.dirname(
-    os.path.dirname(os.path.abspath(__file__)))), "csrc")
-_SO = os.path.join(_CSRC, "build", "libcurvine_sdk.so")
 _lib = None
 _tried = False
 
@@ -31,15 +26,10 @@ def _load():
     if _tried:
         return _lib
     _tried = True
-    if not os.path.exists(_SO) and os.path.exists(
-            os.path.join(_CSRC, "Makefile")):
-        try:
-            subprocess.run(["make", "-C", _CSRC], capture_output=True,
-                           timeout=120, check=True)
-        except Exception as e:  # noqa: BLE001 — stay gracefully absent
-            log.debug("native sdk build failed: %s", e)
-    if os.path.exists(_SO):
-        lib = ctypes.CDLL(_SO)
+    from curvine_tpu.common import native
+    so = native.build("libcurvine_sdk.so")
+    if so is not None:
+        lib = ctypes.CDLL(so)
         lib.cv_sdk_connect.restype = ctypes.c_void_p
         lib.cv_sdk_connect.argtypes = [ctypes.c_char_p, ctypes.c_int,
                                        ctypes.c_char_p]

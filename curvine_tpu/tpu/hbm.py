@@ -78,8 +78,9 @@ class HbmTier:
                  exports: HbmExportTable | None = None, policy=None):
         from curvine_tpu.common.cache import make_policy
         self.capacity = capacity_bytes
-        self.device = device if device is not None else jax.devices()[0]
+        self.device = device if device is not None else jax.local_devices()[0]
         self.used = 0
+        self.placed_at = 0.0    # when a block last landed on this chip
         self._blocks: dict[int, jax.Array] = {}
         self._atime: dict[int, float] = {}
         self.hits = 0
@@ -115,7 +116,7 @@ class HbmTier:
         self._evict_for(need)
         dev_arr = jax.device_put(arr, self.device)
         self._blocks[block_id] = dev_arr
-        self._atime[block_id] = time.monotonic()
+        self._atime[block_id] = self.placed_at = time.monotonic()
         self.used += need
         self.policy.on_admit(block_id, need)
         if self.exports is not None:
@@ -169,8 +170,9 @@ class MultiHbmTier:
     blocks can be spread as replicas across chips so every consumer
     reads HBM-locally instead of crossing PCIe or ICI.
 
-    This is the multi-chip completion of the round-2 single-device tier
-    (which bound jax.devices()[0] only)."""
+    One process per chip: building this claims every local chip for the
+    process, so it belongs to a worker embedded in the process that runs
+    the JAX consumer — a standalone `cv worker` keeps hbm_capacity = 0."""
 
     def __init__(self, capacity_bytes: int, devices=None,
                  admission: str = "lru", ghost_entries: int = 2048,
@@ -212,7 +214,11 @@ class MultiHbmTier:
 
     # ---- placement ----
     def _pick(self) -> "HbmTier":
-        return min(self.tiers.values(), key=lambda t: t.used)
+        # least-used chip; among equally full ones the chip placed on
+        # longest ago — a full tier rotates its evictions over all chips
+        # instead of churning the first one's few slots
+        return min(self.tiers.values(),
+                   key=lambda t: (t.used, t.placed_at))
 
     def _tier_of(self, device) -> "HbmTier":
         did = getattr(device, "id", device)

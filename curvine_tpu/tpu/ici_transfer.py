@@ -64,8 +64,6 @@ def ring_shift(sharded: jax.Array, mesh: Mesh, axis: str | None = None,
     (replicas spread to adjacent chips at link speed, no host hop, no
     full all-gather). Numerics: shard k of the result equals shard
     (k-steps) % N of the input."""
-    from curvine_tpu.tpu.mesh import shard_map_compat
-
     axis = axis or mesh.axis_names[0]
     key = (mesh, axis, steps, sharded.ndim)
     fn = _SHIFT_FNS.get(key)
@@ -77,8 +75,9 @@ def ring_shift(sharded: jax.Array, mesh: Mesh, axis: str | None = None,
         def shift(x):
             return jax.lax.ppermute(x, axis, perm)
 
-        fn = _SHIFT_FNS[key] = jax.jit(
-            shard_map_compat(shift, mesh, spec, spec))
+        fn = _SHIFT_FNS[key] = jax.jit(jax.shard_map(
+            shift, mesh=mesh, in_specs=spec, out_specs=spec,
+            check_vma=False))
     return fn(sharded)
 
 
@@ -108,8 +107,6 @@ def verify_scattered(sharded: jax.Array, mesh: Mesh,
     uint32 wrap-around is deliberate (x64 is disabled under jit on TPU
     and a truncated int64 would wrap SILENTLY; mod-2^32 is the defined
     checksum). Returns [N] uint32 sums, one per shard."""
-    from curvine_tpu.tpu.mesh import shard_map_compat
-
     axis = axis or mesh.axis_names[0]
     key = (mesh, axis, sharded.ndim)
     fn = _SUM_FNS.get(key)
@@ -120,6 +117,7 @@ def verify_scattered(sharded: jax.Array, mesh: Mesh,
             # keepdims-style [1] result per shard → concatenates to [N]
             return jnp.sum(x.astype(jnp.uint32)).reshape(1)
 
-        fn = _SUM_FNS[key] = jax.jit(
-            shard_map_compat(shard_sum, mesh, spec, P(axis)))
+        fn = _SUM_FNS[key] = jax.jit(jax.shard_map(
+            shard_sum, mesh=mesh, in_specs=spec, out_specs=P(axis),
+            check_vma=False))
     return np.asarray(fn(sharded)).astype(np.uint32)

@@ -19,19 +19,6 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
-def shard_map_compat(fn, mesh, in_specs, out_specs, replication_ok=True):
-    """shard_map across JAX generations: `jax.shard_map(check_vma=...)`
-    (new API) when present, `jax.experimental.shard_map.shard_map(
-    check_rep=...)` otherwise — same semantics, renamed kwarg."""
-    sm = getattr(jax, "shard_map", None)
-    if sm is not None:
-        return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                  check_vma=not replication_ok)
-    from jax.experimental.shard_map import shard_map as sm
-    return sm(fn, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-              check_rep=not replication_ok)
-
-
 def factor_mesh(n: int, axes: int = 2) -> tuple[int, ...]:
     """Balanced near-square factorization of n devices into `axes` dims,
     larger factor first (data axis gets the larger share)."""

@@ -41,7 +41,9 @@ class ModelConfig:
     moe_experts: int = 0       # >0: MoE FFN, experts sharded over 'ep'
     # Fused (Pallas) flash attention on TPU: no [B,H,L,L] score
     # materialization, O(L) memory. Requires head_dim % 128 == 0 and
-    # seq % 128 == 0; anything else falls back to dense_attention.
+    # seq % 128 == 0 — a TPU run asking for it with other shapes is an
+    # error, not a dense run. The kernel is TPU-only: on the CPU test
+    # mesh the same config traces dense_attention.
     use_flash_attention: bool = False
     # Cross-entropy in chunks of this many tokens (0 = one-shot): the
     # [B·L, vocab] f32 logits never materialize — each chunk's logits
@@ -103,11 +105,14 @@ def _rmsnorm(x, scale):
     return (x * jax.lax.rsqrt(var + 1e-6)).astype(x.dtype) * scale
 
 
-def _flash_eligible(cfg: ModelConfig, L: int) -> bool:
-    return (cfg.use_flash_attention
-            and jax.default_backend() == "tpu"
-            and cfg.head_dim % 128 == 0
-            and L % 128 == 0)
+def _use_flash(cfg: ModelConfig, L: int) -> bool:
+    if not cfg.use_flash_attention or jax.default_backend() == "cpu":
+        return False
+    if cfg.head_dim % 128 or L % 128:
+        raise ValueError(
+            f"use_flash_attention needs head_dim % 128 == 0 and "
+            f"seq % 128 == 0; got head_dim {cfg.head_dim}, seq {L}")
+    return True
 
 
 def _flash_attention(q, k, v):
@@ -130,7 +135,7 @@ def _attention(x, layer, cfg: ModelConfig, mesh: Mesh | None):
     v = (x @ layer["wv"]).reshape(B, L, H, hd).transpose(0, 2, 1, 3)
     if cfg.use_ring_attention and mesh is not None and "seq" in mesh.axis_names:
         o = ring_attention_sharded(q, k, v, mesh, axis_name="seq", causal=True)
-    elif _flash_eligible(cfg, L):
+    elif _use_flash(cfg, L):
         o = _flash_attention(q, k, v)
     else:
         o = dense_attention(q, k, v, causal=True)

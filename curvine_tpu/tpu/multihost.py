@@ -12,27 +12,22 @@ the framework is already written against global meshes."""
 
 from __future__ import annotations
 
-import logging
 import os
 
 import jax
-
-log = logging.getLogger(__name__)
 
 
 def initialize(coordinator: str | None = None,
                num_processes: int | None = None,
                process_id: int | None = None) -> None:
-    """Connect this process to the pod's JAX distributed runtime.
-
-    No-ops for single-process runs; on TPU pods with env-provided topology
-    (TPU_WORKER_HOSTNAMES etc.) jax.distributed autodetects everything."""
+    """Connect this process to the pod's JAX distributed runtime. With
+    no coordinator given, jax.distributed autodetects from the pod's
+    environment (TPU_WORKER_HOSTNAMES etc.). A failure raises: a process
+    that was told to join a pod and could not must not train alone. A
+    single-process run simply does not call this."""
     coordinator = coordinator or os.environ.get("CURVINE_COORDINATOR")
     if coordinator is None and num_processes is None:
-        try:
-            jax.distributed.initialize()    # autodetect (TPU pod metadata)
-        except Exception as e:  # noqa: BLE001 — single-host fallback
-            log.debug("jax.distributed autodetect skipped: %s", e)
+        jax.distributed.initialize()
         return
     jax.distributed.initialize(coordinator_address=coordinator,
                                num_processes=num_processes,

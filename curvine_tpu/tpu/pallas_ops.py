@@ -2,8 +2,7 @@
 
 block_checksum: integrity hash of an HBM-resident cached block computed
 on-device (VPU tile reduction) — verifying a block after an ICI/DCN
-transfer without ever copying it back to the host. Falls back to pallas
-interpret mode off-TPU so tests run on CPU.
+transfer without ever copying it back to the host.
 
 pq_lut_scan: the IVF-PQ ADC inner loop (vector/index.py) — score W
 candidates by summing M one-byte codeword lookups against a per-query
@@ -22,6 +21,17 @@ from jax.experimental import pallas as pl
 LANE = 128
 SUBLANE = 8
 TILE_WORDS = 64 * SUBLANE * LANE     # 64 f32-tiles per grid step (256 KiB)
+
+
+def interpret_for(arr) -> bool:
+    """Pallas interpret mode is for arrays that live on a CPU device (the
+    test mesh) and nothing else: any accelerator gets the compiled Mosaic
+    kernel or an error, never a silent emulation. A tracer or a host
+    array has no device yet: the backend it will be compiled for or put
+    on decides."""
+    if isinstance(arr, jax.Array) and not isinstance(arr, jax.core.Tracer):
+        return next(iter(arr.devices())).platform == "cpu"
+    return jax.default_backend() == "cpu"
 
 
 def _checksum_kernel(x_ref, out_ref):
@@ -70,8 +80,7 @@ def _checksum_words(words: jax.Array, interpret: bool = False) -> jax.Array:
 
 def block_checksum(block: jax.Array) -> int:
     """Checksum of a device-resident uint8 block (stays on device)."""
-    interpret = jax.devices()[0].platform != "tpu" or \
-        block.devices().pop().platform != "tpu"
+    interpret = interpret_for(block)
     nbytes = block.shape[0]
     pad = (-nbytes) % 4
     if pad:
@@ -82,25 +91,25 @@ def block_checksum(block: jax.Array) -> int:
 
 
 def block_checksum_host(data: bytes | np.ndarray) -> int:
-    """Reference/host implementation (numpy) of the same hash."""
+    """Reference/host implementation (numpy) of the same hash. All sums
+    wrap mod 2^32 on purpose — that is the kernel's int32 arithmetic."""
     arr = np.frombuffer(data, dtype=np.uint8) if isinstance(
         data, (bytes, bytearray, memoryview)) else np.asarray(data)
-    pad = (-arr.size) % 4
-    if pad:
-        arr = np.pad(arr, (0, pad))
-    words = arr.view(np.uint32).astype(np.uint64)
-    n = words.size
-    padded = ((n + TILE_WORDS - 1) // TILE_WORDS) * TILE_WORDS
-    w = np.zeros(padded, dtype=np.uint64)
-    w[:n] = words
-    s = np.uint64(w.sum()) & np.uint64(0xFFFFFFFF)
-    # mixed term: index within each lane-row (column id), offset per tile
-    cols = np.tile(np.arange(LANE, dtype=np.uint64), padded // LANE)
-    tile_of = (np.arange(padded, dtype=np.uint64) // TILE_WORDS) \
-        * np.uint64(TILE_WORDS)
-    mixed = np.bitwise_xor(w, (cols + tile_of) & np.uint64(0xFFFFFFFF))
-    m = np.uint64(mixed.sum()) & np.uint64(0xFFFFFFFF)
-    return int((s ^ ((m << np.uint64(1)) & np.uint64(0xFFFFFFFF))))
+    n = -(-arr.size // 4)
+    tiles = -(-n // TILE_WORDS)
+    if arr.size == tiles * TILE_WORDS * 4:
+        words = arr.view(np.uint32)
+    else:
+        words = np.zeros(tiles * TILE_WORDS, dtype=np.uint32)
+        words.view(np.uint8)[:arr.size] = arr
+    w = words.reshape(tiles, TILE_WORDS // LANE, LANE)
+    s = w.sum(dtype=np.uint32)
+    # mixed term: lane index within each row, offset by the tile's base
+    mix = (np.arange(LANE, dtype=np.uint32)[None, None, :]
+           + (np.arange(tiles, dtype=np.uint32)
+              * np.uint32(TILE_WORDS))[:, None, None])
+    m = np.bitwise_xor(w, mix).sum(dtype=np.uint32)
+    return int(s ^ (m << np.uint32(1)))
 
 
 # ---------------------------------------------------------------- PQ ADC
@@ -155,9 +164,9 @@ def pq_lut_scan(lut: jax.Array, codes: jax.Array,
     [W, M] int — W is padded to the candidate tile internally.
     pre_offset=True means codes already hold code + m·ksub (the pinned
     flat-LUT layout). Traceable (used inside the jitted IVF-PQ search);
-    interpret=None picks interpret mode off-TPU like block_checksum."""
+    interpret=None decides like block_checksum (interpret_for)."""
     if interpret is None:
-        interpret = jax.devices()[0].platform != "tpu"
+        interpret = interpret_for(codes)
     w = codes.shape[0]
     pad = (-w) % PQ_TILE
     if pad:

@@ -88,10 +88,9 @@ def pipeline_forward(params_stacked: dict, tokens, cfg: ModelConfig,
 
     layer_specs = {k: P("pp", *([None] * (v.ndim - 1)))
                    for k, v in params_stacked["layers"].items()}
-    from curvine_tpu.tpu.mesh import shard_map_compat
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         pipelined, mesh=mesh,
-        in_specs=(layer_specs, P()), out_specs=P("pp"))
+        in_specs=(layer_specs, P()), out_specs=P("pp"), check_vma=False)
     # out_specs P('pp') stacks each stage's masked buffer: [S*M, mb, L, D];
     # summing the stage axis recovers the last stage's outputs
     stacked_out = fn(params_stacked["layers"], x)

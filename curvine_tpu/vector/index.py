@@ -128,7 +128,7 @@ class PqCodebook:
         ksub = max(1, min(ksub, 256, tn))
         sub = np.ascontiguousarray(
             train_v.reshape(tn, m, dsub).transpose(1, 0, 2))
-        dev = device if device is not None else jax.devices()[0]
+        dev = device if device is not None else jax.local_devices()[0]
         step = _kmeans_step_fn(tn, dsub, ksub)
         cbs = []
         for mi in range(m):
@@ -158,7 +158,7 @@ class PqCodebook:
         if d != self.m * self.dsub:
             raise err.InvalidArgument(
                 f"encode dim {d} != {self.m}x{self.dsub}")
-        dev = device if device is not None else jax.devices()[0]
+        dev = device if device is not None else jax.local_devices()[0]
         cbs = jax.device_put(self.codebooks, dev)
         out = np.empty((n, self.m), dtype=np.uint8)
         chunk = min(chunk, max(1, n))
@@ -442,7 +442,7 @@ class IvfIndex:
         nlist = max(1, min(nlist, n))
         rng = np.random.default_rng(seed)
         seeds = vectors[rng.choice(n, size=nlist, replace=False)]
-        dev = device if device is not None else jax.devices()[0]
+        dev = device if device is not None else jax.local_devices()[0]
         v = jax.device_put(np.asarray(vectors, dtype=np.float32), dev)
         cent = jax.device_put(np.asarray(seeds, dtype=np.float32), dev)
         step = _kmeans_step_fn(n, d, nlist)
@@ -581,8 +581,9 @@ class IvfIndex:
 
         use_pq: "auto" uses the ADC path iff PQ codes were built;
         rerank: ADC survivors re-scored exactly (default max(4k, 32));
-        pallas: "auto" fuses the ADC scan as a Pallas kernel on TPU
-        (interpret-mode fallback if forced on elsewhere)."""
+        pallas: "auto" fuses the ADC scan as a Pallas kernel on TPU;
+        True forces it (compiled — interpret mode only where `device`
+        is a CPU device, i.e. the tests)."""
         import jax
 
         if use_pq == "auto":
@@ -602,7 +603,7 @@ class IvfIndex:
         platform = getattr(device, "platform", "")
         use_pallas = pallas is True or (pallas == "auto"
                                         and platform == "tpu")
-        interpret = platform != "tpu"
+        interpret = platform == "cpu"
         fn = _pq_search_fn(metric, k, nprobe, rr, use_pallas, interpret)
         return fn(q, state["cent"], state["lists"], state["cbs"],
                   state["codes"], state["norms"], v_pinned, ids_pinned)

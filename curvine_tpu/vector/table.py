@@ -392,7 +392,7 @@ class VectorTable:
             nlist = max(1, int(np.sqrt(n)))     # the usual IVF default
         snap = table_snapshot(self)
         snap["metric"] = metric
-        dev = device if device is not None else jax.devices()[0]
+        dev = device if device is not None else jax.local_devices()[0]
         idx = IvfIndex.build(host, live, nlist, snap, iters=iters,
                              device=dev, cap_pct=cap_pct, pq_m=pq_m,
                              pq_ksub=pq_ksub, pq_iters=pq_iters,
@@ -465,7 +465,7 @@ class VectorTable:
         query = np.atleast_2d(np.asarray(query, dtype=np.float32))
         if query.shape[1] != self.dim:
             raise err.InvalidArgument(f"query dim {query.shape[1]} != {self.dim}")
-        dev = device if device is not None else jax.devices()[0]
+        dev = device if device is not None else jax.local_devices()[0]
         v, ids = await self._device_vectors(metric, dev, dtype=dtype)
         idx = await self._fresh_index(metric) if use_index else None
         if use_index and idx is None and self._index is not None:

@@ -197,20 +197,23 @@ class WorkerServer:
         self.peer_pool = ConnectionPool(size=2, rpc_conf=self.conf.rpc)
         self.worker_id = worker_id if worker_id is not None else 0
         self.chunk_size = wc.io_chunk_size
-        # HBM tier-0: device-resident block cache for workers co-located
-        # with a TPU (in-process consumers get on-device fetches)
+        # HBM tier-0: device-resident block cache. Building it claims
+        # every local chip for THIS process (one process per chip), so
+        # hbm_capacity > 0 belongs to a worker embedded in the process
+        # that runs the JAX consumer; a standalone `cv worker` keeps 0.
+        # A worker told to hold a tier that cannot come up does not
+        # start — serving without it would hide the missing device.
         self.hbm = None
         if wc.hbm_capacity > 0:
-            try:
-                # one tier per local chip (a TPU host drives 4-8): per-chip
-                # capacity accounting, least-used placement, replica spread
-                from curvine_tpu.tpu.hbm import MultiHbmTier
-                self.hbm = MultiHbmTier(wc.hbm_capacity,
-                                        admission=wc.cache_admission,
-                                        ghost_entries=wc.cache_ghost_entries,
-                                        export_cap=wc.hbm_export_cap)
-            except Exception as e:  # noqa: BLE001 — no device available
-                log.warning("hbm tier disabled: %s", e)
+            from curvine_tpu.tpu.compile_cache import enable_compile_cache
+            from curvine_tpu.tpu.hbm import MultiHbmTier
+            enable_compile_cache()
+            # one tier per local chip (a TPU host drives 4-8): per-chip
+            # capacity accounting, least-used placement, replica spread
+            self.hbm = MultiHbmTier(wc.hbm_capacity,
+                                    admission=wc.cache_admission,
+                                    ghost_entries=wc.cache_ghost_entries,
+                                    export_cap=wc.hbm_export_cap)
         self._bg: list[asyncio.Task] = []
         from curvine_tpu.common.executor import ScheduledExecutor
         self.executor = ScheduledExecutor("worker")
@@ -640,15 +643,12 @@ class WorkerServer:
                                            buf.data) != info.crc32c:
                         raise err.AbnormalData(
                             f"block {block_id} failed promotion verify")
+                from curvine_tpu.tpu import pallas_ops
                 arr = self.hbm.put(block_id, buf)
-                try:
-                    from curvine_tpu.tpu import pallas_ops
-                    if (pallas_ops.block_checksum(arr)
-                            != pallas_ops.block_checksum_host(buf)):
-                        raise err.AbnormalData(
-                            f"block {block_id} device copy diverges")
-                except ImportError:
-                    pass
+                if (pallas_ops.block_checksum(arr)
+                        != pallas_ops.block_checksum_host(buf)):
+                    raise err.AbnormalData(
+                        f"block {block_id} device copy diverges")
                 return info.len
 
             try:

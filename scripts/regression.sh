@@ -46,6 +46,9 @@ else
 fi
 run_stage dryrun-multichip dryrun.log \
     python -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
+# the two stages below need a TPU and fail without one (each is its own
+# process, one after the other: one process per chip)
+run_stage chip-smoke smoke.log python chip_smoke.py
 run_stage bench bench.log python bench.py
 grep -h '^{' "$OUT/bench.log" | tail -1 > "$OUT/bench.json" 2>/dev/null
 
@@ -65,7 +68,7 @@ grep -h '^{' "$OUT/bench.log" | tail -1 > "$OUT/bench.json" 2>/dev/null
     if [ -s "$OUT/bench.json" ]; then
         echo "<h2>bench</h2><pre>$(python -m json.tool < "$OUT/bench.json")</pre>"
     fi
-    echo "<p>logs: pytest.log · dryrun.log · bench.log</p>"
+    echo "<p>logs: pytest.log · dryrun.log · smoke.log · bench.log</p>"
 } > "$OUT/report.html"
 
 echo "report: $OUT/report.html"

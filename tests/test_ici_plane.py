@@ -399,6 +399,12 @@ async def test_replication_prefers_ici_near_source():
     async with MiniCluster(workers=3, conf=_hbm_conf()) as mc:
         rm = mc.master.replication
         c = mc.client()
+        # MiniCluster puts worker i at [i, 0]: whenever the middle worker
+        # is the destination both holders sit one hop away, and the tie
+        # falls to the iteration order of the port-derived worker ids.
+        # Move worker 2 out so all three pairwise distances differ.
+        mc.workers[2].conf.worker.ici_coords = [3, 0]
+        await mc.workers[2].heartbeat_once()
         data = os.urandom(64 * 1024)
         await c.write_all("/ici/near", data, replicas=2)
         fb = await c.meta.get_block_locations("/ici/near")
@@ -422,8 +428,7 @@ async def test_replication_prefers_ici_near_source():
         mc.master.fs.blocks.desired[bid] = 3
         ok = await rm._replicate(bid)
         assert ok and submitted["block_id"] == bid
-        # MiniCluster places worker i at ici coords [i, 0]: the chosen
-        # source must be the holder nearest the destination in hops
+        # the chosen source must be the holder nearest the destination
         by_id = mc.master.fs.workers.workers
         src_wid = submitted["source"]["worker_id"]
         want = min(holders, key=lambda wid: ici_hops(

@@ -18,6 +18,7 @@ from curvine_tpu.common.types import (
     MountInfo, SetAttrOpts,
 )
 from curvine_tpu.client.meta_cache import MISS, MetaCache, parent_dir
+from curvine_tpu.obs.trace import NULL_SPAN
 from curvine_tpu.rpc import RpcCode
 from curvine_tpu.rpc.client import Connection, ConnectionPool, RetryPolicy
 from curvine_tpu.rpc.frame import pack, unpack
@@ -65,6 +66,12 @@ class FsClient:
         # a client span; the context is stamped into the RPC header by
         # the connection layer so the master's span links to it
         self.tracer = None
+        # optional counter dict (set by CurvineClient, as `tracer` is):
+        # per master call the client's wall time beside the server's own
+        # queue and handle time from the reply (meta.*), so the three
+        # parts of a call — the master, its queue, everything between —
+        # can be told apart from this side
+        self.counters: dict | None = None
         cc = self.conf.client
         self.masters = list(cc.master_addrs)
         self._active = 0
@@ -127,11 +134,22 @@ class FsClient:
             req["client_id"] = self.client_id
             req["call_id"] = next(self._call_ids)
 
+        # (the metrics push and the span collect neither trace nor
+        # count themselves: an idle client would never fall silent)
+        counted = code not in _UNTRACED
+        span = NULL_SPAN
+        if self.tracer is not None and counted:
+            span = self.tracer.span(f"meta.{RpcCode(code).name.lower()}")
+
         async def once() -> dict:
             try:
+                t0 = time.perf_counter()
                 conn = await self._conn()
                 rep = await conn.call(code, data=pack(req),
                                       deadline=deadline)
+                if counted:
+                    self._count_call(time.perf_counter() - t0,
+                                     rep.srv_seconds(), span)
                 return unpack(rep.data) or {}
             except err.CurvineError as e:
                 if e.code in (err.ErrorCode.NOT_LEADER, err.ErrorCode.CONNECT):
@@ -141,11 +159,28 @@ class FsClient:
                     self._fast_probe_after = 0.0
                 raise
 
-        if self.tracer is not None and code not in _UNTRACED:
-            with self.tracer.span(f"meta.{RpcCode(code).name.lower()}"):
-                # the retry policy never sleeps past the caller's budget
-                return await self.retry.run(once, deadline=deadline)
-        return await self.retry.run(once, deadline=deadline)
+        with span:
+            # the retry policy never sleeps past the caller's budget
+            return await self.retry.run(once, deadline=deadline)
+
+    def _count_call(self, wall_s: float, srv, span) -> None:
+        """One answered call of the Python port: meta.calls, meta.wall_s
+        and — where the peer sent its own time — meta.srv_queue_s,
+        meta.srv_handle_s, so that wall − queue − handle is the
+        connection, the wire and this client's loop. (The native fast
+        plane sends none and is not counted here: it would dilute the
+        means.)"""
+        queue_s, handle_s = srv or (0.0, 0.0)
+        if srv is not None:
+            span.set_attr("srv_queue_us", round(queue_s * 1e6))
+            span.set_attr("srv_handle_us", round(handle_s * 1e6))
+        c = self.counters
+        if c is None:
+            return
+        for key, v in (("meta.calls", 1), ("meta.wall_s", wall_s),
+                       ("meta.srv_queue_s", queue_s),
+                       ("meta.srv_handle_s", handle_s)):
+            c[key] = c.get(key, 0) + v
 
     def _note_leader_hint(self, e: err.CurvineError) -> None:
         """NOT_LEADER redirect handling: adopt the member list the error

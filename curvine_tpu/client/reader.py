@@ -19,6 +19,7 @@ from curvine_tpu.common import errors as err  # noqa: F401
 from curvine_tpu.common.types import (
     ExtendedBlock, FileBlocks, LocatedBlock, WorkerAddress,
 )
+from curvine_tpu.obs.trace import Timed
 from curvine_tpu.rpc import RpcCode, transport
 from curvine_tpu.rpc.client import ConnectionPool
 from curvine_tpu.rpc.deadline import Deadline
@@ -235,11 +236,21 @@ class FsReader:
             locs = self.health.order(locs, key=self._addr)
         return locs
 
-    def _span(self, op: str, **attrs):
-        """Tracer span (or a no-op when untraced)."""
+    def _span(self, op: str, detail: bool = False, **attrs):
+        """Tracer span (or a no-op when untraced). `detail`: a step
+        inside an operation, which raises no slow-op line of its own
+        (with no span round it, it is the operation: Tracer.span)."""
         if self.tracer is None:
             return nullcontext()
-        return self.tracer.span(op, attrs=attrs or None)
+        return self.tracer.span(op, attrs=attrs or None, detail=detail)
+
+    def _phase(self, phase: str):
+        """One phase of a read, timed at the one place its work is done
+        (docs/observability.md has the table): seconds and count into
+        the client's counters as read.phase.<phase>.s / .n, always on,
+        and the span phase.<phase>."""
+        return Timed(self.counters, f"read.phase.{phase}",
+                     self._span(f"phase.{phase}", detail=True))
 
     # ---------------- hole regions ----------------
 
@@ -321,16 +332,21 @@ class FsReader:
                     loc.ip_addr in ("127.0.0.1", "localhost"):
                 try:
                     addr = f"{loc.ip_addr or loc.hostname}:{loc.rpc_port}"
-                    conn = await self.pool.get(addr)
-                    # lease clocks start at request SEND, not reply
-                    # arrival: the worker grants after our send, so
-                    # send + lease_ms always undershoots the worker's
-                    # expiry no matter how long the reply took — a
-                    # delayed reply can never extend the window past
-                    # what the worker's quarantine covers
-                    sent_at = time.time()
-                    rep = await conn.call(RpcCode.GET_BLOCK_INFO,
-                                          data=pack({"block_id": bid}))
+                    with self._phase("probe"):
+                        conn = await self.pool.get(addr)
+                        # lease clocks start at request SEND, not reply
+                        # arrival: the worker grants after our send, so
+                        # send + lease_ms always undershoots the worker's
+                        # expiry no matter how long the reply took — a
+                        # delayed reply can never extend the window past
+                        # what the worker's quarantine covers
+                        sent_at = time.time()
+                        rep = await conn.call(RpcCode.GET_BLOCK_INFO,
+                                              data=pack({"block_id": bid}))
+                    srv = rep.srv_seconds()
+                    if srv is not None:
+                        # the worker's own share of the probe's wall
+                        self._count("read.probe.srv_handle_s", srv[1])
                     info = rep.header or unpack(rep.data) or {}
                     if info.get("direct_io"):
                         self.direct_queue_depth = max(
@@ -391,7 +407,9 @@ class FsReader:
     def _mark(self, path: str) -> None:
         self._serve_paths.add(path)
 
-    def _served_by(self) -> str:
+    def served_by(self) -> str:
+        """The rungs that served the current read op, as each marked
+        itself where it returned ("shm", "local", "remote", …)."""
         return "+".join(sorted(self._serve_paths)) or "none"
 
     def _shm_hit(self, bid: int) -> None:
@@ -427,10 +445,11 @@ class FsReader:
         spath = self._shm_sock.get(bid)
         if spath is None:
             return None
-        from curvine_tpu.worker.shm import fetch_block_fd
+        stamps: list[float] = []
+        t_submit = time.perf_counter()
         try:
-            fd, length = await asyncio.to_thread(fetch_block_fd,
-                                                 spath, bid)
+            fd, length = await asyncio.to_thread(self._grant, spath, bid,
+                                                 stamps)
         except (LookupError, OSError, ValueError) as e:
             # worker dropped the export / channel gone: stop retrying
             # this block, serve it through fd/socket instead
@@ -438,6 +457,18 @@ class FsReader:
             self._shm_sock.pop(bid, None)
             self._shm_fallback(bid)
             return None
+        finally:
+            if len(stamps) == 2:
+                # counted here, on the loop: fetch threads never write
+                # the counters. `resume` is what the hand-off cost beside
+                # the grant itself: submit → thread running, thread
+                # returned → this task running again (the loop, the GIL)
+                grant_s = stamps[1] - stamps[0]
+                self._count("read.phase.grant.s", grant_s)
+                self._count("read.phase.grant.n")
+                self._count("read.phase.resume.s",
+                            time.perf_counter() - t_submit - grant_s)
+                self._count("read.phase.resume.n")
         other = self._shm_maps.get(bid)
         if other is not None:
             # lost a concurrent-fetch race: keep the first mapping
@@ -449,7 +480,8 @@ class FsReader:
             self._shm_fallback(bid)
             return None
         try:
-            mm = mmap.mmap(fd, length, access=mmap.ACCESS_READ)
+            with self._phase("map"):
+                mm = mmap.mmap(fd, length, access=mmap.ACCESS_READ)
         except (OSError, ValueError):
             os.close(fd)
             self._shm_fallback(bid)
@@ -465,6 +497,19 @@ class FsReader:
             return None
         self._shm_maps[bid] = (fd, mm)
         return mm
+
+    def _grant(self, spath: str, bid: int, stamps: list) -> tuple[int, int]:
+        """`fetch_block_fd` on the fetch thread, under the span of the
+        phase `grant`; `stamps` gets when it began and ended there, so
+        the awaiting task can tell the grant from its own wait to run
+        again."""
+        from curvine_tpu.worker.shm import fetch_block_fd
+        with self._span("phase.grant", detail=True):
+            stamps.append(time.perf_counter())
+            try:
+                return fetch_block_fd(spath, bid)
+            finally:
+                stamps.append(time.perf_counter())
 
     async def _shm_read_into(self, lb: LocatedBlock, block_off: int,
                              out) -> int:
@@ -494,7 +539,13 @@ class FsReader:
         lb, block_off = located
         if block_off + n > lb.block.len:
             return None
-        mm = await self._shm_map(lb)
+        with self._span("shm_view", detail=True, block=lb.block.id,
+                        n=n) as sp:
+            mm = await self._shm_map(lb)
+            if sp is not None:
+                sp.set_attr("served_by", "none" if mm is None else
+                            "shm_warm" if lb.block.id in self._shm_warm
+                            else "shm")
         if mm is None:
             return None
         import numpy as np
@@ -544,7 +595,8 @@ class FsReader:
         if ent is None:
             return True
         want, algo = ent
-        got = _block_crc(algo, data)
+        with self._phase("verify"):
+            got = _block_crc(algo, data)
         if got is None or got == want:
             return True
         self._flag_corrupt(lb, self._pick_loc(lb))
@@ -614,19 +666,28 @@ class FsReader:
             self.pos += len(first)
             if len(first) == n or not first:
                 return first      # common case: one block segment, no copy
-            out = bytearray(first)
+            with self._phase("copy"):
+                out = bytearray(first)
             while len(out) < n:
                 got = await self._read_some(self.pos, n - len(out),
                                             deadline=dl)
                 if not got:
                     break
-                out += got
+                with self._phase("copy"):
+                    out += got
                 self.pos += len(got)
-            return bytes(out)
+            with self._phase("copy"):
+                return bytes(out)
 
     async def read_all(self, deadline_ms=None) -> bytes:
         self.seek(0)
-        return await self.read(self.len, deadline_ms=deadline_ms)
+        self._serve_paths = set()
+        with self._span("read_all", detail=True, path=self.path,
+                        n=self.len) as sp:
+            data = await self.read(self.len, deadline_ms=deadline_ms)
+            if sp is not None:
+                sp.set_attr("served_by", self.served_by())
+        return data
 
     async def pread(self, offset: int, n: int, deadline_ms=None) -> bytes:
         """Positional read without moving the cursor."""
@@ -657,7 +718,7 @@ class FsReader:
                 offset, out, use_prefetch=True,
                 deadline=self._deadline(deadline_ms))
             if sp is not None:
-                sp.set_attr("served_by", self._served_by())
+                sp.set_attr("served_by", self.served_by())
         self.detector.record_read(offset, offset + filled)
         self._prefetch_topup(offset + filled)
         return out[:filled]
@@ -761,12 +822,12 @@ class FsReader:
             if view is not None:
                 if sp is not None:
                     # _shm_view marked shm or shm_warm as appropriate
-                    sp.set_attr("served_by", self._served_by())
+                    sp.set_attr("served_by", self.served_by())
                 return view
             out = self._alloc_out(n)
             got = await self._read_range(offset, n, parallel, out, dl)
             if sp is not None:
-                sp.set_attr("served_by", self._served_by())
+                sp.set_attr("served_by", self.served_by())
             return got
 
     async def _read_range(self, offset: int, n: int, parallel: int,
@@ -994,6 +1055,17 @@ class FsReader:
 
         Shm-mapped blocks ARE true zero-copy here again: the sealed
         mapping serves a read-only view with no preadv and no buffer."""
+        self._serve_paths = set()
+        with self._span("mmap_view", detail=True, path=self.path,
+                        offset=offset, n=n) as sp:
+            view = await self._mmap_view(offset, n)
+            if sp is not None:
+                # "none": not short-circuit readable, the caller falls
+                # to read_all
+                sp.set_attr("served_by", self.served_by())
+        return view
+
+    async def _mmap_view(self, offset: int, n: int):
         import numpy as np
         view = await self._shm_view(offset, n)
         if view is not None:
@@ -1009,7 +1081,8 @@ class FsReader:
             return None
         buf = np.empty(n, dtype=np.uint8)
         base = self._local_offs.get(lb.block.id, 0)
-        got = os.preadv(fd, [memoryview(buf)], base + block_off)
+        with self._phase("copy"):
+            got = os.preadv(fd, [memoryview(buf)], base + block_off)
         if got != n:
             # stale probe (block shrank/moved): drop the cached handles
             # so the caller's fallback path re-probes instead of looping
@@ -1019,6 +1092,7 @@ class FsReader:
                 and not self._sc_verify_ok(lb, buf):
             return None       # caller falls back to the verified path
         self._note_sc_read(lb.block.id, n)
+        self._mark("local")
         return buf
 
     # ---------------- erasure-coded reads ----------------
@@ -1204,8 +1278,10 @@ class FsReader:
             try:
                 with self._span("read_block", addr=self._addr(loc),
                                 block=lb.block.id):
-                    return await self._read_from(loc, lb, block_off, n,
+                    data = await self._read_from(loc, lb, block_off, n,
                                                  deadline=hop)
+                self._mark("remote")
+                return data
             except err.CurvineError as e:
                 log.warning("read block %d from %s:%d failed (%s), "
                             "trying next replica", lb.block.id,
@@ -1303,6 +1379,10 @@ class FsReader:
         return bytes(out)
 
     async def close(self) -> None:
+        with self._phase("close"):
+            await self._close()
+
+    async def _close(self) -> None:
         # prefetch window: cancel AND await, so no task outlives the
         # reader (a cancelled-never-awaited task warns at loop teardown
         # and pins its receive buffer)

@@ -16,7 +16,7 @@ from curvine_tpu.common.types import StorageType
 from curvine_tpu.client.fs_client import FsClient
 from curvine_tpu.client.reader import FsReader
 from curvine_tpu.client.writer import FsWriter
-from curvine_tpu.obs.trace import Tracer
+from curvine_tpu.obs.trace import Timed, Tracer
 from curvine_tpu.rpc.client import ConnectionPool
 
 log = logging.getLogger(__name__)
@@ -65,6 +65,9 @@ class CurvineClient:
         self.counters: dict[str, float] = {}
         self._reported: dict[str, float] = {}
         self._metrics_task = None
+        # the meta client accounts its calls (and the master's own time
+        # from each reply) into the same dict: meta.*
+        self.meta.counters = self.counters
         # meta lease cache hit/miss/invalidation counters ride the same
         # METRICS_REPORT flush (master shows them as client.meta_cache.*)
         if self.meta.cache is not None:
@@ -174,8 +177,12 @@ class CurvineClient:
 
     async def open(self, path: str) -> FsReader:
         self._ensure_metrics_task()
-        with self.tracer.span("open", attrs={"path": path}):
+        # `locate`, the first phase of a read (docs/observability.md);
+        # the reader accounts the others into the same counters
+        with Timed(self.counters, "read.phase.locate",
+                   self.tracer.span("open", attrs={"path": path})):
             fb = await self.meta.get_block_locations(path)
+        self.counters["read.files"] = self.counters.get("read.files", 0) + 1
         cc = self.conf.client
         return FsReader(self.meta, path, fb, self.pool,
                         chunk_size=cc.read_chunk_size,

@@ -50,13 +50,12 @@ class StepProfiler:
     def step_done(self) -> None:
         self.steps += 1
         self.metrics.inc("steps")
-        self.publish_fractions()
 
     def publish_fractions(self) -> None:
         """Gauge each stage's share of accounted pipeline time as
-        ``stage.<name>.frac`` so /metrics answers 'where did the step
-        go' without a snapshot call (input_wait is the cache indictment
-        number the perf gate watches)."""
+        ``stage.<name>.frac``. For a caller that wants the gauges on
+        /metrics, at its own pace: ten quantile walks, so not a thing
+        for every step of a feed."""
         for stage, frac in self.summary()["fractions"].items():
             self.metrics.gauge(f"stage.{stage}.frac", frac)
 

@@ -15,6 +15,13 @@ backstops: a span that ended in error, and a span slower than
 ``obs.slow_op_ms`` (which additionally emits a structured slow-op log
 line). Parity in spirit: the reference's pervasive prometheus wiring
 (master_metrics.rs / worker_metrics.rs) plus Dapper §3 propagation.
+
+While a ``jax.profiler`` session is open in the process, every span —
+sampled or not — is also a ``TraceAnnotation`` named
+``cv.<component>.<op>`` in the profiler's trace, and every recorded span
+carries ``mono`` (``CLOCK_MONOTONIC``, one axis for all processes of a
+host) beside the wall-clock ``start``. ``Timed`` pairs a span with an
+always-on counter for the phases a benchmark reads.
 """
 
 from __future__ import annotations
@@ -22,6 +29,7 @@ from __future__ import annotations
 import contextvars
 import logging
 import random
+import sys
 import time
 from collections import deque
 
@@ -39,6 +47,21 @@ _current: contextvars.ContextVar["SpanCtx | None"] = \
 def current_ctx() -> "SpanCtx | None":
     """The ambient span context of the calling task, if any."""
     return _current.get()
+
+
+def _annotate(component: str, op: str):
+    """An open ``jax.profiler.TraceAnnotation`` ``cv.<component>.<op>``
+    while a profiler session is running in this process, else None: the
+    program's spans then lie on the profiler's timeline beside the
+    device's idle gaps. JAX is looked up, never imported: a process that
+    has not loaded it (`cv master`) has no session to write into and
+    must stay JAX-free."""
+    prof = sys.modules.get("jax.profiler")
+    if prof is None or not prof.TraceAnnotation.is_enabled():
+        return None
+    ann = prof.TraceAnnotation(f"cv.{component}.{op}")
+    ann.__enter__()
+    return ann
 
 
 def _new_trace_id() -> str:
@@ -134,13 +157,15 @@ class Span:
     happen in different tasks (streaming upload sinks)."""
 
     __slots__ = ("tracer", "ctx", "parent_id", "op", "attrs", "start",
-                 "_t0", "status", "dur", "_token", "_finished")
+                 "_t0", "status", "dur", "_token", "_finished", "_ann",
+                 "detail")
 
     def __init__(self, tracer: "Tracer", ctx: SpanCtx, parent_id: int,
-                 op: str, attrs: dict):
+                 op: str, attrs: dict, detail: bool = False):
         self.tracer = tracer
         self.ctx = ctx
         self.parent_id = parent_id
+        self.detail = detail
         self.op = op
         self.attrs = attrs
         self.start = time.time()
@@ -149,6 +174,9 @@ class Span:
         self.dur = 0.0
         self._token = None
         self._finished = False
+        # whatever the head sampling decided: the profiler's trace is
+        # the caller's own, bounded by the session, not by the ring
+        self._ann = _annotate(tracer.component, op)
 
     def set_attr(self, key: str, value) -> "Span":
         self.attrs[key] = value
@@ -167,6 +195,9 @@ class Span:
         if status is not None:
             self.status = status
         self.dur = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+            self._ann = None
         self.tracer._record(self)
 
     def __enter__(self) -> "Span":
@@ -209,6 +240,33 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+class Timed:
+    """One measurement, two sinks. ``with Timed(counters, key, span):``
+    adds the block's seconds to ``counters[key + ".s"]`` and one to
+    ``counters[key + ".n"]`` — always on, what `/metrics` and the
+    benchmark read — and runs the block under ``span``, which is what
+    `cv trace`, the slow-op log and the profiler's timeline show."""
+
+    __slots__ = ("counters", "_s", "_n", "span", "_t0")
+
+    def __init__(self, counters: dict, key: str, span=NULL_SPAN):
+        self.counters = counters
+        self._s = key + ".s"
+        self._n = key + ".n"
+        self.span = span
+
+    def __enter__(self):
+        self._t0 = time.perf_counter()
+        return self.span.__enter__()
+
+    def __exit__(self, et, ev, tb) -> bool:
+        self.span.__exit__(et, ev, tb)
+        c = self.counters
+        c[self._s] = c.get(self._s, 0.0) + time.perf_counter() - self._t0
+        c[self._n] = c.get(self._n, 0) + 1
+        return False
+
+
 class Tracer:
     """Per-component tracing front end: sampling decisions, span
     creation, the bounded store, and the slow-op backstop."""
@@ -245,17 +303,22 @@ class Tracer:
         self.last_trace_id = ctx.trace_id
         return Span(self, ctx, 0, op, dict(attrs or {}))
 
-    def span(self, op: str, attrs: dict | None = None, parent=None):
+    def span(self, op: str, attrs: dict | None = None, parent=None,
+             detail: bool = False):
         """A child of ``parent`` (a SpanCtx, e.g. from the wire) or of
         the ambient task context; with neither, a new sampled-by-rate
-        root."""
+        root. A ``detail`` span is a step inside an operation (a phase
+        of a read): a span like any other, kept in the ring by the same
+        rule, but it raises no slow-op line of its own — the operation
+        round it does, under the same trace id. With none round it, it
+        is the operation."""
         if not self.enabled:
             return NULL_SPAN
         p = parent if parent is not None else _current.get()
         if p is None:
             return self.start_trace(op, attrs)
         ctx = SpanCtx(p.trace_id, _new_span_id(), p.sampled)
-        return Span(self, ctx, p.span_id, op, dict(attrs or {}))
+        return Span(self, ctx, p.span_id, op, dict(attrs or {}), detail)
 
     # ---------------- record / query ----------------
 
@@ -265,7 +328,7 @@ class Tracer:
         if self.metrics is not None:
             self.metrics.inc("trace.spans_recorded" if keep
                              else "trace.spans_dropped")
-        if slow:
+        if slow and not span.detail:
             log.warning(
                 "slow-op component=%s op=%s dur_ms=%.1f status=%s "
                 "trace_id=%s span_id=%x attrs=%s",
@@ -276,8 +339,8 @@ class Tracer:
         self.store.append({
             "trace_id": span.ctx.trace_id, "span_id": span.ctx.span_id,
             "parent": span.parent_id, "component": self.component,
-            "op": span.op, "start": span.start, "dur": span.dur,
-            "status": span.status, "attrs": span.attrs,
+            "op": span.op, "start": span.start, "mono": span._t0,
+            "dur": span.dur, "status": span.status, "attrs": span.attrs,
         })
 
     def spans_for(self, trace_id: str) -> list[dict]:

@@ -26,7 +26,7 @@ from curvine_tpu.common.errors import ConnectError, CurvineError, RpcTimeout
 from curvine_tpu.common.qos import TENANT_KEY, current_tenant
 from curvine_tpu.obs.trace import TRACE_KEY, current_ctx
 from curvine_tpu.rpc.deadline import DEADLINE_KEY, Deadline
-from curvine_tpu.rpc.frame import Flags, Message, pack, unpack
+from curvine_tpu.rpc.frame import SRV_KEY, Flags, Message, pack, unpack
 from curvine_tpu.rpc.transport import (BulkDecoder, CoalescedWriter,
                                        recv_pool)
 
@@ -152,8 +152,12 @@ class Connection:
                     else:
                         data = bytes(await dec.read_payload(
                             loop, sock, data_len))
+                # the server's own time leaves the header here, so a
+                # caller that parses `header or unpack(data)` sees what
+                # it saw before
                 msg = Message(code=code, req_id=req_id, status=status,
-                              flags=flags, header=header, data=data)
+                              flags=flags, header=header, data=data,
+                              srv=header.pop(SRV_KEY, None))
                 q = self._waiters.get(req_id)
                 if q is not None:
                     # streaming chunks landed in a sink don't need delivery

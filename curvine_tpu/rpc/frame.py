@@ -23,6 +23,12 @@ from curvine_tpu.obs.trace import TRACE_KEY, SpanCtx  # noqa: F401
 # [trace_id, span_id, sampled] (obs/trace.py); rides the same rail as
 # the deadline and is re-stamped with the local span id per hop.
 
+# SRV_KEY: reserved REPLY header field carrying the server's own time
+# for the request, [queue_us, handle_us]: frame parsed → handler start,
+# handler start → reply built. The receiving Connection pops it before
+# the caller sees the header and hands it on as ``Message.srv``.
+SRV_KEY = "srv"
+
 VERSION = 1
 # fixed metadata after the u32 frame length:
 #   u8 version | u16 code | u64 req_id | u8 status | u8 flags | u32 header_len
@@ -56,6 +62,12 @@ class Message:
     # server-side: the caller's trace context (set once at dispatch from
     # the TRACE_KEY header field; never serialized)
     trace: "SpanCtx | None" = None
+    # server-side: perf_counter() when the request frame was parsed off
+    # the wire (0.0 = not stamped; never serialized)
+    parsed: float = 0.0
+    # client-side: the server's [queue_us, handle_us] for this reply,
+    # popped off the header (None from an older or native peer)
+    srv: "list | None" = None
 
     @property
     def is_response(self) -> bool:
@@ -79,6 +91,15 @@ class Message:
     def trace_ctx(self) -> "SpanCtx | None":
         """The caller's trace context, if the request carries one."""
         return SpanCtx.from_header(self.header)
+
+    def srv_seconds(self) -> "tuple[float, float] | None":
+        """(queue_s, handle_s) the server spent on this reply's request,
+        or None where the peer sent none (or something else)."""
+        try:
+            queue_us, handle_us = self.srv
+            return queue_us / 1e6, handle_us / 1e6
+        except (TypeError, ValueError):
+            return None
 
     def check(self) -> "Message":
         """Raise the carried remote error, if any."""

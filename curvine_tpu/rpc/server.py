@@ -29,7 +29,7 @@ from typing import Awaitable, Callable
 from curvine_tpu.common.errors import CurvineError, Throttled
 from curvine_tpu.common.qos import TENANT_KEY
 from curvine_tpu.rpc.frame import (
-    FIXED_LEN, LEN_PREFIX, Flags, Message, error_for, response_for,
+    FIXED_LEN, LEN_PREFIX, SRV_KEY, Flags, Message, error_for, response_for,
 )
 from curvine_tpu.rpc import frame as frame_mod
 from curvine_tpu.rpc.transport import BulkDecoder, CoalescedWriter
@@ -305,7 +305,8 @@ class RpcServer:
                     except (ConnectionResetError, OSError):
                         break
                 msg = Message(code=code, req_id=req_id, status=status,
-                              flags=flags, header=header, data=data)
+                              flags=flags, header=header, data=data,
+                              parsed=time.perf_counter())
                 if is_chunk:
                     # NEVER block the receive loop on a stream queue: if
                     # the request frame was dropped (fault injection) or
@@ -408,6 +409,7 @@ class RpcServer:
                 msg.deadline.check(f"{self.name} {_code_name(msg.code)}")
             if handler is None:
                 raise CurvineError(f"no handler for code {msg.code}")
+            t_handle = time.perf_counter()
             result = await handler(msg, conn)
             if result is None:
                 return  # handler streamed its own response
@@ -417,6 +419,11 @@ class RpcServer:
                 header, data = {}, result
             else:
                 header, data = result, b""
+            # the server's own time rides the reply (a copy: the
+            # handler's dict may be one it keeps)
+            header = {**(header or {}), SRV_KEY: [
+                int((t_handle - (msg.parsed or t_handle)) * 1e6),
+                int((time.perf_counter() - t_handle) * 1e6)]}
             await conn.send(response_for(
                 msg, header=header, data=data, flags=Flags.RESPONSE | Flags.EOF))
         except asyncio.CancelledError:

@@ -15,12 +15,15 @@ from __future__ import annotations
 
 import json
 import logging
+import time
+from contextlib import contextmanager
 
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from curvine_tpu.client import CurvineClient
+from curvine_tpu.obs.trace import Timed
 
 log = logging.getLogger(__name__)
 
@@ -136,28 +139,75 @@ async def load_checkpoint(client: CurvineClient, path: str,
     soon as its bytes land — cache reads overlap device transfers instead
     of the round-2 read-everything-then-transfer-everything sequence."""
     import asyncio
-    manifest, skel, treedef = await _load_manifest(client, path,
-                                                   allow_pickle)
-
-    async def load_one(t):
-        reader = await client.open(f"{path}/{t['name']}")
-        view = await reader.mmap_view(0, reader.len)
-        if view is None:
-            view = np.frombuffer(await reader.read_all(), dtype=np.uint8)
-        arr = view.view(np.dtype(t["dtype"])).reshape(t["shape"])
+    with _restore(client, path):
+        manifest, skel, treedef = await _load_manifest(client, path,
+                                                       allow_pickle)
+        # (no placer: own the memory past the reader's close)
+        flat = await asyncio.gather(*(
+            _load_tensor(client, path, t, placer or np.array)
+            for t in manifest))
         if placer is not None:
-            out = placer(arr)         # async dispatch; device copies now
-        else:
-            out = np.array(arr)       # own the memory past reader close
-        await reader.close()
-        return out
-
-    flat = await asyncio.gather(*(load_one(t) for t in manifest))
-    if placer is not None:
-        flat = [jax.block_until_ready(a) for a in flat]
+            flat = _wait_ready(client, flat)
     if skel is not None:
         return _tree_build(skel, flat)
     return jax.tree.unflatten(treedef, flat)
+
+
+@contextmanager
+def _restore(client: CurvineClient, path: str):
+    """One whole restore: the span ``ckpt.restore``, the root of its
+    trace — every tensor's spans share the trace id that its slow-op
+    line prints — and ckpt.wall_s / ckpt.restores, a restore's mean
+    seconds on /metrics."""
+    t0 = time.perf_counter()
+    with client.tracer.span("ckpt.restore", attrs={"path": path}):
+        yield
+    c = client.counters
+    c["ckpt.wall_s"] = c.get("ckpt.wall_s", 0.0) + time.perf_counter() - t0
+    c["ckpt.restores"] = c.get("ckpt.restores", 0) + 1
+
+
+async def _load_tensor(client: CurvineClient, path: str, t: dict, place,
+                       peer_hbm: bool = False):
+    """One tensor from the cache to where ``place`` puts it, under the
+    span ``ckpt.tensor`` (a step of the restore and the parent of the
+    reader's phases; it raises no slow-op line of its own: in a
+    many-way restore every tensor is slow). Bytes come
+    as a short-circuit view where the file lies in one block, else as a
+    copy through ``read_all``; with ``peer_hbm`` from a peer's HBM tier
+    first. ``place(arr)`` is timed as ckpt.place (an async dispatch: the
+    device copies while the next tensor is read); the reader closes
+    after it."""
+    name = f"{path}/{t['name']}"
+    with client.tracer.span("ckpt.tensor", attrs={"name": t["name"]},
+                            detail=True) as sp:
+        arr = await _hbm_source(client, name, client.counters) \
+            if peer_hbm else None
+        reader = None
+        if arr is not None:
+            sp.set_attr("served_by", "peer_hbm")
+        else:
+            reader = await client.open(name)
+            arr = await reader.mmap_view(0, reader.len)
+            if arr is None:
+                arr = np.frombuffer(await reader.read_all(), dtype=np.uint8)
+            sp.set_attr("blocks", len(reader.blocks.block_locs))
+            sp.set_attr("served_by", reader.served_by())
+        sp.set_attr("bytes", arr.nbytes)
+        arr = arr.view(np.dtype(t["dtype"])).reshape(t["shape"])
+        with Timed(client.counters, "ckpt.place",
+                   client.tracer.span("ckpt.place", detail=True)):
+            out = place(arr)
+        if reader is not None:
+            await reader.close()
+        return out
+
+
+def _wait_ready(client: CurvineClient, flat: list) -> list:
+    """The closing sweep: every transfer dispatched, wait for each."""
+    with Timed(client.counters, "ckpt.ready_wait",
+               client.tracer.span("ckpt.ready_wait", detail=True)):
+        return [jax.block_until_ready(a) for a in flat]
 
 
 async def _read_all(client: CurvineClient, path: str) -> bytes:
@@ -236,54 +286,41 @@ async def _distribute_tree(client: CurvineClient, path: str, mesh: Mesh,
 
     Bit-exact with the flat path — only the sourcing and order differ."""
     import asyncio
-    import time
     from curvine_tpu.tpu import ici_plane
-    manifest, skel, treedef = await _load_manifest(client, path,
-                                                   allow_pickle)
-    counters = getattr(client, "counters", None)
-    devs = mesh.devices.reshape(-1)
-    sched = ici_plane.broadcast_schedule(
-        len(devs), coords=[tuple(getattr(d, "coords", None) or (i,))
-                           for i, d in enumerate(devs)])
-    log.debug("broadcast schedule for %s: %d devices, depth %d",
-              path, len(devs), sched.depth())
-    sharding = NamedSharding(mesh, P())
-    t0 = time.perf_counter()
+    with _restore(client, path):
+        manifest, skel, treedef = await _load_manifest(client, path,
+                                                       allow_pickle)
+        counters = client.counters
+        devs = mesh.devices.reshape(-1)
+        sched = ici_plane.broadcast_schedule(
+            len(devs), coords=[tuple(getattr(d, "coords", None) or (i,))
+                               for i, d in enumerate(devs)])
+        log.debug("broadcast schedule for %s: %d devices, depth %d",
+                  path, len(devs), sched.depth())
+        sharding = NamedSharding(mesh, P())
+        t_read = time.perf_counter()
 
-    async def load_one(t):
-        name = f"{path}/{t['name']}"
-        arr = await _hbm_source(client, name, counters)
-        reader = None
-        if arr is None:
-            reader = await client.open(name)
-            view = await reader.mmap_view(0, reader.len)
-            if view is None:
-                view = np.frombuffer(await reader.read_all(),
-                                     dtype=np.uint8)
-            arr = view
-        out = jax.device_put(
-            arr.view(np.dtype(t["dtype"])).reshape(t["shape"]), sharding)
-        if reader is not None:
-            await reader.close()
-        return out
+        def place(arr):
+            return jax.device_put(arr, sharding)
 
-    def size_of(t):
-        n = 1
-        for d in t["shape"]:
-            n *= int(d)
-        return n * np.dtype(t["dtype"]).itemsize
+        def size_of(t):
+            n = 1
+            for d in t["shape"]:
+                n *= int(d)
+            return n * np.dtype(t["dtype"]).itemsize
 
-    lpt = sorted(range(len(manifest)), key=lambda i: -size_of(manifest[i]))
-    tasks = {i: asyncio.ensure_future(load_one(manifest[i])) for i in lpt}
-    flat = [await tasks[i] for i in range(len(manifest))]
-    flat = [jax.block_until_ready(a) for a in flat]
-    if counters is not None:
+        lpt = sorted(range(len(manifest)),
+                     key=lambda i: -size_of(manifest[i]))
+        tasks = {i: asyncio.ensure_future(_load_tensor(
+            client, path, manifest[i], place, peer_hbm=True)) for i in lpt}
+        flat = [await tasks[i] for i in range(len(manifest))]
+        flat = _wait_ready(client, flat)
         counters["ici.broadcast_bytes"] = \
             counters.get("ici.broadcast_bytes", 0) \
             + sum(size_of(t) for t in manifest)
         counters["ici.broadcast_ms"] = \
             counters.get("ici.broadcast_ms", 0) \
-            + int((time.perf_counter() - t0) * 1000)
+            + int((time.perf_counter() - t_read) * 1000)
     if skel is not None:
         return _tree_build(skel, flat)
     return jax.tree.unflatten(treedef, flat)

@@ -7,6 +7,7 @@ spans from client, master AND worker, correct parent/child links, and
 monotone span intervals."""
 
 import asyncio
+import logging
 import os
 
 import pytest
@@ -367,3 +368,355 @@ async def test_traced_write_and_replication_fanout(tmp_path):
         ops = {(s["component"], s["op"]) for s in spans}
         assert ("master", "replicate_block") in ops
         assert ("worker", "submit_block_replication_job") in ops
+
+
+# ---------------------------------------------------------------------
+# one read, accounted from inside: phases, the server's own time, the
+# profiler's clock (docs/observability.md, "The phases of a read")
+# ---------------------------------------------------------------------
+
+READ_PHASES = ("locate", "probe", "grant", "resume", "map", "verify",
+               "copy", "close")
+
+
+def test_timed_counts_and_spans_and_step_done_walks_no_quantiles():
+    tr = Tracer("client", sample_rate=1.0)
+    counters: dict = {}
+    from curvine_tpu.obs.trace import Timed
+    import time
+    t0 = time.perf_counter()
+    for _ in range(3):
+        with Timed(counters, "read.phase.x", tr.span("phase.x")):
+            pass
+    assert counters["read.phase.x.n"] == 3
+    assert 0.0 <= counters["read.phase.x.s"] <= time.perf_counter() - t0
+    spans = tr.store.drain()
+    assert [s["op"] for s in spans] == ["phase.x"] * 3
+    # `mono` is perf_counter() at the span's start: CLOCK_MONOTONIC
+    assert all(t0 <= s["mono"] <= time.perf_counter() for s in spans)
+    # a disabled tracer still counts
+    off = Tracer("client", enabled=False)
+    with Timed(counters, "read.phase.x", off.span("phase.x")):
+        pass
+    assert counters["read.phase.x.n"] == 4
+    # the feed's hot path gauges nothing; a caller still may
+    p = StepProfiler()
+    p.record("decode", 0.002)
+    p.step_done()
+    assert not p.metrics.gauges
+    p.publish_fractions()
+    assert p.metrics.gauges["stage.decode.frac"] == 1.0
+
+
+class _SlowOpLines(logging.Handler):
+    """The slow-op lines `obs.trace` logs while installed, parsed into
+    dicts of their key=value fields."""
+
+    def __init__(self):
+        super().__init__()
+        self.lines: list[dict] = []
+
+    def emit(self, rec):
+        msg = rec.getMessage()
+        if msg.startswith("slow-op "):
+            fields, attrs = msg.split(" attrs=")
+            self.lines.append({"attrs": attrs, **dict(
+                kv.split("=", 1) for kv in fields.split()[1:])})
+
+    def __enter__(self):
+        logging.getLogger("curvine_tpu.obs.trace").addHandler(self)
+        return self
+
+    def __exit__(self, *exc):
+        logging.getLogger("curvine_tpu.obs.trace").removeHandler(self)
+
+
+def test_detail_spans_never_log_and_share_the_operations_trace():
+    """Every slow operation says so, as before; a detail span (a phase
+    inside one) never does, is ambient like any span, and lands in the
+    ring under the trace id the operation's line prints."""
+    import time
+    slow = Tracer("client", sample_rate=0.0, slow_op_ms=1)
+    with _SlowOpLines() as said:
+        with slow.span("open"):
+            with slow.span("meta.get_block_locations"):
+                with slow.span("phase.probe", detail=True) as ph:
+                    assert current_ctx() is ph.ctx
+                    time.sleep(0.003)
+    assert [m["op"] for m in said.lines] == ["meta.get_block_locations",
+                                             "open"]
+    assert len({m["trace_id"] for m in said.lines}) == 1
+    spans = slow.spans_for(said.lines[0]["trace_id"])
+    assert [s["op"] for s in spans] == [
+        "phase.probe", "meta.get_block_locations", "open"]
+    by_op = {s["op"]: s for s in spans}
+    assert by_op["phase.probe"]["parent"] \
+        == by_op["meta.get_block_locations"]["span_id"]
+
+
+async def test_slow_op_line_leads_to_the_phase_unsampled(tmp_path):
+    """The default mode but for the threshold: nothing sampled, no
+    profiler. Each slow-op line's trace id finds, in the ring, the
+    phases of that very read: a bare mmap_view (the feed) has no span
+    round it, so it is the operation and says so itself; a restore is
+    one trace, and the line of `ckpt.restore` or of any `open` in it
+    leads to every tensor's phases."""
+    import jax
+    import numpy as np
+    from curvine_tpu.tpu.broadcast import load_checkpoint, save_checkpoint
+    async with MiniCluster(workers=1, base_dir=str(tmp_path)) as mc:
+        c = mc.client()
+        c.tracer.sample_rate = 0.0
+        await c.write_all("/slow/a.bin", os.urandom(256 * KB))
+        await save_checkpoint(c, "/slow/ck", {"w": np.ones(4096, np.float32)})
+        c.tracer.slow_s = 1e-9              # every span is slow
+        c.tracer.store.clear()
+        with _SlowOpLines() as said:
+            r = await c.open("/slow/a.bin")
+            assert await r.mmap_view(0, r.len) is not None
+            await r.close()
+            await load_checkpoint(c, "/slow/ck", placer=jax.device_put)
+        ring = c.tracer.store.drain(4096)
+        assert all(s["trace_id"] in {m["trace_id"] for m in said.lines}
+                   for s in ring), "a record no line leads to"
+        # of the steps only the two with no span round them spoke
+        assert [m["op"] for m in said.lines if m["op"].startswith(
+            ("phase.", "mmap_view", "shm_view", "read_all", "ckpt.t",
+             "ckpt.p", "ckpt.ready"))] == ["mmap_view", "phase.close"]
+
+        def trace_of(op, **attrs):
+            (line,) = [m for m in said.lines if m["op"] == op
+                       and all(f"'{k}': '{v}'" in m["attrs"]
+                               for k, v in attrs.items())]
+            return {s["op"] for s in ring
+                    if s["trace_id"] == line["trace_id"]}
+
+        assert {"mmap_view", "shm_view", "phase.probe", "phase.grant",
+                "phase.map", "phase.verify"} <= trace_of("mmap_view")
+        in_restore = trace_of("ckpt.restore")
+        assert {"ckpt.tensor", "open", "meta.get_block_locations",
+                "mmap_view", "phase.probe", "phase.grant", "ckpt.place",
+                "ckpt.ready_wait", "phase.close"} <= in_restore
+        assert trace_of("open", path="/slow/ck/t00000.bin") == in_restore
+
+
+async def test_short_circuit_read_accounts_every_phase(tmp_path):
+    """A one-block file as a view and a two-block file through read_all,
+    both co-located: every phase of the ladder is counted where its work
+    is done, they sum to no more than the reads' wall, and each rung
+    names itself."""
+    import time
+    async with MiniCluster(workers=1, base_dir=str(tmp_path),
+                           block_size=128 * KB) as mc:
+        c = mc.client()
+        c.tracer.sample_rate = 1.0
+        one, two = os.urandom(64 * KB), os.urandom(256 * KB)
+        await c.write_all("/ph/one.bin", one)
+        await c.write_all("/ph/two.bin", two)
+        c.tracer.store.clear()
+        before = dict(c.counters)
+        t0 = time.perf_counter()
+        r = await c.open("/ph/one.bin")
+        view = await r.mmap_view(0, r.len)
+        await r.close()
+        r = await c.open("/ph/two.bin")
+        assert await r.mmap_view(0, r.len) is None      # no one mapping
+        data = await r.read_all()
+        await r.close()
+        wall = time.perf_counter() - t0
+        assert bytes(view) == one and data == two
+
+        def grew(k):
+            return c.counters.get(k, 0) - before.get(k, 0)
+
+        assert grew("read.files") == 2
+        for p in READ_PHASES:
+            assert grew(f"read.phase.{p}.n") >= 1, p
+            assert grew(f"read.phase.{p}.s") >= 0.0, p
+        # the buffer of the two-block read: made, grown once, handed on
+        assert grew("read.phase.copy.n") == 3
+        assert sum(grew(f"read.phase.{p}.s") for p in READ_PHASES) <= wall
+        assert 0.0 <= grew("read.probe.srv_handle_s") \
+            <= grew("read.phase.probe.s")
+        spans = c.tracer.store.drain(4096)
+        served = {(s["op"], s["attrs"]["path"]): s["attrs"]["served_by"]
+                  for s in spans if s["op"] in ("mmap_view", "read_all")}
+        assert served == {("mmap_view", "/ph/one.bin"): "shm",
+                          ("mmap_view", "/ph/two.bin"): "none",
+                          ("read_all", "/ph/two.bin"): "shm"}
+        ops = {s["op"] for s in spans}
+        assert "shm_view" in ops
+        assert {f"phase.{p}" for p in READ_PHASES
+                if p not in ("locate", "resume")} <= ops
+        assert "open" in ops          # the span of `locate`
+
+
+async def test_srv_rides_the_reply_and_leaves_the_header(tmp_path):
+    """The server's [queue_us, handle_us] is popped before the caller
+    sees the header; a peer that sends none gives None; per master call
+    the server's time is within the client's wall."""
+    from curvine_tpu.rpc import RpcCode
+    from curvine_tpu.rpc.frame import (
+        SRV_KEY, Flags, pack, response_for, unpack,
+    )
+    from curvine_tpu.rpc.server import RpcServer
+    async with MiniCluster(workers=1, base_dir=str(tmp_path)) as mc:
+        c = mc.client()
+        await c.write_all("/srv/a.bin", b"s" * (64 * KB))
+        fb = await c.meta.get_block_locations("/srv/a.bin")
+        lb = fb.block_locs[0]
+        conn = await c.pool.get(mc.workers[0].addr)
+        rep = await conn.call(RpcCode.GET_BLOCK_INFO,
+                              data=pack({"block_id": lb.block.id}))
+        # what client/reader.py::_local_path parses, as before
+        info = rep.header or unpack(rep.data) or {}
+        assert SRV_KEY not in info and SRV_KEY not in rep.header
+        assert info["path"] and os.path.exists(info["path"])
+        queue_us, handle_us = rep.srv
+        assert queue_us >= 0 and handle_us >= 0
+        assert rep.srv_seconds() == (queue_us / 1e6, handle_us / 1e6)
+        cs = c.counters
+        assert cs["meta.calls"] >= 2
+        assert 0.0 < cs["meta.srv_handle_s"] <= cs["meta.wall_s"]
+        assert cs["meta.srv_handle_s"] + cs["meta.srv_queue_s"] \
+            <= cs["meta.wall_s"]
+        # telemetry about telemetry counts nothing: an idle client's
+        # flush has no delta of its own making
+        await c.flush_metrics()
+        calls = cs["meta.calls"]
+        await c.flush_metrics()
+        assert cs["meta.calls"] == calls
+
+    # a peer that answers by itself (an older server, the native plane)
+    srv = RpcServer("127.0.0.1", 0, name="old")
+
+    async def answers_itself(msg, sconn):
+        await sconn.send(response_for(msg, header={"x": 1},
+                                      flags=Flags.RESPONSE | Flags.EOF))
+
+    srv.register(RpcCode.EXISTS, answers_itself)
+    await srv.start()
+    try:
+        from curvine_tpu.rpc.client import Connection
+        old = await Connection(srv.addr).connect()
+        try:
+            rep = await old.call(RpcCode.EXISTS)
+            assert rep.header == {"x": 1}
+            assert rep.srv is None and rep.srv_seconds() is None
+        finally:
+            await old.close()
+    finally:
+        await srv.stop()
+
+
+async def test_spans_share_the_profilers_clock(tmp_path, monkeypatch):
+    """While a jax.profiler session is open every span is also a
+    TraceAnnotation cv.<component>.<op>; with none open, none is built."""
+    import jax.profiler
+    built = []
+
+    class Spy(jax.profiler.TraceAnnotation):
+        def __init__(self, name, **kw):
+            built.append(name)
+            super().__init__(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Spy)
+
+    async def one_read(c, path):
+        r = await c.open(path)
+        try:
+            assert await r.mmap_view(0, r.len) is not None
+        finally:
+            await r.close()
+
+    async with MiniCluster(workers=1, base_dir=str(tmp_path / "mc")) as mc:
+        c = mc.client()
+        c.tracer.sample_rate = 0.0          # whatever sampling decided
+        await c.write_all("/prof/a.bin", os.urandom(64 * KB))
+        await one_read(c, "/prof/a.bin")
+        assert built == []
+        trace_dir = str(tmp_path / "trace")
+        jax.profiler.start_trace(trace_dir)
+        try:
+            await one_read(c, "/prof/a.bin")
+        finally:
+            jax.profiler.stop_trace()
+        now = len(built)
+        await one_read(c, "/prof/a.bin")
+        assert len(built) == now            # the session is over
+    assert {"cv.client.open", "cv.client.mmap_view",
+            "cv.client.phase.probe", "cv.client.phase.grant",
+            "cv.worker.get_block_info"} <= set(built)
+    import glob
+    (pb,) = glob.glob(os.path.join(trace_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    names = {e.name
+             for plane in jax.profiler.ProfileData.from_file(pb).planes
+             for line in plane.lines for e in line.events
+             if e.name.startswith("cv.")}
+    assert any(n.startswith("cv.client.phase.") for n in names), names
+    assert any(n.startswith("cv.worker.") for n in names), names
+
+
+def test_obs_rpc_and_master_import_no_jax():
+    """obs/ takes JAX from sys.modules and never imports it: the
+    `cv master` child must stay off the chip."""
+    import subprocess
+    import sys
+    code = ("import sys\n"
+            "import curvine_tpu.obs, curvine_tpu.rpc\n"
+            "import curvine_tpu.master.server\n"
+            "from curvine_tpu.obs.trace import Tracer\n"
+            "with Tracer('master', sample_rate=1.0).span('op'):\n"
+            "    pass\n"
+            "sys.exit(int(any(m == 'jax' or m.startswith('jax.')\n"
+            "                 for m in sys.modules)))\n")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert subprocess.run([sys.executable, "-c", code], cwd=repo,
+                          timeout=120).returncode == 0
+
+
+async def test_load_checkpoint_accounts_its_phases(tmp_path):
+    """ckpt.* counters per restore, one ckpt.tensor span a tensor, and
+    the reader's spans hang off it."""
+    import jax
+    import numpy as np
+    from curvine_tpu.tpu.broadcast import load_checkpoint, save_checkpoint
+    async with MiniCluster(workers=1, base_dir=str(tmp_path)) as mc:
+        c = mc.client()
+        c.tracer.sample_rate = 1.0
+        params = {"w": np.arange(4096, dtype=np.float32).reshape(64, 64),
+                  "b": np.ones(64, np.float32),
+                  "e": np.arange(512, dtype=np.int32)}
+        await save_checkpoint(c, "/ckpt/ph", params)
+        c.tracer.store.clear()
+        before = dict(c.counters)
+        back = await load_checkpoint(c, "/ckpt/ph", placer=jax.device_put)
+        for k, v in params.items():
+            np.testing.assert_array_equal(np.asarray(back[k]), v)
+
+        def grew(k):
+            return c.counters.get(k, 0) - before.get(k, 0)
+
+        assert grew("ckpt.restores") == 1
+        assert grew("ckpt.place.n") == 3 and grew("ckpt.place.s") >= 0
+        assert grew("ckpt.ready_wait.n") == 1
+        assert 0 <= grew("ckpt.ready_wait.s") <= grew("ckpt.wall_s")
+        assert grew("ckpt.place.s") <= grew("ckpt.wall_s")
+        spans = c.tracer.store.drain(4096)
+        tensors = [s for s in spans if s["op"] == "ckpt.tensor"]
+        assert sorted(s["attrs"]["name"] for s in tensors) \
+            == ["t00000.bin", "t00001.bin", "t00002.bin"]
+        assert sorted(s["attrs"]["bytes"] for s in tensors) \
+            == [256, 2048, 16384]
+        assert all(s["attrs"]["blocks"] == 1
+                   and s["attrs"]["served_by"] == "shm" for s in tensors)
+        (root,) = [s for s in spans if s["op"] == "ckpt.restore"]
+        assert root["parent"] == 0 and root["attrs"]["path"] == "/ckpt/ph"
+        assert {s["parent"] for s in tensors} == {root["span_id"]}
+        assert {s["trace_id"] for s in spans} == {root["trace_id"]}
+        ids = {s["span_id"] for s in tensors}
+        for op in ("open", "mmap_view", "ckpt.place", "phase.close"):
+            kids = [s for s in spans if s["op"] == op
+                    and s["parent"] in ids]
+            assert len(kids) == 3, op

@@ -367,7 +367,7 @@ async def test_warm_shm_export_after_heat(tmp_path):
         assert isinstance(view, np.ndarray)
         assert not view.flags.writeable
         assert bytes(view) == payload[8192:8192 + 4096]
-        assert "shm_warm" in r2._served_by()
+        assert "shm_warm" in r2.served_by()
         await r2.close()
 
         # the warm counters ride METRICS_REPORT into the master's

@@ -28,16 +28,18 @@ from curvine_tpu.rpc.frame import pack, unpack
 log = logging.getLogger(__name__)
 
 
-def _block_crc(algo: str, data) -> int | None:
-    """Checksum `data` with the block's commit-time algorithm; None →
-    algorithm unknown to this client (skip verification, e.g. during a
-    rolling upgrade that introduced a new algo on the workers first)."""
+def _block_crc(algo: str, data) -> tuple[int | None, int]:
+    """Checksum `data` with the block's commit-time algorithm, and the
+    bytes that had to be copied to do it (0: hashed where they lie).
+    Checksum None → algorithm unknown to this client (skip verification,
+    e.g. during a rolling upgrade that introduced a new algo on the
+    workers first)."""
     if algo == "crc32":
-        return zlib.crc32(data)
+        return zlib.crc32(data), 0
     if algo == "crc32c":
         from curvine_tpu.common import native
-        return native.crc32c(data)
-    return None
+        return native.crc32c_counted(data)
+    return None, 0
 
 
 class ReadDetector:
@@ -156,9 +158,9 @@ class FsReader:
         self._block_crc: dict[int, tuple[int, str]] = {}
         # shared-memory short-circuit (docs/data-plane.md): the worker
         # advertised a sealed-memfd side channel for these blocks; maps
-        # are block_id -> (memfd, mmap), verified once at map time and
-        # bounded by the same _SC_CACHE_CAP FIFO as the fd cache
-        # (_drop_local closes both)
+        # are block_id -> (memfd, mmap), verified once at map time (on
+        # the fetch thread, in place) and bounded by the same
+        # _SC_CACHE_CAP FIFO as the fd cache (_drop_local closes both)
         self._shm_sock: dict[int, str] = {}
         self._shm_maps: dict[int, tuple[int, mmap.mmap]] = {}
         # block ids whose shm capability is a WARM export (below-MEM
@@ -244,12 +246,15 @@ class FsReader:
             return nullcontext()
         return self.tracer.span(op, attrs=attrs or None, detail=detail)
 
-    def _phase(self, phase: str):
+    def _phase(self, phase: str, into: dict | None = None):
         """One phase of a read, timed at the one place its work is done
         (docs/observability.md has the table): seconds and count into
         the client's counters as read.phase.<phase>.s / .n, always on,
-        and the span phase.<phase>."""
-        return Timed(self.counters, f"read.phase.{phase}",
+        and the span phase.<phase>. A fetch thread never writes the
+        counters: it times into a dict of its own (`into`), which the
+        loop adds when the thread has handed back."""
+        return Timed(self.counters if into is None else into,
+                     f"read.phase.{phase}",
                      self._span(f"phase.{phase}", detail=True))
 
     # ---------------- hole regions ----------------
@@ -308,15 +313,19 @@ class FsReader:
         self._shm_warm.discard(bid)
         ent = self._shm_maps.pop(bid, None)
         if ent is not None:
-            fd, mm = ent
+            self._unmap(*ent)
+
+    @staticmethod
+    def _unmap(fd: int, mm: mmap.mmap | None) -> None:
+        if mm is not None:
             try:
                 mm.close()
             except BufferError:
                 pass
-            try:
-                os.close(fd)
-            except OSError:
-                pass
+        try:
+            os.close(fd)
+        except OSError:
+            pass
 
     async def _local_path(self, lb: LocatedBlock) -> str | None:
         """Resolve the on-disk path for a co-located block (cached)."""
@@ -433,7 +442,10 @@ class FsReader:
         socket → thread; asyncio can't carry ancillary fds), map the
         sealed memfd read-only, verify the full block ONCE against the
         commit-time checksum — after which every read of the block is a
-        pure memory access. None → caller uses the fd/socket paths."""
+        pure memory access. All three are one hand-off to a fetch thread
+        (`_fetch_shm`); the loop compares the checksum it brings back
+        and does everything that touches this reader's state.
+        None → caller uses the fd/socket paths."""
         bid = lb.block.id
         ent = self._shm_maps.get(bid)
         if ent is not None:
@@ -445,11 +457,14 @@ class FsReader:
         spath = self._shm_sock.get(bid)
         if spath is None:
             return None
-        stamps: list[float] = []
+        want, algo = self._block_crc.get(bid, (None, None))
+        if not self.verify:
+            algo = None
+        spent: dict[str, float] = {}
         t_submit = time.perf_counter()
         try:
-            fd, length = await asyncio.to_thread(self._grant, spath, bid,
-                                                 stamps)
+            fd, length, mm, got, copied = await asyncio.to_thread(
+                self._fetch_shm, spath, lb, algo, spent)
         except (LookupError, OSError, ValueError) as e:
             # worker dropped the export / channel gone: stop retrying
             # this block, serve it through fd/socket instead
@@ -458,58 +473,76 @@ class FsReader:
             self._shm_fallback(bid)
             return None
         finally:
-            if len(stamps) == 2:
-                # counted here, on the loop: fetch threads never write
-                # the counters. `resume` is what the hand-off cost beside
-                # the grant itself: submit → thread running, thread
-                # returned → this task running again (the loop, the GIL)
-                grant_s = stamps[1] - stamps[0]
-                self._count("read.phase.grant.s", grant_s)
-                self._count("read.phase.grant.n")
-                self._count("read.phase.resume.s",
-                            time.perf_counter() - t_submit - grant_s)
+            # counted here, on the loop: fetch threads never write the
+            # counters. `resume` is what the hand-off cost beside the
+            # work in the thread: submit → thread running, thread
+            # returned → this task running again (the loop, the GIL)
+            wall = time.perf_counter() - t_submit
+            for key, v in spent.items():
+                self._count(key, v)
+                if key.endswith(".s"):
+                    wall -= v
+            if spent:
+                self._count("read.phase.resume.s", wall)
                 self._count("read.phase.resume.n")
+        if got is not None:
+            self._count_verify(length, copied)
         other = self._shm_maps.get(bid)
         if other is not None:
             # lost a concurrent-fetch race: keep the first mapping
-            os.close(fd)
+            self._unmap(fd, mm)
             return other[1]
-        if length != lb.block.len or length <= 0:
+        if mm is None:
+            # a grant of another length than the block's is a stale
+            # export: stop asking for it. A map that failed may be
+            # tried again
             os.close(fd)
-            self._shm_sock.pop(bid, None)
+            if length != lb.block.len or length <= 0:
+                self._shm_sock.pop(bid, None)
             self._shm_fallback(bid)
             return None
-        try:
-            with self._phase("map"):
-                mm = mmap.mmap(fd, length, access=mmap.ACCESS_READ)
-        except (OSError, ValueError):
-            os.close(fd)
-            self._shm_fallback(bid)
-            return None
-        if self.verify and not self._sc_verify_ok(lb, memoryview(mm)):
-            # _sc_verify_ok flagged the replica and dropped the caches
-            try:
-                mm.close()
-            except BufferError:
-                pass
-            os.close(fd)
+        if got is not None and got != want:
+            self._sc_corrupt(lb)    # flags the replica, drops the caches
+            self._unmap(fd, mm)
             self._shm_fallback(bid)
             return None
         self._shm_maps[bid] = (fd, mm)
         return mm
 
-    def _grant(self, spath: str, bid: int, stamps: list) -> tuple[int, int]:
-        """`fetch_block_fd` on the fetch thread, under the span of the
-        phase `grant`; `stamps` gets when it began and ended there, so
-        the awaiting task can tell the grant from its own wait to run
-        again."""
+    def _fetch_shm(self, spath: str, lb: LocatedBlock, algo: str | None,
+                   spent: dict) -> tuple:
+        """On the fetch thread: `fetch_block_fd` (phase `grant`), map the
+        memfd (`map`) and, given the commit-time `algo`, checksum the
+        mapping where it lies (`verify`). The first touch of every page
+        and the hash run here, without the GIL, not on the loop.
+        Each phase has its span and leaves its seconds in `spent`, so the
+        awaiting task can tell the work from its own wait to run again.
+        → (fd, granted length, mapping or None, checksum or None, bytes
+        copied to hash); touches nothing of the reader's state."""
         from curvine_tpu.worker.shm import fetch_block_fd
-        with self._span("phase.grant", detail=True):
-            stamps.append(time.perf_counter())
+        with self._phase("grant", spent):
+            fd, length = fetch_block_fd(spath, lb.block.id)
+        mm = got = None
+        copied = 0
+        if length == lb.block.len and length > 0:
+            # a block that is verified has every page read right away:
+            # the kernel maps them all in this one call (MAP_POPULATE)
+            # at a tenth of what a trap a page costs the hash (0.6
+            # against 6.3 us a page on a v5e host's VM, and the traps
+            # of all threads of a process take turns). mmap() runs
+            # without the GIL. Read-only either way (PROT_READ)
+            flags = mmap.MAP_SHARED | (
+                mmap.MAP_POPULATE if algo is not None else 0)
             try:
-                return fetch_block_fd(spath, bid)
-            finally:
-                stamps.append(time.perf_counter())
+                with self._phase("map", spent):
+                    mm = mmap.mmap(fd, length, flags=flags,
+                                   prot=mmap.PROT_READ)
+            except (OSError, ValueError):
+                pass
+        if mm is not None and algo is not None:
+            with self._phase("verify", spent):
+                got, copied = _block_crc(algo, mm)
+        return fd, length, mm, got, copied
 
     async def _shm_read_into(self, lb: LocatedBlock, block_off: int,
                              out) -> int:
@@ -586,19 +619,31 @@ class FsReader:
                 log.debug("corrupt-replica report failed: %s", e)
         asyncio.ensure_future(_report())
 
+    def _count_verify(self, hashed: int, copied: int) -> None:
+        self._count("read.verify.bytes", hashed)
+        self._count("read.verify.copied_bytes", copied)
+
     def _sc_verify_ok(self, lb: LocatedBlock, data) -> bool:
-        """Verify a FULL-block short-circuit read against the commit-time
-        checksum from GET_BLOCK_INFO. On mismatch: flag the replica and
-        drop every local cache for the block so this read (and the next)
-        goes through the remote failover path instead."""
+        """Verify a FULL-block read of the fd rung against the
+        commit-time checksum from GET_BLOCK_INFO. On mismatch: flag the
+        replica and drop every local cache for the block so this read
+        (and the next) goes through the remote failover path instead."""
         ent = self._block_crc.get(lb.block.id)
         if ent is None:
             return True
         want, algo = ent
         with self._phase("verify"):
-            got = _block_crc(algo, data)
-        if got is None or got == want:
+            got, copied = _block_crc(algo, data)
+        if got is None:
             return True
+        self._count_verify(len(data), copied)
+        if got == want:
+            return True
+        self._sc_corrupt(lb)
+        return False
+
+    def _sc_corrupt(self, lb: LocatedBlock) -> None:
+        """A short-circuit copy of the block failed verification."""
         self._flag_corrupt(lb, self._pick_loc(lb))
         bid = lb.block.id
         self._local_paths[bid] = None
@@ -611,7 +656,6 @@ class FsReader:
             except OSError:
                 pass
         self._drop_shm(bid)
-        return False
 
     # ---------------- short-circuit read accounting ----------------
 
@@ -987,13 +1031,15 @@ class FsReader:
                 if self.verify and block_off == 0 \
                         and got == lb.block.len \
                         and eof.get("block_crc32") is not None:
-                    have = _block_crc(eof.get("block_crc_algo", ""),
-                                      sink[:got])
-                    if have is not None and have != eof["block_crc32"]:
-                        self._flag_corrupt(lb, loc)
-                        raise err.AbnormalData(
-                            f"block {lb.block.id} from {addr} failed "
-                            f"checksum verification")
+                    have, copied = _block_crc(
+                        eof.get("block_crc_algo", ""), sink[:got])
+                    if have is not None:
+                        self._count_verify(got, copied)
+                        if have != eof["block_crc32"]:
+                            self._flag_corrupt(lb, loc)
+                            raise err.AbnormalData(
+                                f"block {lb.block.id} from {addr} "
+                                f"failed checksum verification")
                 if self.health is not None:
                     self.health.ok(addr)
                 # readinto scatter: payload bytes landed directly in
@@ -1323,12 +1369,15 @@ class FsReader:
                     eof = m.header
             if self.verify and offset == 0 and len(out) == lb.block.len \
                     and eof.get("block_crc32") is not None:
-                have = _block_crc(eof.get("block_crc_algo", ""), out)
-                if have is not None and have != eof["block_crc32"]:
-                    self._flag_corrupt(lb, loc)
-                    raise err.AbnormalData(
-                        f"block {block_id} from {addr} failed "
-                        f"checksum verification")
+                have, copied = _block_crc(eof.get("block_crc_algo", ""),
+                                          out)
+                if have is not None:
+                    self._count_verify(len(out), copied)
+                    if have != eof["block_crc32"]:
+                        self._flag_corrupt(lb, loc)
+                        raise err.AbnormalData(
+                            f"block {block_id} from {addr} failed "
+                            f"checksum verification")
         except err.CurvineError:
             if self.health is not None:
                 self.health.fail(addr, worker_id=loc.worker_id)

@@ -63,7 +63,8 @@ def _load():
         try:
             lib = ctypes.CDLL(so)
             lib.cv_crc32c.restype = ctypes.c_uint32
-            lib.cv_crc32c.argtypes = [ctypes.c_char_p, ctypes.c_size_t,
+            # void*: bytes, a ctypes array or a bare address alike
+            lib.cv_crc32c.argtypes = [ctypes.c_void_p, ctypes.c_size_t,
                                       ctypes.c_uint32]
             lib.cv_xxh64.restype = ctypes.c_uint64
             lib.cv_xxh64.argtypes = [ctypes.c_char_p, ctypes.c_size_t,
@@ -100,19 +101,37 @@ def available() -> bool:
 
 
 def crc32c(data, seed: int = 0) -> int:
+    return crc32c_counted(data, seed)[0]
+
+
+def crc32c_counted(data, seed: int = 0) -> tuple[int, int]:
+    """(crc32c of `data`, bytes that had to be copied to hash it). Any
+    contiguous buffer is hashed where it lies: a read-only one (a
+    sealed shm mapping, a slice of the caller's bytes) has no ctypes
+    `from_buffer`, so its address is taken through numpy. The hash is a
+    CDLL call: no GIL while it runs, nor in the page faults inside it."""
     lib = _load()
-    if lib is None:
-        return _crc32c_py(data, seed)
-    if isinstance(data, bytes):
-        return lib.cv_crc32c(data, len(data), seed)
+    owned = isinstance(data, bytes)
     n = data.nbytes if isinstance(data, memoryview) else len(data)
+    if lib is None:
+        return _crc32c_py(data, seed), 0 if owned else n
+    if owned:
+        return lib.cv_crc32c(data, n, seed), 0
     try:
         # zero-copy for writable buffers (read-path views into sinks):
         # hashing at hardware speed is pointless behind a memcpy
         buf = (ctypes.c_char * n).from_buffer(data)
     except TypeError:
-        buf = bytes(data)
-    return lib.cv_crc32c(buf, n, seed)
+        import numpy as np
+        try:
+            # `keep` holds the buffer export for as long as the call
+            keep = np.frombuffer(data, dtype=np.uint8)
+        except (TypeError, ValueError, BufferError):
+            # no buffer protocol, or not contiguous: the one case left
+            # that hashes a copy
+            return lib.cv_crc32c(bytes(data), n, seed), n
+        return lib.cv_crc32c(keep.ctypes.data, n, seed), 0
+    return lib.cv_crc32c(buf, n, seed), 0
 
 
 def has_gf() -> bool:

@@ -27,6 +27,58 @@ def test_crc32c_vectors():
     assert native.crc32c(b, seed=native.crc32c(a)) == native.crc32c(data)
 
 
+def _readonly(kind: str, data: bytes):
+    if kind == "memoryview":
+        return memoryview(data)
+    if kind == "numpy":
+        import numpy as np
+        return np.frombuffer(data, dtype=np.uint8)
+    import mmap
+    fd = os.memfd_create("cv-test-crc")
+    try:
+        os.write(fd, data)
+        return mmap.mmap(fd, len(data), access=mmap.ACCESS_READ)
+    finally:
+        os.close(fd)
+
+
+@pytest.mark.parametrize("kind", ["mmap", "numpy", "memoryview"])
+@pytest.mark.parametrize("seed", [0, 0x9E3779B9])
+def test_crc32c_hashes_readonly_buffers_in_place(kind, seed):
+    """A read-only buffer (a sealed shm mapping, a slice of the caller's
+    bytes) is hashed at its own address: the right checksum, chained
+    from any seed, and the block is not allocated a second time."""
+    import tracemalloc
+    if not native.available():
+        pytest.skip("native unavailable")
+    data = os.urandom(2 * MB + 13)
+    buf = _readonly(kind, data)
+    assert memoryview(buf).readonly
+    want = native._crc32c_py(data[:4096], seed)
+    assert native.crc32c(memoryview(buf)[:4096], seed) == want
+    want = native.crc32c(data, seed)         # bytes: the path it had
+    native.crc32c(buf, seed)                 # numpy's own imports, once
+    tracemalloc.start()
+    got, copied = native.crc32c_counted(buf, seed)
+    peak = tracemalloc.get_traced_memory()[1]
+    tracemalloc.stop()
+    assert (got, copied) == (want, 0)
+    assert peak < len(data) // 4
+    if kind == "mmap":
+        buf.close()                          # no export left behind
+
+
+def test_crc32c_counts_the_copy_it_cannot_avoid():
+    """Writable buffers hash in place as before; a buffer that is not
+    contiguous is the one input still copied, and says so."""
+    data = os.urandom(64 * 1024)
+    assert native.crc32c_counted(bytearray(data)) \
+        == (native.crc32c(data), 0)
+    strided = memoryview(data)[::2]
+    assert native.crc32c_counted(strided) \
+        == (native.crc32c(bytes(strided)), len(data) // 2)
+
+
 def test_xxh64_vectors():
     if not native.available():
         pytest.skip("native unavailable")

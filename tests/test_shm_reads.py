@@ -14,6 +14,7 @@ import gc
 import mmap
 import os
 import sys
+import threading
 
 import numpy as np
 import pytest
@@ -32,6 +33,13 @@ pytestmark = pytest.mark.skipif(
 
 def _fd_count() -> int:
     return len(os.listdir("/proc/self/fd"))
+
+
+def _fd_target(fd: str) -> str:
+    try:
+        return os.readlink(f"/proc/self/fd/{fd}")
+    except OSError:
+        return ""
 
 
 # ---------------- the hit path: zero-RPC data plane ----------------
@@ -139,8 +147,11 @@ async def test_shm_fd_lru_churn_no_leak(tmp_path):
         async def churn(rounds: int) -> None:
             for i in range(rounds):
                 off = (i % n_blocks) * 64 * 1024
-                got = await r.pread_view(off, 4096)
-                assert bytes(got) == payload[off:off + 4096]
+                # two at once: each first read of a block is a race of
+                # two fetches, and the loser's mapping must go too
+                for got in await asyncio.gather(r.pread_view(off, 4096),
+                                                r.pread_view(off, 4096)):
+                    assert bytes(got) == payload[off:off + 4096]
 
         await churn(64)              # reach steady state
         gc.collect()
@@ -154,6 +165,141 @@ async def test_shm_fd_lru_churn_no_leak(tmp_path):
         assert mc.workers[0].shm.evictions > 0
         await r.close()
         assert not r._shm_maps
+        await c.close()
+
+
+async def test_shm_first_map_verifies_off_the_loop_in_place(
+        tmp_path, monkeypatch):
+    """The checksum of a first map runs on the fetch thread, not on the
+    client's loop, over the sealed mapping itself: every byte of the
+    block is hashed once and none is copied to be hashed."""
+    from curvine_tpu.client import reader as creader
+    conf = ClusterConf()
+    conf.data_dir = str(tmp_path)
+    async with MiniCluster(workers=1, conf=conf, base_dir=str(tmp_path),
+                           block_size=4 * MB) as mc:
+        c = mc.client()
+        payload = os.urandom(2 * MB + 4096)
+        await c.write_all("/shm/v.bin", payload)
+        seen = []
+        real = creader._block_crc
+
+        def spy(algo, data):
+            seen.append((threading.get_ident(), type(data),
+                         memoryview(data).readonly, len(data)))
+            return real(algo, data)
+
+        monkeypatch.setattr(creader, "_block_crc", spy)
+        before = dict(c.counters)
+
+        def grew(key):
+            return c.counters.get(key, 0) - before.get(key, 0)
+
+        r = await c.open("/shm/v.bin")
+        view = await r.mmap_view(0, len(payload))
+        assert bytes(view) == payload
+        assert bytes(await r.pread_view(4096, 4096)) \
+            == payload[4096:8192]            # a warm map hashes nothing
+        assert len(seen) == 1
+        tid, kind, readonly, n = seen[0]
+        assert tid != threading.get_ident()
+        assert kind is mmap.mmap and readonly and n == len(payload)
+        assert grew("read.verify.bytes") == len(payload)
+        assert grew("read.verify.copied_bytes") == 0
+        assert "read.verify.copied_bytes" in c.counters
+        # stamped on the thread, added on the loop, once a first map
+        for p in ("grant", "map", "verify", "resume"):
+            assert grew(f"read.phase.{p}.n") == 1, p
+            assert grew(f"read.phase.{p}.s") >= 0.0, p
+        del view
+        await r.close()
+        await c.close()
+
+
+async def test_shm_corrupt_export_is_refused(tmp_path, monkeypatch):
+    """An export whose bytes differ from the commit-time checksum never
+    reaches the caller: the shm rung refuses it (mismatch counted, the
+    replica reported, the mapping and its fd gone) and the read is
+    served by the verified remote path."""
+    from curvine_tpu.rpc import RpcCode
+    conf = ClusterConf()
+    conf.data_dir = str(tmp_path)
+    async with MiniCluster(workers=1, conf=conf, base_dir=str(tmp_path),
+                           block_size=MB) as mc:
+        c = mc.client()
+        payload = os.urandom(256 * 1024)
+        await c.write_all("/shm/bad.bin", payload)
+        real_fetch = wshm.fetch_block_fd
+
+        def tampered(sock_path, block_id, timeout=5.0):
+            fd, n = real_fetch(sock_path, block_id, timeout)
+            data = bytearray(os.pread(fd, n, 0))
+            os.close(fd)
+            data[n // 2] ^= 0x01
+            bad = os.memfd_create("cv-test-bad")
+            os.write(bad, data)
+            return bad, n
+
+        monkeypatch.setattr(wshm, "fetch_block_fd", tampered)
+        r = await c.open("/shm/bad.bin")
+        reported = []
+        real_call = r.fs.call
+
+        async def call(code, *a, **kw):
+            if code == RpcCode.REPORT_UNDER_REPLICATED_BLOCKS:
+                reported.append(a[0] if a else kw)
+            return await real_call(code, *a, **kw)
+
+        monkeypatch.setattr(r.fs, "call", call)
+        bid = r.blocks.block_locs[0].block.id
+        assert await r.mmap_view(0, len(payload)) is None
+        assert bid not in r._shm_maps and bid not in r._shm_sock
+        gc.collect()
+        assert not [fd for fd in os.listdir("/proc/self/fd")
+                    if "cv-test-bad" in _fd_target(fd)]
+        assert await r.read_all() == payload
+        assert c.counters.get("read.checksum_mismatch", 0) == 1
+        assert c.counters.get("read.shm_fallbacks", 0) == 1
+        assert c.counters.get("read.shm_hits", 0) == 0
+        await asyncio.sleep(0.05)            # the report is fire-and-forget
+        assert [m["block_ids"] for m in reported] == [[bid]]
+        await r.close()
+        await c.close()
+
+
+async def test_shm_concurrent_first_reads_share_one_mapping(tmp_path):
+    """Concurrent first reads of one block each fetch, map and verify
+    on a thread of their own; one mapping is kept, the losers unmap and
+    close theirs, and nothing outlives close()."""
+    conf = ClusterConf()
+    conf.data_dir = str(tmp_path)
+    async with MiniCluster(workers=1, conf=conf, base_dir=str(tmp_path),
+                           block_size=MB) as mc:
+        c = mc.client()
+        payload = os.urandom(512 * 1024)
+        await c.write_all("/shm/race.bin", payload)
+        r = await c.open("/shm/race.bin")
+        lb = r.blocks.block_locs[0]
+        await r._local_path(lb)              # probe once, ahead of the race
+
+        def memfds() -> int:
+            # the worker (in this process too) holds the export's own
+            gc.collect()
+            return len([fd for fd in os.listdir("/proc/self/fd")
+                        if f"cv-blk-{lb.block.id}" in _fd_target(fd)])
+
+        base = memfds()      # (another file's block 1, in this process)
+        maps = await asyncio.gather(*(r._shm_map(lb) for _ in range(6)))
+        assert all(m is maps[0] for m in maps) and maps[0] is not None
+        assert list(r._shm_maps) == [lb.block.id]
+        assert c.counters.get("read.phase.grant.n", 0) == 6   # a real race
+        assert c.counters.get("read.verify.copied_bytes", 0) == 0
+        assert bytes(maps[0][:4096]) == payload[:4096]
+        del maps
+        # the export, the one map's memfd and the dup that mmap keeps
+        assert memfds() == base + 3
+        await r.close()
+        assert not r._shm_maps and memfds() == base + 1
         await c.close()
 
 

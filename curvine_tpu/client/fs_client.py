@@ -616,6 +616,15 @@ class FsClient:
                           replicas: int = 1) -> str:
         return await self.submit_job("load", path, recursive, replicas)
 
+    async def submit_load_if_absent(self, path: str) -> tuple[str, str]:
+        """Load one file unless a load of that path is live at the
+        master: (job id, outcome), the outcome "submitted" or "deduped"
+        (that path's live job)."""
+        rep = await self.call(RpcCode.SUBMIT_JOB, {
+            "kind": "load", "path": path, "recursive": False,
+            "replicas": 1, "if_absent": True}, mutate=True)
+        return rep["job_id"], rep["outcome"]
+
     async def prefetch_window(self, path: str, cursor: int = 0,
                               window: int = 8, epoch: int = 0,
                               seed: int = 0) -> dict:

@@ -12,7 +12,7 @@ import logging
 
 from curvine_tpu.common import errors as err
 from curvine_tpu.common.conf import ClusterConf
-from curvine_tpu.common.types import StorageType
+from curvine_tpu.common.types import StorageState, StorageType
 from curvine_tpu.client.fs_client import FsClient
 from curvine_tpu.client.reader import FsReader
 from curvine_tpu.client.writer import FsWriter
@@ -57,7 +57,10 @@ class CurvineClient:
         if cc.tenant:
             from curvine_tpu.common.qos import set_process_tenant
             set_process_tenant(cc.tenant)
-        self._mount_cache: dict[str, object] = {}
+        # SUBMIT_JOB calls of auto-cache loads not answered yet, held so
+        # that close() can cancel them; the master keeps one live load a
+        # path, whoever asks and however often
+        self._load_submits: set = set()
         # client-side IO counters: short-circuit reads/writes bypass the
         # worker entirely, so their bytes are invisible to worker metrics
         # — pushed to the master (METRICS_REPORT) so dashboards see the
@@ -77,6 +80,8 @@ class CurvineClient:
         if self._metrics_task is not None:
             self._metrics_task.cancel()
             self._metrics_task = None
+        for t in list(self._load_submits):
+            t.cancel()
         try:
             await self.flush_metrics()
         except Exception:      # noqa: BLE001 — best-effort on teardown
@@ -182,6 +187,12 @@ class CurvineClient:
         with Timed(self.counters, "read.phase.locate",
                    self.tracer.span("open", attrs={"path": path})):
             fb = await self.meta.get_block_locations(path)
+        if _freed(fb.status) and fb.status.len:
+            # an FsReader over the empty block list would read the
+            # whole file as a hole, zeros
+            raise err.BlockNotFound(
+                f"{path}: freed from the cache, its bytes live in the "
+                f"under-store alone (unified_open reads them there)")
         self.counters["read.files"] = self.counters.get("read.files", 0) + 1
         cc = self.conf.client
         return FsReader(self.meta, path, fb, self.pool,
@@ -306,10 +317,14 @@ class CurvineClient:
         against cachedness — but a FREED file (TTL free / `cv free`:
         blocks dropped, storage state flipped to UFS) is not a hole
         file; its bytes live only in the under-store now."""
-        from curvine_tpu.common.types import StorageState
-        if st.storage_policy.state == StorageState.UFS:
+        if _freed(st):
             return False
         fb = await self.meta.get_block_locations(path)
+        # `st` may be a leased copy from before the master freed the
+        # file under cache pressure; the status that came with the
+        # block list is the master's own
+        if _freed(fb.status):
+            return False
         # a committed stripe retires its replicas, so empty locs is the
         # NORMAL cached state for an erasure-coded block — it serves
         # through the cells (degraded decode included)
@@ -319,18 +334,75 @@ class CurvineClient:
         """Open preferring cache; uncached files under a mount stream
         directly from the UFS (FsReader-compatible UfsReader). Cached
         reads are wrapped so a mid-stream replica loss falls back to
-        the mounted object transparently (FallbackReader)."""
-        st = await self.meta.file_status(path)
-        try:
-            cached = st.len == 0 or await self._has_cached_blocks(path, st)
-        except err.FileNotFound:
-            cached = False      # UFS-only object: no inode yet
-        if cached:
-            return FallbackReader(self, path, await self.open(path), st)
+        the mounted object transparently (FallbackReader). Under an
+        `auto_cache` mount a miss also asks the master for an
+        asynchronous load of the file (docs/caching.md, "Auto-cache on
+        open"); this read is served from the UFS either way."""
+        with self.tracer.span("unified_open", attrs={"path": path}) as sp:
+            st = await self.meta.file_status(path)
+            try:
+                cached = not _being_loaded(st) and (
+                    st.len == 0 or await self._has_cached_blocks(path, st))
+            except err.FileNotFound:
+                cached = False      # UFS-only object: no inode yet
+            if cached:
+                try:
+                    r = await self.open(path)
+                except err.BlockNotFound:
+                    pass        # the master freed it between the answers
+                else:
+                    if not _being_loaded(r.blocks.status):
+                        sp.set_attr("served_by", "cache")
+                        return FallbackReader(self, path, r, st)
+                    # a load replaced the copy between the two answers:
+                    # its bytes so far are not the object
+                    await r.close()
+            mount, ufs, uri = await self._ufs_for(path)
+            if _being_loaded(st):
+                # the inode is the load's own, still empty: the object's
+                # length is the under-store's to say
+                ust = await ufs.stat(uri)
+                if ust is None:
+                    raise err.FileNotFound(uri)
+                length = ust.len
+            else:
+                length = st.len
+            sp.set_attr("served_by", "ufs")
+            return self._ufs_reader(path, mount, ufs, uri, length)
+
+    def _ufs_reader(self, path: str, mount, ufs, uri: str, length: int):
+        """A reader over the under-store's object for one read the
+        cache did not serve — a miss, or a cached block dropped under
+        its reader — accounted (read.ufs.files) and, under an
+        auto_cache mount, followed by a load of the file."""
         from curvine_tpu.client.ufs_reader import UfsReader
-        mount, ufs, uri = await self._ufs_for(path)
-        return UfsReader(ufs, uri, st.len,
-                         chunk_size=self.conf.client.read_chunk_size)
+        c = self.counters
+        c["read.ufs.files"] = c.get("read.ufs.files", 0) + 1
+        if mount.auto_cache:
+            self._submit_load(path)
+        return UfsReader(ufs, uri, length,
+                         chunk_size=self.conf.client.read_chunk_size,
+                         counters=c, tracer=self.tracer)
+
+    def _submit_load(self, path: str) -> None:
+        """Ask the master, in the background, to load one file into the
+        cache unless a load of it is live there. Advisory: a refusal is
+        logged and the read that asked is not held up or failed."""
+        import asyncio
+        c = self.counters
+
+        async def submit():
+            try:
+                _, outcome = await self.meta.submit_load_if_absent(path)
+                key = "cache.load." + outcome
+                c[key] = c.get(key, 0) + 1
+            except err.CurvineError as e:
+                log.debug("auto-cache load of %s not submitted: %s",
+                          path, e)
+
+        t = asyncio.ensure_future(submit())
+        self._load_submits.add(t)
+        t.add_done_callback(self._load_submits.discard)
 
     async def content_summary(self, path: str) -> dict:
         """Recursive length/file/dir counts: ONE master RPC for pure
@@ -450,6 +522,21 @@ class CurvineClient:
             log.debug("cache copy of %s failed: %s", path, e)
 
 
+def _freed(st) -> bool:
+    """The master dropped the file's blocks and kept its inode (TTL
+    free, `cv free`, cache pressure): its bytes live in the under-store
+    alone, and its empty block list is not a hole."""
+    return st.storage_policy.state == StorageState.UFS
+
+
+def _being_loaded(st) -> bool:
+    """An inode a UFS→cache load has created and not completed yet
+    (load_from_ufs stamps ufs_mtime at create): its bytes so far are not
+    the object, so readers go to the under-store until it completes."""
+    return not st.is_complete and not st.is_dir \
+        and bool(st.storage_policy.ufs_mtime)
+
+
 # errors that mean "the cached copy is unreachable", not "the request is
 # wrong" — only these divert a read to the UFS
 _FALLBACK_CODES = frozenset({
@@ -521,7 +608,6 @@ class FallbackReader:
             raise err.AbnormalData(
                 f"{self._path}: UFS object shrank to {ust.len} below "
                 f"read offset {resume}") from cause
-        from curvine_tpu.client.ufs_reader import UfsReader
         try:
             await self._r.close()
         except Exception:            # noqa: BLE001 — old stream is dead
@@ -531,11 +617,15 @@ class FallbackReader:
         self._client.tracer.span(
             "ufs_fallback", attrs={"path": self._path, "resume": resume}
         ).error(cause).finish()
-        log.warning("read fallback to UFS for %s at offset %d (%s)",
-                    self._path, resume, cause)
-        self._r = UfsReader(ufs, uri, ust.len,
-                            chunk_size=self._client.conf.client
-                            .read_chunk_size)
+        # routine where the cache is smaller than the set (a block can
+        # be dropped between the master's answer and the read): counted
+        # and traced, not a warning
+        log.info("read fallback to UFS for %s at offset %d (%s)",
+                 self._path, resume, cause)
+        c = self._client.counters
+        c["read.ufs_fallbacks"] = c.get("read.ufs_fallbacks", 0) + 1
+        self._r = self._client._ufs_reader(self._path, mount, ufs, uri,
+                                           ust.len)
         self._fell_back = True
 
     async def _do(self, op: str, *args):

@@ -1228,7 +1228,8 @@ class MasterFilesystem:
 
     def worker_block_report(self, worker_id: int, held: dict,
                             storage_types: dict,
-                            incremental: bool = False) -> dict:
+                            incremental: bool = False,
+                            removed: list | None = None) -> dict:
         w = self.workers.workers.get(worker_id)
         if w is not None and w.state == WorkerState.DECOMMISSIONED:
             # a drained worker's copies are surplus and were purged from
@@ -1239,6 +1240,11 @@ class MasterFilesystem:
         storage_types = {int(k): int(v) for k, v in storage_types.items()}
         orphans = self.blocks.apply_report(worker_id, held, storage_types,
                                            incremental=incremental)
+        # blocks the worker dropped under cache pressure since it last
+        # said so: their locations are gone now, not at the next full
+        # report
+        for bid in removed or ():
+            self.blocks.remove_replica(int(bid), worker_id)
         if not incremental:
             self.workers.mark_reported(worker_id)
         # report-driven len bumps are durable but not journaled: persist

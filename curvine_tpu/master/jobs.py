@@ -9,6 +9,7 @@ creates one task per file, and dispatches tasks to live workers
 from __future__ import annotations
 
 import asyncio
+import collections
 import itertools
 import logging
 import uuid
@@ -21,12 +22,22 @@ from curvine_tpu.rpc.frame import pack
 
 log = logging.getLogger(__name__)
 
+_LIVE = (JobState.PENDING, JobState.RUNNING)
+# finished jobs kept queryable (job_status after the fact); older ones
+# leave the table and the store. A client that loads on every miss
+# submits a job a file: without a bound the table grows with the traffic.
+MAX_FINISHED_JOBS = 1024
+
 
 class JobManager:
     def __init__(self, fs, mounts, dispatch_interval_s: float = 0.2):
         self.fs = fs
         self.mounts = mounts
         self.jobs: dict[str, JobInfo] = {}
+        # ids of finished jobs, oldest first (see _retire)
+        self._finished: collections.deque[str] = collections.deque()
+        # path -> id of the live load job of exactly that path
+        self._live_loads: dict[str, str] = {}
         self.pool = ConnectionPool(size=1)
         self.dispatch_interval_s = dispatch_interval_s
         self._pending: asyncio.Queue[TaskInfo] = asyncio.Queue()
@@ -45,9 +56,50 @@ class JobManager:
                       state=JobState.PENDING, create_ms=now_ms(),
                       recursive=recursive, replicas=replicas)
         self.jobs[job.job_id] = job
+        if kind == "load":
+            self._live_loads[path] = job.job_id
         self._persist(job)
         self._plan(job)
         return job
+
+    def submit_load_if_absent(self, path: str,
+                              replicas: int = 1) -> tuple[str, str]:
+        """A client's auto-cache on a miss: (job id, outcome). One live
+        load a path, whoever asked first and however many ask meanwhile
+        ("deduped"); else a new single-file load ("submitted")."""
+        live = self.live_load(path)
+        if live is not None:
+            return live.job_id, "deduped"
+        return self.submit("load", path, recursive=False,
+                           replicas=replicas).job_id, "submitted"
+
+    def live_load(self, path: str) -> JobInfo | None:
+        """The load job of exactly `path` that has not finished, if any:
+        what a client's auto-cache asks before it submits another."""
+        job = self.jobs.get(self._live_loads.get(path, ""))
+        return job if job is not None and job.state in _LIVE else None
+
+    def live_loads(self) -> int:
+        return len(self._live_loads)
+
+    def _retire(self, job: JobInfo) -> None:
+        """A job has reached a final state: it stays queryable until
+        MAX_FINISHED_JOBS newer ones have finished, then leaves the
+        table and the store."""
+        if getattr(job, "_retired", False):
+            return
+        job._retired = True
+        if self._live_loads.get(job.path) == job.job_id:
+            del self._live_loads[job.path]
+        self._finished.append(job.job_id)
+        while len(self._finished) > MAX_FINISHED_JOBS:
+            old = self._finished.popleft()
+            if self.jobs.pop(old, None) is not None:
+                try:
+                    self.fs._log("job_del", {"job_id": old})
+                except err.CurvineError as e:
+                    log.warning("dropping finished job %s failed: %s",
+                                old, e)
 
     def _plan(self, job: JobInfo) -> None:
         if job.kind == "load":
@@ -189,6 +241,10 @@ class JobManager:
             self.fs._log("job_put", {"job": wire})
         except err.CurvineError as e:
             log.warning("persisting job %s failed: %s", job.job_id, e)
+        if job.state not in _LIVE:
+            # every path to a final state persists it, so this is the
+            # one place a job joins the bounded history
+            self._retire(job)
 
     def recover(self) -> int:
         """Resume interrupted jobs from the durable store (called when
@@ -197,9 +253,10 @@ class JobManager:
         pruned. Returns the number of jobs resumed."""
         resumed = 0
         cutoff = now_ms() - 7 * 24 * 3600 * 1000
+        finished = []
         for wire in list(self.fs.store.iter_jobs()):
             job = JobInfo.from_wire(wire)
-            if job.state in (JobState.PENDING, JobState.RUNNING):
+            if job.state in _LIVE:
                 # the DURABLE state is the truth: re-plan even when an
                 # in-RAM record exists (a demoted tenure drained its task
                 # queue, so those tasks are gone). Load/export tasks are
@@ -212,6 +269,8 @@ class JobManager:
                     # advise extends THIS job; _plan_prefetch resumes
                     # from the persisted cursor, not the dataset start
                     self._prefetch[(job.path, job.epoch)] = job.job_id
+                elif job.kind == "load":
+                    self._live_loads[job.path] = job.job_id
                 self._plan(job)
                 resumed += 1
                 log.info("resuming %s job %s on %s", job.kind,
@@ -224,7 +283,13 @@ class JobManager:
                         pass
                     self.jobs.pop(job.job_id, None)
                     continue
-                self.jobs.setdefault(job.job_id, job)
+                if job.job_id not in self.jobs:
+                    self.jobs[job.job_id] = job
+                    finished.append(job)
+        # finished jobs known only from the store join the bounded
+        # history, oldest first
+        for job in sorted(finished, key=lambda j: j.finish_ms):
+            self._retire(job)
         return resumed
 
     async def _plan_export(self, job: JobInfo, recursive: bool) -> None:

@@ -33,6 +33,10 @@ class QuotaManager:
         self.low_water = low_water
         self.check_interval_s = check_interval_s
         self.usage_ttl_s = usage_ttl_s
+        # called with the path of each file freed under cache pressure:
+        # the master pushes the invalidation to clients that hold its
+        # status under a read lease (their copy still says "cached")
+        self.on_free = None
         # quota'd-dir usage cache: inode id -> [bytes, files, expiry].
         # The subtree walk is O(subtree) — unaffordable per create on big
         # namespaces — so enforcement reads a TTL'd snapshot and bumps it
@@ -184,6 +188,12 @@ class QuotaManager:
                 freed += 1
             except err.CurvineError as e:
                 log.debug("evict %s failed: %s", path, e)
+                continue
+            if self.on_free is not None:
+                try:
+                    self.on_free(path)
+                except Exception:   # noqa: BLE001 — push best-effort
+                    log.exception("on_free hook for %s", path)
         if freed:
             log.info("cache pressure: freed %d cold files", freed)
         return freed

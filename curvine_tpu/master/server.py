@@ -115,6 +115,9 @@ class MasterServer:
                 lambda path: self.leases.invalidate([path])
         from curvine_tpu.master.quota import QuotaManager
         self.quota = QuotaManager(self.fs)
+        if self.leases is not None:
+            self.quota.on_free = \
+                lambda path: self.leases.invalidate([path])
         from curvine_tpu.master.locks import LockManager
         self.locks = LockManager()
         self.acl = AclEnforcer(self.fs, enabled=mc.acl_enabled,
@@ -1207,7 +1210,8 @@ class MasterServer:
     def _worker_block_report(self, q):
         return self.fs.worker_block_report(
             q["worker_id"], q.get("blocks", {}), q.get("storage_types", {}),
-            incremental=q.get("incremental", False))
+            incremental=q.get("incremental", False),
+            removed=q.get("removed"))
 
     def _replacement_worker(self, q):
         w = self.replication.replacement_worker(
@@ -1325,10 +1329,16 @@ class MasterServer:
 
     # --- jobs ---
     def _submit_job(self, q):
-        job = self.jobs.submit(q.get("kind", "load"), q["path"],
-                               recursive=q.get("recursive", True),
-                               replicas=q.get("replicas", 1))
-        return {"job_id": job.job_id}
+        kind = q.get("kind", "load")
+        if q.get("if_absent") and kind == "load":
+            job_id, outcome = self.jobs.submit_load_if_absent(
+                q["path"], replicas=q.get("replicas", 1))
+        else:
+            job_id, outcome = self.jobs.submit(
+                kind, q["path"], recursive=q.get("recursive", True),
+                replicas=q.get("replicas", 1)).job_id, "submitted"
+        self.metrics.gauge("jobs.load.live", self.jobs.live_loads())
+        return {"job_id": job_id, "outcome": outcome}
 
     def _prefetch_window(self, q):
         """Epoch-aware prefetch advise (docs/caching.md): the client
@@ -1352,4 +1362,5 @@ class MasterServer:
 
     def _report_task(self, q):
         self.jobs.report_task(q["task"])
+        self.metrics.gauge("jobs.load.live", self.jobs.live_loads())
         return {}

@@ -218,6 +218,9 @@ class WorkerServer:
         from curvine_tpu.common.executor import ScheduledExecutor
         self.executor = ScheduledExecutor("worker")
         self._task_sem = asyncio.Semaphore(wc.task_parallelism)
+        self._task_client = None          # _task_client_get
+        self._load_tasks: set = set()     # _submit_task
+        self._evict_reports: set = set()  # _evict_once
         self._leader_idx = 0
         # heartbeat failure dedup/backoff state
         self._hb_fails = 0
@@ -297,6 +300,11 @@ class WorkerServer:
         for t in self._bg:
             t.cancel()
         self._bg.clear()
+        for t in [*self._load_tasks, *self._evict_reports]:
+            t.cancel()
+        if self._task_client is not None:
+            await self._task_client.close()
+            self._task_client = None
         if self._shm_channel is not None:
             await asyncio.to_thread(self._shm_channel.stop)
             self._shm_channel = None
@@ -557,24 +565,44 @@ class WorkerServer:
                 self.hbm.drop(bid)
 
     async def _evict_once(self) -> None:
-        dropped0 = self.store.dropped_total
         demoted0 = self.store.demoted_total
-        removed = await asyncio.to_thread(self.store.maybe_evict)
-        if self.hbm is not None:
-            for bid in removed:
-                if not self.store.contains(bid):   # dropped, not demoted
-                    # capacity pressure, not deletion: ghost the device
-                    # copy so a re-broadcast of this (still-hot) block
-                    # re-admits straight to the policy's main queue
-                    self.hbm.drop(bid, evicted=True)
-        # evicted counts only blocks that LEFT the cache; demotions moved
-        # tiers without losing data and get their own counter
-        if self.store.dropped_total > dropped0:
-            self.metrics.inc("blocks.evicted",
-                             self.store.dropped_total - dropped0)
+        await asyncio.to_thread(self.store.maybe_evict)
+        # every block that LEFT the cache since the last tick, by this
+        # trim or by a create that needed room; demotions moved tiers
+        # without losing data and get their own counter
+        dropped = self.store.take_dropped()
         if self.store.demoted_total > demoted0:
             self.metrics.inc("blocks.demoted",
                              self.store.demoted_total - demoted0)
+        if not dropped:
+            return
+        self.metrics.inc("blocks.evicted", len(dropped))
+        if self.hbm is not None:
+            for bid in dropped:
+                # capacity pressure, not deletion: ghost the device
+                # copy so a re-broadcast of this (still-hot) block
+                # re-admits straight to the policy's main queue
+                self.hbm.drop(bid, evicted=True)
+        # tell the masters now: until they know, they hand clients the
+        # locations of blocks that are gone, and the full report that
+        # would correct them is block_report_interval_ms away
+        payload = pack({"worker_id": self.worker_id, "blocks": {},
+                        "storage_types": {}, "incremental": True,
+                        "removed": dropped})
+
+        async def report(addr: str) -> None:
+            try:
+                await self._bounded_master_call(
+                    addr, RpcCode.WORKER_BLOCK_REPORT, payload,
+                    connect_s=3.0, call_s=5.0)
+            except Exception as e:  # noqa: BLE001 — the full report heals
+                log.debug("eviction report to %s failed: %s", addr, e)
+
+        # not awaited: a master that is down must not stall the trim
+        for addr in self.conf.client.master_addrs:
+            t = asyncio.ensure_future(report(addr))
+            self._evict_reports.add(t)
+            t.add_done_callback(self._evict_reports.discard)
 
     async def _promote_once(self) -> None:
         """Hot-data promotion scan; tier changes reach the master on the
@@ -1518,28 +1546,49 @@ class WorkerServer:
         if task.kind == "ec_convert":
             asyncio.ensure_future(self._run_ec_convert_task(task))
         else:
-            asyncio.ensure_future(self._run_load_task(task))
+            # held, so that stop() can cancel what still queues for a slot
+            t = asyncio.ensure_future(self._run_load_task(task))
+            self._load_tasks.add(t)
+            t.add_done_callback(self._load_tasks.discard)
         return {"accepted": True}
+
+    def _task_client_get(self):
+        """The one client the load tasks share, made at the first task
+        (a client per task dialled the master and this worker anew each
+        time) and closed with the worker."""
+        if self._task_client is None:
+            from curvine_tpu.client import CurvineClient
+            self._task_client = CurvineClient(self.conf)
+        return self._task_client
 
     async def _run_load_task(self, task: TaskInfo) -> None:
         """UFS ↔ cache transfer. Parity: worker/task/load_task_runner.rs
-        (load) + the export job flow (cache → UFS)."""
-        from curvine_tpu.client import CurvineClient
+        (load) + the export job flow (cache → UFS). Accounted as
+        load.tasks / load.bytes / load.s / load.failed and the span
+        `load` (docs/observability.md), queueing for a slot excluded."""
         async with self._task_sem:
-            client = CurvineClient(self.conf)
+            client = self._task_client_get()
+            t0 = time.perf_counter()
             try:
-                if task.kind == "export":
-                    n = await client.export_to_ufs(task.path)
-                elif task.kind == "prefetch":
-                    n = await client.prefetch(task.path)
-                else:
-                    n = await client.load_from_ufs(task.path)
+                with self.tracer.span("load", attrs={
+                        "path": task.path, "kind": task.kind or "load"}):
+                    if task.kind == "export":
+                        n = await client.export_to_ufs(task.path)
+                    elif task.kind == "prefetch":
+                        n = await client.prefetch(task.path)
+                    else:
+                        n = await client.load_from_ufs(task.path)
                 task.state = JobState.COMPLETED
                 task.loaded_len = n
+                self.metrics.inc("load.bytes", n)
             except Exception as e:  # noqa: BLE001
                 task.state = JobState.FAILED
                 task.message = str(e)
-                log.warning("load task %s failed: %s", task.task_id, e)
+                self.metrics.inc("load.failed")
+                # a mount taken away while its loads queued is routine
+                log.log(logging.INFO if isinstance(e, err.MountNotFound)
+                        else logging.WARNING,
+                        "load task %s failed: %s", task.task_id, e)
             finally:
                 task.worker_id = self.worker_id
                 try:
@@ -1547,7 +1596,8 @@ class WorkerServer:
                                             pack({"task": task.to_wire()}))
                 except Exception as e:
                     log.warning("task report failed: %s", e)
-                await client.close()
+                self.metrics.inc("load.tasks")
+                self.metrics.inc("load.s", time.perf_counter() - t0)
 
     # ---------------- erasure coding ----------------
 

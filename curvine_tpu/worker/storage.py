@@ -496,6 +496,10 @@ class BlockStore:
         # cache; demoted/promoted = moved between tiers, nothing lost)
         self.dropped_total = 0
         self.demoted_total = 0
+        # ids dropped under cache pressure (by the trim or by a create
+        # that needed room) that the worker has not yet told the master
+        # about: take_dropped()
+        self._dropped: list[int] = []
         self.promoted_total = 0
         self._load_existing()
 
@@ -1181,6 +1185,7 @@ class BlockStore:
             self._remove_locked(info, evicted=True)
             evicted.append(bid)
             self.dropped_total += 1
+            self._dropped.append(bid)
         if evicted:
             log.info("evicted %d blocks from %s", len(evicted), tier.dir_id)
         return evicted
@@ -1242,6 +1247,7 @@ class BlockStore:
                         self._remove_locked(info, evicted=True)
                         removed.append(bid)
                         self.dropped_total += 1
+                        self._dropped.append(bid)
                         progress = True
             with self._lock:
                 if tier.available >= target:
@@ -1255,6 +1261,13 @@ class BlockStore:
                      len(removed), tier.dir_id, demoted,
                      len(removed) - demoted)
         return removed
+
+    def take_dropped(self) -> list[int]:
+        """Ids of the blocks dropped under cache pressure since the last
+        call, whichever path dropped them."""
+        with self._lock:
+            out, self._dropped = self._dropped, []
+        return out
 
     def maybe_evict(self) -> list[int]:
         """Background check: any tier above high-water gets trimmed."""

@@ -473,18 +473,7 @@ class FsReader:
             self._shm_fallback(bid)
             return None
         finally:
-            # counted here, on the loop: fetch threads never write the
-            # counters. `resume` is what the hand-off cost beside the
-            # work in the thread: submit → thread running, thread
-            # returned → this task running again (the loop, the GIL)
-            wall = time.perf_counter() - t_submit
-            for key, v in spent.items():
-                self._count(key, v)
-                if key.endswith(".s"):
-                    wall -= v
-            if spent:
-                self._count("read.phase.resume.s", wall)
-                self._count("read.phase.resume.n")
+            self._count_fetch(spent, t_submit)
         if got is not None:
             self._count_verify(length, copied)
         other = self._shm_maps.get(bid)
@@ -509,14 +498,31 @@ class FsReader:
         self._shm_maps[bid] = (fd, mm)
         return mm
 
+    def _count_fetch(self, spent: dict, t_submit: float) -> None:
+        """What a fetch thread stamped into `spent`, counted here, on
+        the loop: fetch threads never write the counters. `resume` is
+        what the hand-off cost beside the work in the thread: submit →
+        thread running, thread returned → this task running again (the
+        loop, the GIL)."""
+        wall = time.perf_counter() - t_submit
+        for key, v in spent.items():
+            self._count(key, v)
+            if key.endswith(".s"):
+                wall -= v
+        if spent:
+            self._count("read.phase.resume.s", wall)
+            self._count("read.phase.resume.n")
+
     def _fetch_shm(self, spath: str, lb: LocatedBlock, algo: str | None,
-                   spent: dict) -> tuple:
+                   spent: dict, into: tuple | None = None) -> tuple:
         """On the fetch thread: `fetch_block_fd` (phase `grant`), map the
         memfd (`map`) and, given the commit-time `algo`, checksum the
         mapping where it lies (`verify`). The first touch of every page
         and the hash run here, without the GIL, not on the loop.
         Each phase has its span and leaves its seconds in `spent`, so the
         awaiting task can tell the work from its own wait to run again.
+        `into` = (SpanMap, offset): the block is one of a range's and is
+        mapped there, beside its neighbours, not on its own.
         → (fd, granted length, mapping or None, checksum or None, bytes
         copied to hash); touches nothing of the reader's state."""
         from curvine_tpu.worker.shm import fetch_block_fd
@@ -536,7 +542,8 @@ class FsReader:
             try:
                 with self._phase("map", spent):
                     mm = mmap.mmap(fd, length, flags=flags,
-                                   prot=mmap.PROT_READ)
+                                   prot=mmap.PROT_READ) if into is None \
+                        else into[0].map(fd, length, into[1], flags)
             except (OSError, ValueError):
                 pass
         if mm is not None and algo is not None:
@@ -563,7 +570,8 @@ class FsReader:
         """Zero-copy numpy view onto a shm-mapped block range — the
         whole point of the shm plane: read_range/mmap_view return a
         read-only slice of the sealed mapping itself, no RPC, no copy.
-        None → range not single-block / block not shm-served."""
+        A range over several blocks is a slice of their mappings side
+        by side (`_span_view`). None → a block not shm-served."""
         if n <= 0:
             return None
         located = self._locate(offset)
@@ -571,7 +579,7 @@ class FsReader:
             return None
         lb, block_off = located
         if block_off + n > lb.block.len:
-            return None
+            return await self._span_view(offset, n)
         with self._span("shm_view", detail=True, block=lb.block.id,
                         n=n) as sp:
             mm = await self._shm_map(lb)
@@ -587,6 +595,136 @@ class FsReader:
         self._count("read.zero_copy_bytes", n)
         return np.frombuffer(mm, dtype=np.uint8, count=n,
                              offset=block_off)
+
+    def _span_blocks(self, offset: int, n: int) -> list | None:
+        """The consecutive blocks under [offset, offset+n) if they can
+        lie side by side in one range of addresses: replicated blocks
+        with a location each, no hole between or after them, and every
+        one but the last a whole number of pages (the next starts where
+        it ends)."""
+        import bisect
+        locs = self.blocks.block_locs
+        i = bisect.bisect_right(self._block_offs, offset) - 1
+        if i < 0:
+            return None
+        lbs: list[LocatedBlock] = []
+        end = offset + n
+        at = locs[i].offset
+        while at < end:
+            if i >= len(locs) or locs[i].offset != at:
+                return None          # a hole
+            lb = locs[i]
+            if not lb.locs or lb.block.len <= 0:
+                return None          # an EC stripe, or locationless
+            lbs.append(lb)
+            at += lb.block.len
+            i += 1
+        if any(lb.block.len % mmap.PAGESIZE for lb in lbs[:-1]) \
+                or len(lbs) > self._SC_CACHE_CAP:
+            return None
+        return lbs
+
+    async def _span_view(self, offset: int, n: int):
+        """`_shm_view` for a range that spans blocks: the same
+        algorithm — map the sealed export, verify it once, hand out a
+        slice — over each block of the range, the blocks fetched
+        together on fetch threads and mapped side by side in one
+        `SpanMap`. The loop only compares the checksums the threads
+        bring back. All blocks or nothing: None if any of them is not
+        served by the shm rung, and then no byte of the range has
+        reached the caller (a block that failed its checksum is flagged
+        as on the one-block path). The range belongs to the views
+        handed out, not to this reader: it stays mapped until the last
+        of them is collected, whatever is closed or evicted before."""
+        if not self.short_circuit:
+            return None
+        lbs = self._span_blocks(offset, n)
+        if lbs is None:
+            return None
+        with self._span("shm_view", detail=True, block=lbs[0].block.id,
+                        n=n, blocks=len(lbs)) as sp:
+            whole = await self._span_map(lbs)
+            if sp is not None:
+                sp.set_attr("served_by", "none" if whole is None else
+                            "+".join(sorted({
+                                "shm_warm" if lb.block.id in self._shm_warm
+                                else "shm" for lb in lbs})))
+        if whole is None:
+            return None
+        start = offset - lbs[0].offset
+        for lb in lbs:
+            lo = max(offset, lb.offset)
+            hi = min(offset + n, lb.offset + lb.block.len)
+            self._note_sc_read(lb.block.id, hi - lo)
+            self._shm_hit(lb.block.id)
+        self._count("read.zero_copy_bytes", n)
+        self._count("read.span_views")
+        self._count("read.span_view_bytes", n)
+        return whole[start:start + n]
+
+    async def _span_map(self, lbs: list):
+        """The blocks `lbs` mapped side by side and verified → the
+        read-only array over all of them, or None."""
+        from curvine_tpu.client.spanmap import SpanMap
+        # the probes (GET_BLOCK_INFO: shm_sock, checksum) together too
+        await asyncio.gather(*(self._local_path(lb) for lb in lbs
+                               if lb.block.id not in self._local_paths))
+        spaths = [self._shm_sock.get(lb.block.id) for lb in lbs]
+        if None in spaths:
+            return None
+        wants = [self._block_crc.get(lb.block.id, (None, None))
+                 if self.verify else (None, None) for lb in lbs]
+        try:
+            span = SpanMap(sum(lb.block.len for lb in lbs))
+        except OSError as e:
+            log.debug("no address range for %d blocks: %s", len(lbs), e)
+            return None
+
+        async def fetch(lb: LocatedBlock, spath: str, want: tuple):
+            spent: dict[str, float] = {}
+            t_submit = time.perf_counter()
+            try:
+                return await asyncio.to_thread(
+                    self._fetch_shm, spath, lb, want[1], spent,
+                    (span, lb.offset - lbs[0].offset))
+            finally:
+                self._count_fetch(spent, t_submit)
+
+        fetched = await asyncio.gather(*map(fetch, lbs, spaths, wants),
+                                       return_exceptions=True)
+        ok, raised = True, None
+        for lb, (want, _algo), res in zip(lbs, wants, fetched):
+            bid = lb.block.id
+            if isinstance(res, (LookupError, OSError, ValueError)):
+                # as in _shm_map: the export or the channel is gone
+                log.debug("shm fetch for block %d failed: %s", bid, res)
+                self._shm_sock.pop(bid, None)
+                self._shm_fallback(bid)
+                ok = False
+                continue
+            if isinstance(res, BaseException):
+                raised = res
+                continue
+            fd, length, mm, got, copied = res
+            os.close(fd)             # the mapping holds the pages
+            if got is not None:
+                self._count_verify(length, copied)
+            if mm is None:
+                # a stale export (another length), or the map failed
+                if length != lb.block.len:
+                    self._shm_sock.pop(bid, None)
+                self._shm_fallback(bid)
+                ok = False
+            elif got is not None and got != want:
+                self._sc_corrupt(lb)  # flags the replica, drops the caches
+                self._shm_fallback(bid)
+                ok = False
+        if ok and raised is None:
+            return span.view()
+        span.close()                 # no view of it was handed out
+        if raised is not None:
+            raise raised
+        return None
 
     def _alloc_out(self, n: int):
         """Caller-visible read destination: page-aligned mmap-backed
@@ -852,8 +990,9 @@ class FsReader:
         one large file saturates multiple workers/replicas instead of
         one socket.
 
-        Shm-mapped single-block ranges skip ALL of that: the return is
-        a read-only zero-copy view onto the sealed mapping itself."""
+        Shm-mapped ranges skip ALL of that: the return is a read-only
+        zero-copy view onto the sealed mapping itself, or onto the
+        mappings of the range's blocks side by side."""
         import numpy as np
         n = max(0, min(n, self.len - offset))
         if n == 0:
@@ -1100,7 +1239,8 @@ class FsReader:
         Returns None when the range isn't short-circuit readable.
 
         Shm-mapped blocks ARE true zero-copy here again: the sealed
-        mapping serves a read-only view with no preadv and no buffer."""
+        mapping serves a read-only view with no preadv and no buffer,
+        and so does a range over several of them (`_span_view`)."""
         self._serve_paths = set()
         with self._span("mmap_view", detail=True, path=self.path,
                         offset=offset, n=n) as sp:

@@ -173,7 +173,8 @@ async def _load_tensor(client: CurvineClient, path: str, t: dict, place,
     span ``ckpt.tensor`` (a step of the restore and the parent of the
     reader's phases; it raises no slow-op line of its own: in a
     many-way restore every tensor is slow). Bytes come
-    as a short-circuit view where the file lies in one block, else as a
+    as a short-circuit view (of one block, or of the file's blocks side
+    by side) where the shm rung serves all of it, else as a
     copy through ``read_all``; with ``peer_hbm`` from a peer's HBM tier
     first. ``place(arr)`` is timed as ckpt.place (an async dispatch: the
     device copies while the next tensor is read); the reader closes

@@ -501,10 +501,10 @@ async def test_slow_op_line_leads_to_the_phase_unsampled(tmp_path):
 
 
 async def test_short_circuit_read_accounts_every_phase(tmp_path):
-    """A one-block file as a view and a two-block file through read_all,
-    both co-located: every phase of the ladder is counted where its work
-    is done, they sum to no more than the reads' wall, and each rung
-    names itself."""
+    """A one-block file as a view, a two-block file as a view and
+    through read_all, all co-located: every phase of the ladder is
+    counted where its work is done, they sum to no more than the reads'
+    wall, and each rung names itself."""
     import time
     async with MiniCluster(workers=1, base_dir=str(tmp_path),
                            block_size=128 * KB) as mc:
@@ -520,8 +520,7 @@ async def test_short_circuit_read_accounts_every_phase(tmp_path):
         view = await r.mmap_view(0, r.len)
         await r.close()
         r = await c.open("/ph/two.bin")
-        assert await r.mmap_view(0, r.len) is None      # no one mapping
-        data = await r.read_all()
+        data = await r.read_all()               # bytes: assembled
         await r.close()
         wall = time.perf_counter() - t0
         assert bytes(view) == one and data == two
@@ -538,11 +537,18 @@ async def test_short_circuit_read_accounts_every_phase(tmp_path):
         assert sum(grew(f"read.phase.{p}.s") for p in READ_PHASES) <= wall
         assert 0.0 <= grew("read.probe.srv_handle_s") \
             <= grew("read.phase.probe.s")
+        # as a view the two blocks come at once, each on a thread: their
+        # phases are counted a block and overlap in the view's wall
+        r = await c.open("/ph/two.bin")
+        assert bytes(await r.mmap_view(0, r.len)) == two
+        await r.close()
+        assert grew("read.phase.copy.n") == 3
+        assert grew("read.phase.grant.n") == 5
         spans = c.tracer.store.drain(4096)
         served = {(s["op"], s["attrs"]["path"]): s["attrs"]["served_by"]
                   for s in spans if s["op"] in ("mmap_view", "read_all")}
         assert served == {("mmap_view", "/ph/one.bin"): "shm",
-                          ("mmap_view", "/ph/two.bin"): "none",
+                          ("mmap_view", "/ph/two.bin"): "shm",
                           ("read_all", "/ph/two.bin"): "shm"}
         ops = {s["op"] for s in spans}
         assert "shm_view" in ops
@@ -720,3 +726,50 @@ async def test_load_checkpoint_accounts_its_phases(tmp_path):
             kids = [s for s in spans if s["op"] == op
                     and s["parent"] in ids]
             assert len(kids) == 3, op
+
+
+async def test_load_checkpoint_takes_a_multiblock_tensor_as_a_view(
+        tmp_path, monkeypatch):
+    """A tensor larger than a block restores bit-exact through the
+    block-spanning view: `ckpt.tensor` says shm and how many blocks,
+    and `read_all` is called for the manifest alone."""
+    import jax
+    import numpy as np
+    from curvine_tpu.client.reader import FsReader
+    from curvine_tpu.tpu.broadcast import load_checkpoint, save_checkpoint
+    async with MiniCluster(workers=1, base_dir=str(tmp_path),
+                           block_size=64 * 1024) as mc:
+        c = mc.client()
+        c.tracer.sample_rate = 1.0
+        rng = np.random.default_rng(7)
+        params = {"big": rng.standard_normal((300, 256)).astype(np.float32),
+                  "small": np.arange(512, dtype=np.int32)}
+        await save_checkpoint(c, "/ckpt/mb", params)
+        copied = []
+        real_read_all = FsReader.read_all
+
+        async def read_all(self, *a, **kw):
+            copied.append(self.path)
+            return await real_read_all(self, *a, **kw)
+
+        monkeypatch.setattr(FsReader, "read_all", read_all)
+        c.tracer.store.clear()
+        before = dict(c.counters)
+        back = await load_checkpoint(c, "/ckpt/mb", placer=jax.device_put)
+        for k, v in params.items():
+            assert np.asarray(back[k]).tobytes() == v.tobytes()
+        assert copied == ["/ckpt/mb/manifest.json"]
+        by_bytes = {s["attrs"]["bytes"]: s["attrs"]
+                    for s in c.tracer.store.drain(4096)
+                    if s["op"] == "ckpt.tensor"}
+        assert by_bytes[300 * 256 * 4]["blocks"] == 5
+        assert by_bytes[2048]["blocks"] == 1
+        assert {a["served_by"] for a in by_bytes.values()} == {"shm"}
+
+        def grew(k):
+            return c.counters.get(k, 0) - before.get(k, 0)
+
+        assert grew("read.span_views") == 1
+        assert grew("read.span_view_bytes") == 300 * 256 * 4
+        assert grew("read.zero_copy_bytes") == 300 * 256 * 4 + 2048
+        assert grew("read.verify.copied_bytes") == 0

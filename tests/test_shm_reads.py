@@ -303,6 +303,329 @@ async def test_shm_concurrent_first_reads_share_one_mapping(tmp_path):
         await c.close()
 
 
+# ---------------- a range over several blocks: one view ----------------
+
+def _memfd_maps(name: str = "memfd:cv-") -> int:
+    """Mappings of sealed exports in this process (the worker, in this
+    process too, copies into its memfds with sendfile and maps none)."""
+    with open("/proc/self/maps") as f:
+        return sum(name in line for line in f)
+
+
+def _span_cluster(tmp_path, conf=None, block_size=MB):
+    conf = conf or ClusterConf()
+    conf.data_dir = str(tmp_path)
+    return MiniCluster(workers=1, conf=conf, base_dir=str(tmp_path),
+                       block_size=block_size)
+
+
+def test_spanmap_places_memfds_side_by_side():
+    """SpanMap alone: memfds mapped at their offsets read as one
+    read-only array; the range lives as long as any array over it and
+    not longer — afterwards nothing of the process lies in it."""
+    from curvine_tpu.client.spanmap import SpanMap
+
+    def overlapping(lo: int, hi: int) -> list[str]:
+        with open("/proc/self/maps") as f:
+            return [line for line in f
+                    if int(line.split("-")[0], 16) < hi
+                    and int(line.split("-")[1].split()[0], 16) > lo]
+
+    page = mmap.PAGESIZE
+    parts = [os.urandom(3 * page), os.urandom(page), os.urandom(page + 17)]
+    span = SpanMap(sum(map(len, parts)))
+    lo = span.view().ctypes.data
+    hi = lo + span.nbytes
+    assert lo % page == 0 and len(overlapping(lo, hi)) == 1   # reserved
+    with pytest.raises(ValueError):
+        span.map(0, page, 100, mmap.MAP_SHARED)     # not on a page
+    with pytest.raises(ValueError):
+        span.map(0, 2 * page, 4 * page, mmap.MAP_SHARED)    # past the end
+    at = 0
+    for i, part in enumerate(parts):
+        fd = os.memfd_create(f"cv-test-span-{i}")
+        try:
+            os.write(fd, part)
+            piece = span.map(fd, len(part), at, mmap.MAP_SHARED
+                             | (mmap.MAP_POPULATE if i else 0))
+        finally:
+            os.close(fd)             # the mapping holds the pages
+        assert bytes(piece) == part and not piece.flags.writeable
+        at += len(part)
+    with pytest.raises(OSError):
+        span.map(-1, page, 0, mmap.MAP_SHARED)      # the kernel refuses
+    whole = span.view()
+    assert whole.base is span and not whole.flags.writeable
+    assert bytes(whole) == b"".join(parts)
+    assert len(overlapping(lo, hi)) == 3
+    inner = whole[page + 1:-3]
+    del whole, piece, span
+    gc.collect()
+    assert bytes(inner) == b"".join(parts)[page + 1:-3]
+    del inner
+    gc.collect()
+    assert not overlapping(lo, hi)
+    done = SpanMap(page)
+    lo = done.view().ctypes.data
+    done.close()
+    done.close()                     # once only
+    assert not overlapping(lo, lo + page)
+
+
+@pytest.mark.parametrize("offset,n", [
+    (0, 3 * MB + MB // 2 + 7),           # the whole file: 3½ blocks
+    (MB - 4096 - 5, 8192 + 11),          # straddles one block boundary
+    (MB + 1, 2 * MB),                    # a middle block and both sides
+], ids=["whole", "straddle", "interior"])
+async def test_span_view_is_one_zero_copy_view(tmp_path, offset, n):
+    """A range over consecutive blocks comes back as one read-only view
+    of the sealed exports mapped side by side: byte-equal, counted as a
+    span view and as zero-copy bytes, every block hashed once where it
+    lies, and no buffer assembled (`copy` does not move)."""
+    async with _span_cluster(tmp_path) as mc:
+        c = mc.client()
+        c.tracer.sample_rate = 1.0
+        payload = os.urandom(3 * MB + MB // 2 + 7)
+        await c.write_all("/shm/span.bin", payload)
+        r = await c.open("/shm/span.bin")
+        c.tracer.store.clear()
+        before = dict(c.counters)
+
+        def grew(key):
+            return c.counters.get(key, 0) - before.get(key, 0)
+
+        view = await r.mmap_view(offset, n)
+        assert isinstance(view, np.ndarray) and view.dtype == np.uint8
+        assert not view.flags.writeable and view.flags.c_contiguous
+        assert bytes(view) == payload[offset:offset + n]
+        with pytest.raises(ValueError):
+            view[0] = 0
+        k = (offset + n - 1) // MB - offset // MB + 1
+        covered = sum(min(MB, len(payload) - i * MB)
+                      for i in range(offset // MB, offset // MB + k))
+        assert grew("read.span_views") == 1
+        assert grew("read.span_view_bytes") == n
+        assert grew("read.zero_copy_bytes") == n
+        assert grew("read.shm_hits") == k
+        assert grew("read.verify.bytes") == covered
+        assert grew("read.verify.copied_bytes") == 0
+        assert grew("read.phase.copy.n") == 0
+        for p in ("probe", "grant", "map", "verify", "resume"):
+            assert grew(f"read.phase.{p}.n") == k, p
+        assert r.served_by() == "shm"
+        (sp,) = [s for s in c.tracer.store.drain(4096)
+                 if s["op"] == "shm_view"]
+        assert sp["attrs"]["blocks"] == k
+        assert sp["attrs"]["served_by"] == "shm"
+        # each block is mapped once, and only in the range
+        assert _memfd_maps() == k and not r._shm_maps
+        assert mc.workers[0].metrics.counters.get("bytes.read", 0) == 0
+        # read_range goes through the same door
+        again = await r.read_range(offset, n, parallel=4)
+        assert not again.flags.writeable
+        assert bytes(again) == payload[offset:offset + n]
+        assert grew("read.span_views") == 2
+        del view, again
+        await r.close()
+        await c.close()
+
+
+async def test_span_view_outlives_close_and_eviction_no_leak(tmp_path):
+    """The range belongs to its views: it survives the reader's close
+    and the worker's eviction of the exports, it is unmapped when the
+    last view is collected, and a loop of open / view / close leaves
+    neither mappings nor descriptors behind."""
+    async with _span_cluster(tmp_path, block_size=256 * 1024) as mc:
+        c = mc.client()
+        payload = os.urandom(3 * 256 * 1024 + 1000)
+        await c.write_all("/shm/life.bin", payload)
+
+        async def one_view():
+            r = await c.open("/shm/life.bin")
+            view = await r.mmap_view(0, r.len)
+            assert view is not None
+            bids = [lb.block.id for lb in r.blocks.block_locs]
+            await r.close()
+            return view, bids
+
+        base_maps = _memfd_maps()
+        view, bids = await one_view()
+        tail = view[len(payload) - 5000:]       # a view of the view
+        for bid in bids:
+            mc.workers[0].shm.invalidate(bid)   # the worker's fds go
+        gc.collect()
+        assert _memfd_maps() == base_maps + 4
+        assert bytes(view) == payload
+        del view
+        gc.collect()
+        assert _memfd_maps() == base_maps + 4   # `tail` holds all of it
+        assert bytes(tail) == payload[-5000:]
+        del tail
+        gc.collect()
+        assert _memfd_maps() == base_maps
+
+        for _ in range(5):                      # exports made, pool dialled
+            await one_view()
+        gc.collect()
+        fds, maps = _fd_count(), _memfd_maps()
+        for _ in range(50):
+            view, _bids = await one_view()
+            assert view[-1] == payload[-1]
+            del view
+        gc.collect()
+        assert _memfd_maps() == maps == base_maps
+        assert _fd_count() <= fds
+        await c.close()
+
+
+async def test_span_view_refuses_a_corrupt_block(tmp_path, monkeypatch):
+    """One export of the four differs from its commit-time checksum: no
+    view (and no byte) of the range reaches the caller, the replica is
+    flagged, the range is unmapped, and `read_all` serves the right
+    bytes through the verified remote path."""
+    from curvine_tpu.rpc import RpcCode
+    async with _span_cluster(tmp_path) as mc:
+        c = mc.client()
+        payload = os.urandom(3 * MB + MB // 2)
+        await c.write_all("/shm/bad4.bin", payload)
+        r = await c.open("/shm/bad4.bin")
+        bad_bid = r.blocks.block_locs[2].block.id
+        real_fetch = wshm.fetch_block_fd
+
+        def tampered(sock_path, block_id, timeout=5.0):
+            fd, n = real_fetch(sock_path, block_id, timeout)
+            if block_id != bad_bid:
+                return fd, n
+            data = bytearray(os.pread(fd, n, 0))
+            os.close(fd)
+            data[n // 2] ^= 0x01
+            bad = os.memfd_create("cv-test-bad")
+            os.write(bad, data)
+            return bad, n
+
+        monkeypatch.setattr(wshm, "fetch_block_fd", tampered)
+        reported = []
+        real_call = r.fs.call
+
+        async def call(code, *a, **kw):
+            if code == RpcCode.REPORT_UNDER_REPLICATED_BLOCKS:
+                reported.append(a[0] if a else kw)
+            return await real_call(code, *a, **kw)
+
+        monkeypatch.setattr(r.fs, "call", call)
+        assert await r.mmap_view(0, len(payload)) is None
+        assert r.served_by() == "none"
+        assert bad_bid not in r._shm_sock
+        assert r._local_paths[bad_bid] is None
+        gc.collect()
+        assert _memfd_maps() == 0
+        assert not [fd for fd in os.listdir("/proc/self/fd")
+                    if "cv-test-bad" in _fd_target(fd)]
+        assert c.counters.get("read.checksum_mismatch", 0) == 1
+        assert c.counters.get("read.span_views", 0) == 0
+        assert c.counters.get("read.zero_copy_bytes", 0) == 0
+        assert await r.read_all() == payload
+        assert c.counters.get("read.checksum_mismatch", 0) == 1
+        assert mc.workers[0].metrics.counters.get("bytes.read", 0) == MB
+        await asyncio.sleep(0.05)            # the report is fire-and-forget
+        assert [m["block_ids"] for m in reported] == [[bad_bid]]
+        await r.close()
+        await c.close()
+
+
+@pytest.mark.parametrize("case", ["hole", "shm_off", "grant_fails",
+                                  "stale_grant"])
+async def test_span_view_falls_back_whole(tmp_path, monkeypatch, case):
+    """Anything but four shm-served blocks in a row: None, nothing left
+    mapped, and the caller's `read_all` returns the file."""
+    conf = ClusterConf()
+    conf.worker.shm_reads = case != "shm_off"
+    async with _span_cluster(tmp_path, conf, 256 * 1024) as mc:
+        c = mc.client()
+        payload = os.urandom(3 * 256 * 1024 + 512)
+        await c.write_all("/shm/fb4.bin", payload)
+        if case == "hole":
+            await c.meta.resize_file("/shm/fb4.bin", 5 * 256 * 1024)
+            payload += bytes(5 * 256 * 1024 - len(payload))
+        r = await c.open("/shm/fb4.bin")
+        victim = r.blocks.block_locs[1].block.id
+        real_fetch = wshm.fetch_block_fd
+
+        def fetch(sock_path, block_id, timeout=5.0):
+            if block_id == victim and case == "grant_fails":
+                raise LookupError("export dropped")
+            fd, n = real_fetch(sock_path, block_id, timeout)
+            # a grant of another length than the block: a stale export
+            return fd, n - 1 if block_id == victim \
+                and case == "stale_grant" else n
+
+        monkeypatch.setattr(wshm, "fetch_block_fd", fetch)
+        assert await r.mmap_view(0, r.len) is None
+        assert r.served_by() == "none"
+        gc.collect()
+        assert _memfd_maps() == 0 and not r._shm_maps
+        assert c.counters.get("read.span_views", 0) == 0
+        assert c.counters.get("read.zero_copy_bytes", 0) == 0
+        if case in ("grant_fails", "stale_grant"):
+            assert c.counters.get("read.shm_fallbacks", 0) == 1
+            assert victim not in r._shm_sock
+            # every granted fd is closed: what is left is the worker's
+            assert len([fd for fd in os.listdir("/proc/self/fd")
+                        if "cv-blk-" in _fd_target(fd)]) \
+                == len(mc.workers[0].shm)
+        monkeypatch.setattr(wshm, "fetch_block_fd", real_fetch)
+        assert await r.read_all() == payload
+        await r.close()
+        await c.close()
+
+
+async def test_span_blocks_are_fetched_together_off_the_loop(
+        tmp_path, monkeypatch):
+    """The blocks of a range are granted, mapped and verified at once,
+    each on a fetch thread of its own, and the loop runs on meanwhile:
+    a barrier that only four concurrent grants can pass, and a task
+    that keeps ticking while they wait in it."""
+    import time
+    async with _span_cluster(tmp_path) as mc:
+        c = mc.client()
+        payload = os.urandom(3 * MB + MB // 2)
+        await c.write_all("/shm/par.bin", payload)
+        r = await c.open("/shm/par.bin")
+        barrier = threading.Barrier(4, timeout=20)
+        threads = []
+        real_fetch = wshm.fetch_block_fd
+
+        def held(sock_path, block_id, timeout=5.0):
+            threads.append(threading.get_ident())
+            barrier.wait()       # broken (→ the test fails) if in turn
+            time.sleep(0.1)
+            return real_fetch(sock_path, block_id, timeout)
+
+        monkeypatch.setattr(wshm, "fetch_block_fd", held)
+        ticks = 0
+
+        async def beat():
+            nonlocal ticks
+            while True:
+                await asyncio.sleep(0.005)
+                ticks += 1
+
+        heart = asyncio.ensure_future(beat())
+        try:
+            view = await r.mmap_view(0, len(payload))
+        finally:
+            heart.cancel()
+        assert bytes(view) == payload
+        assert len(set(threads)) == 4
+        assert threading.get_ident() not in threads
+        assert ticks >= 5, "the loop stood still while the blocks came"
+        assert c.counters["read.phase.grant.n"] == 4
+        assert c.counters["read.phase.grant.s"] >= 0.4    # 4 x 0.1 s
+        del view
+        await r.close()
+        await c.close()
+
+
 async def test_shm_eviction_mid_read_keeps_view_valid(tmp_path):
     """A zero-copy view handed to the caller outlives eviction of its
     mapping: _drop_shm tolerates the exported buffer (BufferError) and

@@ -62,7 +62,7 @@ class Sizes:
     blocks: int = 64                    # 4 GiB data set
     hbm_capacity: int = 2 * GB          # tier-0, half the data set
     hot_blocks: int = 8                 # heat-driven autopin set
-    # the repo's own 1B flagship consumer (bench.py's on-chip shape)
+    # the repo's own 1B flagship consumer
     model: tuple = (("vocab", 32_000), ("d_model", 2560), ("n_heads", 20),
                     ("n_layers", 12), ("d_ff", 10240), ("max_seq", 1024),
                     ("dtype", "bfloat16"), ("use_flash_attention", True),
@@ -93,48 +93,6 @@ class Sizes:
             ann_batch=32, mesh_layers=1)
 
 
-class CompileWatch:
-    """Counts XLA compile requests and persistent-cache traffic through
-    jax.monitoring — 'no compilation after step 1' and 'second run hits
-    the cache' are read off these, not guessed from wall time."""
-
-    def __init__(self):
-        import jax.monitoring as mon
-        self.compiles = 0
-        self.compile_s = 0.0
-        self.cache_hits = 0
-        self.cache_misses = 0
-        mon.register_event_duration_secs_listener(self._duration)
-        mon.register_event_listener(self._event)
-
-    def _duration(self, event: str, secs: float, **kw) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compiles += 1
-            self.compile_s += secs
-
-    def _event(self, event: str, **kw) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.cache_hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.cache_misses += 1
-
-    def snapshot(self) -> dict:
-        return {"compiles": self.compiles,
-                "compile_s": round(self.compile_s, 3),
-                "cache_hits": self.cache_hits,
-                "cache_misses": self.cache_misses}
-
-
-_WATCH: CompileWatch | None = None
-
-
-def compile_watch() -> CompileWatch:
-    global _WATCH            # listeners cannot be unregistered one by one
-    if _WATCH is None:
-        _WATCH = CompileWatch()
-    return _WATCH
-
-
 @dataclasses.dataclass
 class Ctx:
     seed: int
@@ -143,7 +101,7 @@ class Ctx:
     worker: object
     devices: list
     peaks: dict | None       # None off-TPU (tests): no rate is judged there
-    watch: CompileWatch
+    watch: object            # perfbench.compile_watch.CompileWatch
     rates: list = dataclasses.field(default_factory=list)
     carry: dict = dataclasses.field(default_factory=dict)
 
@@ -1009,6 +967,7 @@ async def smoke(seed: int, sizes: Sizes, devices: list, peaks: dict | None,
                 emit=print) -> dict:
     workdir = tempfile.mkdtemp(prefix="curvine-smoke-")
     shm_dir = tempfile.mkdtemp(prefix="curvine-smoke-", dir="/dev/shm")
+    from perfbench.compile_watch import compile_watch
     try:
         native = build_native()
         emit("[native] " + json.dumps(native))
@@ -1031,9 +990,10 @@ def main(argv: list[str] | None = None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
-    if not os.path.isdir(os.path.join(HERE, "curvine_tpu")):
-        print("chip_smoke: the curvine_tpu package is not beside this "
-              "file — nothing to drive", file=sys.stderr)
+    if not all(os.path.isdir(os.path.join(HERE, d))
+               for d in ("curvine_tpu", "perfbench")):
+        print("chip_smoke: the curvine_tpu and perfbench packages are "
+              "not beside this file — nothing to drive", file=sys.stderr)
         return 2
     import jax
     devices = jax.devices()
@@ -1042,7 +1002,8 @@ def main(argv: list[str] | None = None) -> int:
               f"not a TPU — refusing to run", file=sys.stderr)
         return 2
     from curvine_tpu.tpu.compile_cache import enable_compile_cache
-    from curvine_tpu.tpu.peaks import peaks_of
+    from perfbench.compile_watch import compile_watch
+    from perfbench.peaks import peaks_of
 
     def overrun(signum, frame):
         raise TimeoutError(f"chip_smoke passed its {TIME_LIMIT_S} s limit")
@@ -1052,7 +1013,7 @@ def main(argv: list[str] | None = None) -> int:
     t0 = time.perf_counter()
     device = {"platform": devices[0].platform,
               "kind": devices[0].device_kind, "count": len(devices)}
-    peaks = peaks_of(devices[0])
+    peaks = peaks_of(devices[0].device_kind)
     cache = enable_compile_cache()
     print("[host] " + json.dumps({
         **device, **host_facts(), "jax": jax.__version__,

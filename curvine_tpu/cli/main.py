@@ -978,10 +978,6 @@ def build_parser() -> argparse.ArgumentParser:
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     try:
-        # optional rpc.uvloop acceleration: the policy must be swapped
-        # BEFORE asyncio.run creates the loop the daemon will live on
-        from curvine_tpu.rpc.loops import install_event_loop
-        install_event_loop(_conf(args).rpc)
         rc = asyncio.run(args.fn(args))
         return rc if isinstance(rc, int) else 0
     except KeyboardInterrupt:

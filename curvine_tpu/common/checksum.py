@@ -4,10 +4,9 @@ Every commit-time checksum travels with its algorithm name ("crc32" =
 zlib/IEEE, "crc32c" = Castagnoli via the native lib), so any verifier
 can recompute it later regardless of what the writer chose. Writers
 prefer crc32c whenever the native lib is loaded — on x86 it rides the
-SSE4.2 crc32 instruction at many GiB/s, which is what keeps always-on
-read verification inside its perf budget (scripts/perf_smoke.sh gates
-the overhead) — and fall back to zlib crc32 otherwise, which every
-Python runtime can both produce and verify."""
+SSE4.2 crc32 instruction at many GiB/s, which is what makes always-on
+read verification affordable — and fall back to zlib crc32 otherwise,
+which every Python runtime can both produce and verify."""
 
 from __future__ import annotations
 

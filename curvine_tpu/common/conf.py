@@ -51,13 +51,11 @@ class MasterConf:
     # ICI torus shape for the hop-count distance function (e.g. [4, 2]
     # or [2, 2, 2]); empty → distances fall back to host labels
     ici_mesh_shape: list[int] = field(default_factory=list)
-    min_replication: int = 1
     # retry cache
     retry_cache_size: int = 100_000
     retry_cache_ttl_ms: int = 600_000
     # ttl scanner
     ttl_check_ms: int = 1_000
-    ttl_bucket_ms: int = 1_000
     # permissions (parity: acl_feature.rs)
     acl_enabled: bool = True
     superuser: str = "root"
@@ -346,9 +344,6 @@ class ObsConf:
 class RpcConf:
     """Wire transport knobs (curvine_tpu/rpc/transport.py), shared by
     every peer in the process: clients, the master and worker servers."""
-    # optional uvloop acceleration for the whole process event loop;
-    # warn-once fallback to stock asyncio when uvloop is not installed
-    uvloop: bool = False
     # coalesced writer: all frames queued within one event-loop tick
     # leave in a single vectored send, bounded per batch by bytes/frames
     send_coalesce_bytes: int = 256 * 1024
@@ -369,18 +364,6 @@ class RpcConf:
     # reads at least this large get an aligned mmap-backed destination
     # instead of a heap numpy buffer
     recv_aligned_min: int = 256 * 1024
-    # TRUE ring registration for bulk receives (docs/data-plane.md):
-    # the pool's fixed slab set is registered with an io_uring instance
-    # (IORING_REGISTER_BUFFERS) and large READ_BLOCK payload remainders
-    # ride IORING_OP_READ_FIXED submissions instead of per-chunk
-    # sock_recv_into. Probed at first use with a loopback self-test;
-    # any failure (no io_uring, locked-memory limits, unsupported op)
-    # falls back to the portable recv path permanently and silently.
-    recv_ring: bool = True
-    # payload remainders at least this large take the ring path; smaller
-    # ones stay on sock_recv_into (a thread hand-off only pays for
-    # itself on multi-hundred-KB payloads)
-    recv_ring_min: int = 256 * 1024
 
 
 @dataclass
@@ -460,10 +443,6 @@ class ECConf:
     # a block is "cold" (eligible for conversion) when its file's mtime
     # is at least this old; 0 = every complete file qualifies
     convert_cold_s: int = 0
-    # leader-side auto-sweep: submit an ec_convert job over "/" every
-    # this many seconds, converting files whose policy carries an EC
-    # profile. 0 = operator-submitted jobs only (cv ec convert).
-    sweep_interval_s: float = 0.0
 
 
 @dataclass

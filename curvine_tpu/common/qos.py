@@ -26,8 +26,8 @@ door, with the server telling clients how to back off (Dean & Barroso,
     expire".
 
 Everything here is synchronous and allocation-light: the un-throttled
-hot path is a handful of float compares (gated ≤5% overhead in
-perf_smoke). The controller is injected into ``RpcServer`` as
+hot path is a handful of float compares. The controller is injected
+into ``RpcServer`` as
 ``server.qos`` the same way ``obs``/``metrics``/``watchdog`` are.
 """
 

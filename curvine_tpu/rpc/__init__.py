@@ -3,10 +3,9 @@ from curvine_tpu.rpc.frame import Flags, Message
 from curvine_tpu.rpc.client import Connection, ConnectionPool, RetryPolicy
 from curvine_tpu.rpc.server import RpcServer, ServerConn
 from curvine_tpu.rpc.transport import BulkDecoder, CoalescedWriter
-from curvine_tpu.rpc.loops import install_event_loop, loop_impl
 
 __all__ = [
     "RpcCode", "Flags", "Message", "Connection", "ConnectionPool",
     "RetryPolicy", "RpcServer", "ServerConn",
-    "BulkDecoder", "CoalescedWriter", "install_event_loop", "loop_impl",
+    "BulkDecoder", "CoalescedWriter",
 ]

@@ -27,8 +27,7 @@ from curvine_tpu.common.qos import TENANT_KEY, current_tenant
 from curvine_tpu.obs.trace import TRACE_KEY, current_ctx
 from curvine_tpu.rpc.deadline import DEADLINE_KEY, Deadline
 from curvine_tpu.rpc.frame import SRV_KEY, Flags, Message, pack, unpack
-from curvine_tpu.rpc.transport import (BulkDecoder, CoalescedWriter,
-                                       recv_pool)
+from curvine_tpu.rpc.transport import BulkDecoder, CoalescedWriter
 
 log = logging.getLogger(__name__)
 
@@ -111,17 +110,6 @@ class Connection:
 
     # ---------------- receive plumbing ----------------
 
-    def _ring_for(self, n: int):
-        """The process RingRecv when rpc.recv_ring is on, the remainder
-        is big enough to amortize the slab copy (rpc.recv_ring_min),
-        and io_uring probed healthy; None → plain sock_recv_into."""
-        rc = self.rpc_conf
-        if not getattr(rc, "recv_ring", True):
-            return None
-        if n < getattr(rc, "recv_ring_min", 256 * 1024):
-            return None
-        return recv_pool().ring()
-
     async def _read_loop(self) -> None:
         dec, loop, sock = self._dec, self._loop, self._sock
         assert dec is not None and loop is not None and sock is not None
@@ -145,9 +133,7 @@ class Connection:
                                         sink.filled + data_len]
                         got = dec.take_into(dst)
                         if got < data_len:
-                            await dec.recv_sink(
-                                loop, sock, dst[got:],
-                                ring=self._ring_for(data_len - got))
+                            await dec.recv_exact(loop, sock, dst[got:])
                         sink.filled += data_len
                     else:
                         data = bytes(await dec.read_payload(

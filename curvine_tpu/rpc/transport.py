@@ -29,9 +29,6 @@ which must bypass the bulk buffer)."""
 from __future__ import annotations
 
 import asyncio
-import ctypes
-import errno
-import logging
 import mmap
 import socket
 import threading
@@ -40,8 +37,6 @@ from collections import deque
 
 from curvine_tpu.common.errors import ConnectError
 from curvine_tpu.rpc.frame import Message, decode_envelope
-
-log = logging.getLogger(__name__)
 
 SEND_COALESCE_BYTES = 256 * 1024
 SEND_COALESCE_FRAMES = 128
@@ -82,179 +77,6 @@ def alloc_aligned(n: int):
     return np.frombuffer(mm, dtype=np.uint8, count=n)
 
 
-# errnos that mean the RING is broken/unsupported (latch + silent
-# fallback) as opposed to the STREAM being broken (propagate, the
-# connection dies the same way it would on the sock_recv_into path)
-_RING_FATAL = frozenset({errno.ENOSYS, errno.EOPNOTSUPP, errno.EINVAL,
-                         errno.EPERM, errno.ENOMEM, errno.ENXIO})
-
-
-async def _wait_readable(loop: asyncio.AbstractEventLoop,
-                         sock: socket.socket) -> None:
-    fut = loop.create_future()
-    fd = sock.fileno()
-
-    def _ready() -> None:
-        if not fut.done():
-            fut.set_result(None)
-
-    loop.add_reader(fd, _ready)
-    try:
-        await fut
-    finally:
-        loop.remove_reader(fd)
-
-
-class RingRecv:
-    """True io_uring registered receive for large sink payloads.
-
-    Construction registers a small set of page-aligned slabs with a
-    private io_uring (``IORING_REGISTER_BUFFERS``); large READ_BLOCK
-    payload remainders then ride ``IORING_OP_READ_FIXED`` into the
-    pinned slabs — the kernel skips the per-recv get_user_pages walk
-    that every ``sock_recv_into`` pays — and are copied out into the
-    caller's sink view.
-
-    Blocking discipline (readiness-gated submit): the event loop awaits
-    socket readability FIRST, then submits one READ_FIXED and enters
-    with GETEVENTS. The socket is non-blocking and readable, so the op
-    completes immediately (or ``-EAGAIN`` on a spurious wakeup, which
-    just re-awaits) — GETEVENTS never parks the loop.
-
-    A loopback socketpair self-test runs at construction: kernels where
-    READ_FIXED doesn't work on sockets fail HERE, and the pool latches
-    the ring unavailable — permanent silent fallback to sock_recv_into.
-    An op-level ring error mid-payload is equally safe: a failed op
-    consumed no stream bytes, so the remainder finishes on the socket
-    path byte-exactly and the ring is latched dead.
-
-    Thread-safety: one process-wide instance may serve event loops on
-    several threads (the in-proc fleet); each op is a single locked
-    prep→enter→reap→copy critical section, so at most one SQE is ever
-    in flight and a reap can only harvest its own completion."""
-
-    def __init__(self, slab_bytes: int = 1024 * 1024, nslabs: int = 2):
-        self.slab_bytes = slab_bytes
-        self.dead = False
-        self.fixed_ops = 0
-        self.fixed_bytes = 0
-        self._lock = threading.Lock()
-        self._slabs: list[mmap.mmap] = []
-        self._exports: list = []        # ctypes views pinning the slabs
-        self._addrs: list[int] = []
-        # lazy import: keeps pure-client processes from paying the
-        # worker package import unless the ring actually arms
-        from curvine_tpu.worker.io_engine import UringRing
-        self.ring = UringRing(entries=max(4, nslabs))
-        try:
-            for _ in range(nslabs):
-                mm = mmap.mmap(-1, slab_bytes)
-                exp = (ctypes.c_char * slab_bytes).from_buffer(mm)
-                self._slabs.append(mm)
-                self._exports.append(exp)
-                self._addrs.append(ctypes.addressof(exp))
-            self.ring.register_buffers(
-                [(a, slab_bytes) for a in self._addrs])
-            self._self_test()
-        except BaseException:
-            self.close()
-            raise
-
-    def _read_once(self, fd: int, want: int, dst: memoryview) -> int:
-        """One READ_FIXED of up to min(want, slab) bytes, copied out to
-        ``dst``. Returns bytes consumed, 0 on EOF, -1 on EAGAIN; raises
-        OSError on op failure (which consumed no stream bytes)."""
-        want = min(want, self.slab_bytes)
-        with self._lock:
-            # offset 0: sockets are non-seekable, io_uring wants 0 here
-            self.ring.prep_read_fixed(fd, self._addrs[0], want, 0, 0, 1)
-            self.ring.submit_and_wait(1)
-            cqes = self.ring.reap()
-            while not cqes:             # EINTR mid-wait: wait again
-                self.ring.submit_and_wait(1)
-                cqes = self.ring.reap()
-            res = cqes[-1][1]
-            if res == -errno.EAGAIN:
-                return -1
-            if res < 0:
-                raise OSError(-res, "io_uring READ_FIXED failed")
-            if res > 0:
-                dst[:res] = self._slabs[0][:res]
-                self.fixed_ops += 1
-                self.fixed_bytes += res
-            return res
-
-    async def recv_into(self, loop: asyncio.AbstractEventLoop,
-                        sock: socket.socket, view: memoryview) -> None:
-        """Fill ``view`` completely — the ring-armed twin of
-        ``recv_exact`` (byte-exact, including the fallback legs)."""
-        off, n = 0, len(view)
-        while off < n:
-            try:
-                await _wait_readable(loop, sock)
-            except NotImplementedError:       # loop without add_reader
-                self.dead = True
-                await recv_exact(loop, sock, view[off:])
-                return
-            try:
-                got = self._read_once(sock.fileno(), n - off, view[off:])
-            except OSError as e:
-                if e.errno in _RING_FATAL:
-                    self.dead = True
-                    log.warning("ring recv latched off: %s", e)
-                    await recv_exact(loop, sock, view[off:])
-                    return
-                raise
-            if got == 0:
-                raise ConnectionResetError("peer closed")
-            if got > 0:
-                off += got
-
-    def _self_test(self) -> None:
-        """Loopback proof that READ_FIXED works on sockets HERE: any
-        failure raises and the caller latches the fallback."""
-        a, b = socket.socketpair()
-        try:
-            payload = bytes(range(256)) * 16
-            a.sendall(payload)
-            b.setblocking(False)
-            out = bytearray(len(payload))
-            off = 0
-            while off < len(payload):
-                r = self._read_once(b.fileno(), len(payload) - off,
-                                    memoryview(out)[off:])
-                if r <= 0:
-                    raise OSError(errno.EINVAL,
-                                  "ring self-test: short read")
-                off += r
-            if bytes(out) != payload:
-                raise OSError(errno.EINVAL,
-                              "ring self-test: payload mismatch")
-        finally:
-            a.close()
-            b.close()
-        self.fixed_ops = 0              # probe doesn't count as traffic
-        self.fixed_bytes = 0
-
-    def close(self) -> None:
-        self.dead = True
-        # ctypes exports pin the slab mmaps; drop them first
-        self._exports = []
-        self._addrs = []
-        ring = getattr(self, "ring", None)
-        if ring is not None:
-            try:
-                ring.close()
-            except OSError:
-                pass
-        for mm in self._slabs:
-            try:
-                mm.close()
-            except BufferError:         # straggler view; GC frees
-                pass
-        self._slabs = []
-
-
 class RegisteredBuffers:
     """Bounded reuse pool of page-aligned mmap regions, by power-of-two
     size class (mirror of io_engine.BufferPool for the receive side).
@@ -283,10 +105,6 @@ class RegisteredBuffers:
         self._lock = threading.Lock()
         self.acquired = 0
         self.reused = 0
-        # the ring-registered receive path (RingRecv), built lazily and
-        # latched permanently off on any failure
-        self._ring: RingRecv | None = None
-        self._ring_state = 0                # 0 untried, 1 armed, -1 off
 
     def _cls(self, n: int) -> int:
         size = self.min_size
@@ -363,55 +181,14 @@ class RegisteredBuffers:
                 self._resident.add(id(base))
                 self.retained += size
 
-    def ring(self) -> RingRecv | None:
-        """The process RingRecv, built + self-tested on first use; None
-        when io_uring fixed-buffer recv is unavailable (latched — the
-        permanent silent-fallback contract)."""
-        with self._lock:
-            state = self._ring_state
-        if state == 0:
-            try:
-                r = RingRecv()
-            except Exception as e:  # noqa: BLE001 — any failure latches
-                log.info("ring recv unavailable, using sock_recv_into: "
-                         "%s", e)
-                r = None
-            with self._lock:
-                if self._ring_state == 0:
-                    self._ring = r
-                    self._ring_state = 1 if r is not None else -1
-                    r = None
-            if r is not None:
-                r.close()                # lost the arming race
-        ring = self._ring
-        if ring is not None and ring.dead:
-            with self._lock:
-                if self._ring is ring:
-                    self._ring = None
-                    self._ring_state = -1
-            ring.close()
-            return None
-        return ring
-
-    def ring_registered(self) -> bool:
-        """Armed and healthy (never constructs the ring — safe for
-        metrics scrapes)."""
-        ring = self._ring
-        return (self._ring_state == 1 and ring is not None
-                and not ring.dead)
-
     def stats(self) -> dict:
         """Flattened gauges/counters for /metrics (worker heartbeat
         prefixes these with ``rpc.recv_``)."""
-        ring = self._ring
         return {
             "registered_bytes": self.retained,
             "pinned_bytes": self.pinned,
             "acquired": self.acquired,
             "reused": self.reused,
-            "ring_registered": 1 if self.ring_registered() else 0,
-            "fixed_ops": ring.fixed_ops if ring is not None else 0,
-            "fixed_bytes": ring.fixed_bytes if ring is not None else 0,
         }
 
     def drain(self) -> None:
@@ -867,16 +644,6 @@ class BulkDecoder:
         with recv accounting."""
         await recv_exact(loop, sock, view)
         self._account(len(view))
-
-    async def recv_sink(self, loop, sock, view: memoryview,
-                        ring: RingRecv | None = None) -> None:
-        """Sink-remainder receive: the ring fixed-buffer path when one
-        is armed, plain exact recv otherwise. Byte-exact either way."""
-        if ring is not None:
-            await ring.recv_into(loop, sock, view)
-            self._account(len(view))
-        else:
-            await self.recv_exact(loop, sock, view)
 
     async def read_payload(self, loop, sock, n: int) -> memoryview:
         """A contiguous view of the next ``n`` payload bytes, valid

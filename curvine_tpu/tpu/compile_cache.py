@@ -20,8 +20,8 @@ def enable_compile_cache() -> str | None:
     """Turn the persistent cache on for this process and return its
     directory. Where JAX_COMPILATION_CACHE_DIR is given JAX reads it
     itself and the program sets no directory; otherwise DEFAULT_DIR.
-    Call before the first compilation — chip_smoke.py, bench.py and the
-    worker's tier-0 start-up do. The CPU backend (the test mesh) is left
+    Call before the first compilation — chip_smoke.py and the worker's
+    tier-0 start-up do. The CPU backend (the test mesh) is left
     alone: its compiles are cheap, and tests would fill the checkout with
     entries."""
     if jax.default_backend() == "cpu":

@@ -24,8 +24,7 @@ Three coupled pieces (docs/ici-plane.md):
   replicated transfer. On a real pod the chunks ride the ICI fan-out
   back-to-back so every link stays busy (classic pipelined-tree
   broadcast); on the CPU interpret mesh the same chunking keeps each
-  transfer inside the runtime's recycled-buffer fast path, measured ~4x
-  the flat single-put baseline (bench.py::_ici_smoke). The
+  transfer inside the runtime's recycled-buffer fast path. The
   topology-derived schedule (`broadcast_schedule`) plans one reader per
   host with log2-depth ICI fan-out rounds after it.
 """

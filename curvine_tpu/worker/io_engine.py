@@ -131,15 +131,12 @@ class BufferPool:
 
 _SYS_IO_URING_SETUP = 425
 _SYS_IO_URING_ENTER = 426
-_SYS_IO_URING_REGISTER = 427
 _IORING_OFF_SQ_RING = 0
 _IORING_OFF_CQ_RING = 0x8000000
 _IORING_OFF_SQES = 0x10000000
 _IORING_ENTER_GETEVENTS = 1
 _IORING_FEAT_SINGLE_MMAP = 1
 _IORING_OP_READ = 22                     # addr/len read, kernel >= 5.6
-_IORING_OP_READ_FIXED = 4                # read into a registered buffer
-_IORING_REGISTER_BUFFERS = 0
 
 
 class _SqringOffsets(ctypes.Structure):
@@ -187,11 +184,6 @@ class _Sqe(ctypes.Structure):
 class _Cqe(ctypes.Structure):
     _fields_ = [("user_data", ctypes.c_uint64), ("res", ctypes.c_int32),
                 ("flags", ctypes.c_uint32)]
-
-
-class _Iovec(ctypes.Structure):
-    _fields_ = [("iov_base", ctypes.c_void_p),
-                ("iov_len", ctypes.c_size_t)]
 
 
 class UringRing:
@@ -244,46 +236,6 @@ class UringRing:
 
     def sq_space(self) -> int:
         return self.entries - (self._sq_tail.value - self._sq_head.value)
-
-    def register_buffers(self, bufs: list[tuple[int, int]]) -> None:
-        """Pin ``bufs`` ([(addr, len)]) into the ring's fixed-buffer
-        table. After this, ``prep_read_fixed`` ops may name a buffer by
-        index and the kernel skips the per-op get_user_pages walk — the
-        point of the registered receive path (rpc/transport.RingRecv).
-        Raises OSError where the kernel lacks IORING_REGISTER_BUFFERS
-        or refuses to pin (RLIMIT_MEMLOCK); callers fall back."""
-        iovs = (_Iovec * len(bufs))()
-        for i, (addr, ln) in enumerate(bufs):
-            iovs[i].iov_base = addr
-            iovs[i].iov_len = ln
-        r = self._libc.syscall(_SYS_IO_URING_REGISTER, self.fd,
-                               _IORING_REGISTER_BUFFERS,
-                               ctypes.byref(iovs), len(bufs))
-        if r < 0:
-            raise OSError(ctypes.get_errno(), "io_uring_register failed")
-        self._reg_iovs = iovs       # keep the table alive for the ring
-
-    def prep_read_fixed(self, fd: int, buf_addr: int, length: int,
-                        offset: int, buf_index: int,
-                        user_data: int) -> None:
-        """Like prep_read but against a registered buffer: buf_addr must
-        point inside registered buffer ``buf_index``. The sqe buf_index
-        union member is the u16 at offset 40 — the first two bytes of
-        the ``rest`` pad."""
-        tail = self._sq_tail.value
-        idx = tail & self._sq_mask
-        sqe = self._sqes[idx]
-        ctypes.memset(ctypes.byref(sqe), 0, ctypes.sizeof(_Sqe))
-        sqe.opcode = _IORING_OP_READ_FIXED
-        sqe.fd = fd
-        sqe.off = offset
-        sqe.addr = buf_addr
-        sqe.len = length
-        sqe.user_data = user_data
-        sqe.rest[0] = buf_index & 0xFF
-        sqe.rest[1] = (buf_index >> 8) & 0xFF
-        self._sq_array[idx] = idx
-        self._sq_tail.value = tail + 1
 
     def prep_read(self, fd: int, buf_addr: int, length: int, offset: int,
                   user_data: int) -> None:

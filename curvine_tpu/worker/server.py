@@ -420,10 +420,9 @@ class WorkerServer:
                 elif k in ("policy_admits", "policy_ghost_hits",
                            "policy_scan_evicted"):
                     out[f"cache.shm_warm.{k[len('policy_'):]}"] = v
-        # ring-registered receive plane: pool-resident bytes only (the
-        # satellite-1 accounting contract — caller-pinned views are NOT
-        # occupancy), whether the io_uring registration armed, and the
-        # READ_FIXED op count; gauges land on /metrics via the heartbeat
+        # registered receive pool: pool-resident bytes only (caller-
+        # pinned views are NOT occupancy); gauges land on /metrics via
+        # the heartbeat
         from curvine_tpu.rpc import transport
         for k, v in transport.recv_pool().stats().items():
             out[f"rpc.recv_{k}"] = v

@@ -21,7 +21,7 @@ process, writes one block-sized file, then forks worker PROCESSES
 coroutines — real processes so 1K clients exercise 1K connections and
 the SCM_RIGHTS side channel across address spaces, not one event loop
 pretending. ``--no-shm`` reruns the same ladder with worker.shm_reads
-off for A/B comparison (bench.py's shm gate uses this)."""
+off for A/B comparison."""
 
 from __future__ import annotations
 

@@ -12,8 +12,7 @@ engine and measure, at each milestone:
 then time a cold restart (journal-tail replay over the KV applied_seq).
 
 In-process by design: the curve isolates the metadata write path itself
-(journal + store + group commit), not the RPC plane — bench.py's
-meta_create_qps covers the RPC side.
+(journal + store + group commit), not the RPC plane.
 
 Usage:
   python scripts/namespace_scale.py                  # full 10M curve
@@ -145,44 +144,12 @@ def build_fs_existing(base: str, engine: str, fsync: bool, group_ms: float):
     return build_fs(base, engine, fsync, group_ms)
 
 
-async def run_shards(args) -> dict:
-    """Sharded-namespace scaling curve: the SAME batched-create storm at
-    each shard count in --shards (e.g. 1,2,4), full RPC plane (client →
-    router → shard), via bench._shard_smoke. shards=1 is the unsharded
-    master — the honest A side of the A/B. On boxes with fewer cores
-    than shards the curve is expected flat (shard processes time-slice
-    one core); the artifact records cpus + backend so that can't read
-    as a regression."""
-    from bench import _shard_smoke
-    shard_list = [int(s) for s in args.shards.split(",")]
-    n_create = 2_000 if args.quick else 20_000
-    points = []
-    for s in shard_list:
-        r = await _shard_smoke(s, n_create=n_create,
-                               backend=args.shard_backend or None)
-        print(json.dumps(r), flush=True)
-        points.append(r)
-    base_qps = points[0]["meta_create_shard_qps"]
-    out = {
-        "mode": "shard_curve",
-        "n_create": n_create,
-        "cpus": points[0]["cpus"],
-        "shard_curve": points,
-        "speedup_vs_first": {
-            str(r["shards"]): round(
-                r["meta_create_shard_qps"] / max(base_qps, 1e-9), 2)
-            for r in points},
-        "ok": all(r["meta_create_shard_qps"] > 0 for r in points),
-    }
-    return out
-
-
 def main() -> int:
     p = argparse.ArgumentParser(description=__doc__.splitlines()[0])
     p.add_argument("--files", type=int, default=10_000_000)
     p.add_argument("--milestones", default="1000000,5000000,10000000")
     p.add_argument("--quick", action="store_true",
-                   help="50K-file CI smoke (perf_smoke.sh / tier-1 slow)")
+                   help="50K-file smoke (tests/test_namespace_scale.py, slow)")
     p.add_argument("--batch", type=int, default=1024,
                    help="creates per group-commit sync (the RPC-equivalent)")
     p.add_argument("--engine", default="native",
@@ -194,18 +161,11 @@ def main() -> int:
                    help="keep the journal/meta dirs after the run")
     p.add_argument("--out", default="",
                    help="also write the result JSON to this path")
-    p.add_argument("--shards", default="",
-                   help="comma list of shard counts (e.g. 1,2,4): run the "
-                        "sharded-namespace create-QPS curve over the full "
-                        "RPC plane instead of the in-process curve")
-    p.add_argument("--shard-backend", default="",
-                   help="force the shard backend (process|inproc); "
-                        "default auto-picks by core count")
     args = p.parse_args()
     if args.quick:
         args.files = 50_000
         args.milestones = "50000"
-    res = asyncio.run(run_shards(args) if args.shards else run(args))
+    res = asyncio.run(run(args))
     print(json.dumps(res, indent=2))
     if args.out:
         with open(args.out, "w") as f:

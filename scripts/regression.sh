@@ -1,7 +1,7 @@
 #!/bin/bash
 # Nightly regression harness (parity:
 # curvine-tests/regression/daily_regression_test.sh — drives the full
-# suite + dryrun + bench and emits an HTML report + JSON summary).
+# suite + dryrun + the chip smoke and emits an HTML report + JSON summary).
 #
 # Usage: scripts/regression.sh <project_root> <result_dir> [pytest-expr]
 # Exit code: 0 = everything green, 1 = any stage failed.
@@ -46,11 +46,8 @@ else
 fi
 run_stage dryrun-multichip dryrun.log \
     python -c "import __graft_entry__ as g; g.dryrun_multichip(8)"
-# the two stages below need a TPU and fail without one (each is its own
-# process, one after the other: one process per chip)
+# needs a TPU and fails without one
 run_stage chip-smoke smoke.log python chip_smoke.py
-run_stage bench bench.log python bench.py
-grep -h '^{' "$OUT/bench.log" | tail -1 > "$OUT/bench.json" 2>/dev/null
 
 # ---- HTML report ----
 {
@@ -65,10 +62,7 @@ grep -h '^{' "$OUT/bench.log" | tail -1 > "$OUT/bench.json" 2>/dev/null
         echo "<tr><td>$1</td><td class=$2>$2</td><td>$3</td></tr>"
     done < "$OUT/stages.jsonl"
     echo "</table>"
-    if [ -s "$OUT/bench.json" ]; then
-        echo "<h2>bench</h2><pre>$(python -m json.tool < "$OUT/bench.json")</pre>"
-    fi
-    echo "<p>logs: pytest.log · dryrun.log · smoke.log · bench.log</p>"
+    echo "<p>logs: pytest.log · dryrun.log · smoke.log</p>"
 } > "$OUT/report.html"
 
 echo "report: $OUT/report.html"

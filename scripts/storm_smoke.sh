@@ -13,8 +13,7 @@
 # scan against a hot read loop: S3-FIFO admission must hold the
 # post-quiesce hot hit rate above the floor, docs/caching.md) — plus
 # the deadline/breaker acceptance tests from
-# tests/test_storm.py and fail on any invariant violation. Mirrors
-# scripts/perf_smoke.sh.
+# tests/test_storm.py and fail on any invariant violation.
 #
 # Usage: scripts/storm_smoke.sh [project_root]
 #   STORM_RAFT_REPEAT=N   additionally run the raft election/storm tests

@@ -4,16 +4,17 @@ without a TPU, a rate above the published peak is a failure, daemons stay
 off JAX, and nothing on the tier-0 path falls back quietly."""
 
 import os
+import re
 import subprocess
 import sys
-import types
 
 import jax
 import pytest
 
 import chip_smoke
 from curvine_tpu.common.conf import ClusterConf
-from curvine_tpu.tpu import compile_cache, model, peaks
+from curvine_tpu.tpu import compile_cache, model
+from perfbench import peaks
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -55,10 +56,27 @@ def test_rate_above_the_published_peak_fails():
 
 
 def test_unlisted_device_kind_is_an_error():
-    v5e = types.SimpleNamespace(device_kind="TPU v5 lite")
-    assert peaks.peaks_of(v5e)["bf16_flops"] == 197e12
+    assert peaks.peaks_of("TPU v5 lite")["bf16_flops"] == 197e12
     with pytest.raises(ValueError, match="TPU v6 lite"):
-        peaks.peaks_of(types.SimpleNamespace(device_kind="TPU v6 lite"))
+        peaks.peaks_of("TPU v6 lite")
+
+
+def test_every_regression_stage_names_a_program_in_the_checkout():
+    """A stage cannot outlive its program: whatever a `run_stage` line
+    of scripts/regression.sh runs — a script, a test directory, a module
+    it imports — is in the tree."""
+    with open(os.path.join(REPO, "scripts", "regression.sh")) as f:
+        text = f.read().replace("\\\n", " ")
+    stages = [ln for ln in text.splitlines()
+              if ln.lstrip().startswith("run_stage ")]
+    named = []
+    for ln in stages:
+        named += re.findall(r"[\w./-]+\.(?:py|sh)\b|\b[\w.-]+/(?=\s|$)", ln)
+        named += [m + ".py" for m in re.findall(r"\bimport (\w+)", ln)]
+    assert {"tests/", "__graft_entry__.py", "chip_smoke.py"} <= set(named)
+    missing = [n for n in named
+               if not os.path.exists(os.path.join(REPO, n))]
+    assert not missing, f"regression.sh stages run {missing}: not in the tree"
 
 
 def test_daemon_imports_stay_off_jax():

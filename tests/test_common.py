@@ -3,12 +3,15 @@
 Mirrors reference tests: curvine-common/tests/ (proto roundtrips, conf,
 fs_error) and journal_test.rs."""
 
+import dataclasses
 import os
+import textwrap
+import tomllib
 
 import pytest
 
 from curvine_tpu.common import errors as err
-from curvine_tpu.common.conf import ClusterConf
+from curvine_tpu.common.conf import ClusterConf, TierConf
 from curvine_tpu.common.journal import Journal
 from curvine_tpu.common.metrics import MetricsRegistry
 from curvine_tpu.common.path import Path, norm_path
@@ -17,6 +20,8 @@ from curvine_tpu.common.types import (
     MasterInfo, MountInfo, StoragePolicy, StorageType, TtlAction,
     WorkerAddress, WorkerInfo, StorageInfo,
 )
+
+REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_wire_roundtrip():
@@ -274,6 +279,50 @@ def test_conf_env_overrides(tmp_path):
     assert c.client.short_circuit is False
     assert c.data_dir == "/data"
     assert c.worker.tiers and c.worker.tiers[0].storage_type == "mem"
+
+
+def _conf_mismatches(obj, data: dict, where: str = ""):
+    """Keys of a parsed conf file that name no field of the conf object,
+    or whose value did not land on it."""
+    fields = {f.name for f in dataclasses.fields(obj)}
+    for k, v in data.items():
+        if k not in fields:
+            yield f"{where}{k}: no such field"
+        elif k == "tiers":
+            tier_fields = {f.name for f in dataclasses.fields(TierConf)}
+            for t in v:
+                yield from (f"{where}{k}.{tk}: no such field"
+                            for tk in t if tk not in tier_fields)
+            if getattr(obj, k) != [TierConf(**t) for t in v]:
+                yield f"{where}{k}: not loaded"
+        elif dataclasses.is_dataclass(getattr(obj, k)):
+            yield from _conf_mismatches(getattr(obj, k), v, f"{where}{k}.")
+        elif getattr(obj, k) != v:
+            yield f"{where}{k}: {getattr(obj, k)!r} loaded for {v!r}"
+
+
+@pytest.mark.parametrize("shipped", [
+    "etc/curvine-cluster.toml",
+    "deploy/conf/compose.toml",
+    "deploy/k8s/curvine-conf.yaml",
+])
+def test_shipped_conf_names_only_fields_that_exist(shipped, tmp_path):
+    """ClusterConf.load skips a key it does not know, so an option that
+    left the program stays in a shipped file unnoticed: every section
+    and key of the files we ship names a field (a `tiers` entry: of
+    TierConf), and the loaded conf holds the file's values."""
+    with open(os.path.join(REPO, shipped)) as f:
+        text = f.read()
+    if shipped.endswith(".yaml"):   # the TOML is a ConfigMap block scalar
+        block = text.split("curvine-cluster.toml: |\n", 1)[1].splitlines()
+        text = textwrap.dedent("\n".join(
+            ln for ln in block if not ln.strip() or ln.startswith("    ")))
+    data = tomllib.loads(text)
+    assert {"master", "worker", "client"} <= set(data)
+    f = tmp_path / "shipped.toml"
+    f.write_text(text)
+    conf = ClusterConf.load(str(f), env={})
+    assert list(_conf_mismatches(conf, data)) == []
 
 
 # ---------------- group commit (journal batching) ----------------

@@ -1006,8 +1006,8 @@ async def test_read_plane_rollup_reaches_master(tmp_path):
 async def test_latency_ladder_smoke():
     """One scaled-down open-loop rung (64 clients over a CPU-pinned
     process fleet, Poisson arrivals) completes with zero errors — the
-    tier-1 guard for scripts/latency_ladder.py and the perf_smoke
-    concurrency gate, now covering the --cpus multi-core tail path."""
+    tier-1 guard for scripts/latency_ladder.py, covering the --cpus
+    multi-core tail path."""
     scripts = os.path.join(os.path.dirname(os.path.dirname(
         os.path.abspath(__file__))), "scripts")
     if scripts not in sys.path:

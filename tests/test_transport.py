@@ -6,10 +6,9 @@ points, MAX_FRAME rejection mid-batch, interleaved CHUNK-sink + control
 frames landing in one bulk buffer, the cancelled-send contract under the
 coalesced writer (queued cancel = frame-boundary drop, NOT poisoned;
 inline cancel mid-write = poisoned, PR-2 semantics), batch coalescing
-metrics, and the optional-uvloop fallback."""
+metrics, and sink payloads received straight into aligned buffers."""
 
 import asyncio
-import logging
 import socket
 
 import pytest
@@ -17,15 +16,14 @@ import pytest
 from curvine_tpu.common.errors import ConnectError, CurvineError
 from curvine_tpu.common.metrics import MetricsRegistry
 from curvine_tpu.rpc import RpcServer
-from curvine_tpu.rpc import loops as loops_mod
 from curvine_tpu.rpc import transport as transport_mod
-from curvine_tpu.rpc.client import Connection
+from curvine_tpu.rpc.client import Connection, ConnectionPool
 from curvine_tpu.rpc.frame import (
     ENVELOPE_MAX, FIXED_LEN, LEN_PREFIX, MAX_FRAME, Flags, Message,
     decode_envelope,
 )
 from curvine_tpu.rpc.transport import (
-    BulkDecoder, CoalescedWriter, vectored_sendall,
+    BulkDecoder, CoalescedWriter, alloc_aligned, vectored_sendall,
 )
 
 
@@ -321,6 +319,12 @@ async def test_inline_cancel_mid_write_poisons():
 # ------------------------------------------------------- end to end
 
 
+def _chunk(i: int, size: int) -> bytes:
+    """Chunk ``i`` of a stream: every byte depends on its position, so a
+    remainder that lands shifted or twice does not compare equal."""
+    return (bytes(range(256)) * (size // 256 + 2))[i % 256:i % 256 + size]
+
+
 async def _echo_server(metrics=None):
     srv = RpcServer("127.0.0.1", 0, "test")
     srv.metrics = metrics
@@ -330,13 +334,14 @@ async def _echo_server(metrics=None):
     srv.register(9_900, echo)
 
     async def stream(msg, conn):
-        # CHUNK frames + EOF: sizes chosen so several fit one recv
+        # CHUNK frames + EOF: at the default size several fit one recv
         n = int(msg.header.get("chunks", 4))
+        size = int(msg.header.get("chunk_size", 1024))
         for i in range(n):
             await conn.send(Message(
                 code=msg.code, req_id=msg.req_id,
                 flags=Flags.RESPONSE | Flags.CHUNK,
-                data=bytes([i]) * 1024))
+                data=_chunk(i, size)))
         await conn.send(Message(code=msg.code, req_id=msg.req_id,
                                 flags=Flags.RESPONSE | Flags.EOF))
         return None
@@ -366,7 +371,7 @@ async def test_interleaved_chunk_sink_and_control_frames():
         await storm
         assert got == chunks * 1024
         for i in range(chunks):
-            assert sink[i * 1024:(i + 1) * 1024] == bytes([i]) * 1024
+            assert sink[i * 1024:(i + 1) * 1024] == _chunk(i, 1024)
         # transport counters flowed on both peers
         assert m.counters["rpc.bytes_sent"] > 0
         assert m.counters["rpc.bytes_recv"] > 0
@@ -436,94 +441,7 @@ async def test_server_rejects_oversized_frame_mid_stream():
         await srv.stop()
 
 
-# ------------------------------------------- registered receive / ring
-
-
-def _make_ring():
-    try:
-        return transport_mod.RingRecv(slab_bytes=256 * 1024, nslabs=2)
-    except Exception as e:  # noqa: BLE001 — any failure = unavailable
-        pytest.skip(f"io_uring fixed-buffer recv unavailable: {e}")
-
-
-async def test_ring_recv_byte_exact_multi_slab():
-    """READ_FIXED recv over a socketpair, payload several times the
-    slab size: bytes land exactly as sock_recv_into would deliver them
-    and the fixed-op counters account the traffic."""
-    loop = asyncio.get_running_loop()
-    ring = _make_ring()
-    a, b = _nb_socketpair()
-    try:
-        payload = bytes(range(256)) * 4096          # 1MB > 256K slab
-        send = asyncio.ensure_future(loop.sock_sendall(a, payload))
-        out = bytearray(len(payload))
-        await ring.recv_into(loop, b, memoryview(out))
-        await send
-        assert bytes(out) == payload
-        assert ring.fixed_ops >= len(payload) // ring.slab_bytes
-        assert ring.fixed_bytes == len(payload)
-        assert not ring.dead
-    finally:
-        a.close()
-        b.close()
-        ring.close()
-
-
-async def test_ring_fatal_error_latches_and_falls_back(monkeypatch):
-    """A ring-infrastructure errno mid-payload latches the ring dead
-    and finishes the payload on the socket path — byte-exact, because a
-    failed op consumed no stream bytes. The pool then reports the ring
-    unregistered and hands out None forever."""
-    import errno as _errno
-    loop = asyncio.get_running_loop()
-    ring = _make_ring()
-    a, b = _nb_socketpair()
-    try:
-        def boom(fd, want, dst):
-            raise OSError(_errno.ENOSYS, "ring gone")
-
-        monkeypatch.setattr(ring, "_read_once", boom)
-        payload = bytes(range(256)) * 512
-        send = asyncio.ensure_future(loop.sock_sendall(a, payload))
-        out = bytearray(len(payload))
-        await ring.recv_into(loop, b, memoryview(out))
-        await send
-        assert bytes(out) == payload                # fallback byte-exact
-        assert ring.dead
-
-        pool = transport_mod.RegisteredBuffers()
-        pool._ring = ring
-        pool._ring_state = 1
-        assert not pool.ring_registered()
-        assert pool.stats()["ring_registered"] == 0
-        assert pool.ring() is None                  # latched permanently
-        assert pool._ring_state == -1
-    finally:
-        a.close()
-        b.close()
-        ring.close()
-
-
-async def test_ring_stream_error_propagates(monkeypatch):
-    """A NON-fatal errno (the stream died, not the ring) must propagate
-    like the sock path would — no silent retry, no latch-off."""
-    import errno as _errno
-    loop = asyncio.get_running_loop()
-    ring = _make_ring()
-    a, b = _nb_socketpair()
-    try:
-        def boom(fd, want, dst):
-            raise OSError(_errno.ECONNRESET, "peer vanished")
-
-        monkeypatch.setattr(ring, "_read_once", boom)
-        await loop.sock_sendall(a, b"x" * 64)
-        with pytest.raises(OSError) as ei:
-            await ring.recv_into(loop, b, memoryview(bytearray(64)))
-        assert ei.value.errno == _errno.ECONNRESET
-    finally:
-        a.close()
-        b.close()
-        ring.close()
+# ------------------------------------------------ registered receive
 
 
 def test_registered_pool_pinned_accounting_and_double_release():
@@ -565,80 +483,84 @@ def test_registered_pool_pinned_accounting_and_double_release():
     del e
     gc.collect()
     assert pool.pinned == 0
-    # stats() exposes the /metrics keys and never constructs the ring
+    # stats() exposes the /metrics keys
     st = pool.stats()
     assert set(st) == {"registered_bytes", "pinned_bytes", "acquired",
-                       "reused", "ring_registered", "fixed_ops",
-                       "fixed_bytes"}
+                       "reused"}
     assert st["registered_bytes"] == pool.retained
     assert st["pinned_bytes"] == 0
-    assert pool._ring_state == 0, "stats() must not arm io_uring"
     pool.drain()
     assert pool.retained == 0
 
 
-def test_connection_ring_gate(monkeypatch):
-    """rpc.recv_ring / recv_ring_min gate the ring path per call; only
-    large remainders with the flag on reach the pool."""
-    from types import SimpleNamespace
-    from curvine_tpu.rpc import client as client_mod
-    sentinel = object()
-    monkeypatch.setattr(client_mod, "recv_pool",
-                        lambda: SimpleNamespace(ring=lambda: sentinel))
-    off = Connection("h:1", rpc_conf=SimpleNamespace(recv_ring=False))
-    assert off._ring_for(64 * 1024 * 1024) is None
-    on = Connection("h:1", rpc_conf=SimpleNamespace(
-        recv_ring=True, recv_ring_min=256 * 1024))
-    assert on._ring_for(4096) is None           # under the floor
-    assert on._ring_for(1024 * 1024) is sentinel
-
-
-async def test_large_sink_payload_with_ring_policy_end_to_end():
-    """A multi-chunk sink stream with the ring policy enabled at a tiny
-    floor: bytes are exact whether the kernel armed READ_FIXED or the
-    silent sock_recv_into fallback served it — the contract is that the
-    caller cannot tell the difference."""
-    from types import SimpleNamespace
+@pytest.mark.parametrize("chunks,chunk_size", [
+    (8, 1024),                    # several frames in one bulk recv
+    (1, 256 * 1024),              # one frame the size of the recv buffer
+    (1, 1024 * 1024 + 1),         # one frame, odd length, mostly remainder
+])
+async def test_large_sink_payload_end_to_end(chunks, chunk_size):
+    """The data remainder of a reply frame is received straight into an
+    aligned destination, byte-exact, whether the frame fits the bulk
+    buffer or nearly all of it arrives after the envelope."""
     srv = await _echo_server()
-    rc = SimpleNamespace(recv_ring=True, recv_ring_min=4 * 1024)
-    conn = await Connection(f"127.0.0.1:{srv.port}", rpc_conf=rc).connect()
+    conn = await Connection(f"127.0.0.1:{srv.port}").connect()
     try:
-        chunks = 8
-        sink = bytearray(chunks * 1024)
-        got = await conn.call_readinto(9_901, memoryview(sink),
-                                       header={"chunks": chunks})
-        assert got == chunks * 1024
+        sink = alloc_aligned(chunks * chunk_size)
+        assert sink.ctypes.data % 4096 == 0
+        got = await conn.call_readinto(
+            9_901, memoryview(sink),
+            header={"chunks": chunks, "chunk_size": chunk_size})
+        assert got == chunks * chunk_size
         for i in range(chunks):
-            assert sink[i * 1024:(i + 1) * 1024] == bytes([i]) * 1024
+            assert (bytes(sink[i * chunk_size:(i + 1) * chunk_size])
+                    == _chunk(i, chunk_size))
     finally:
         await conn.close()
         await srv.stop()
 
 
-# ------------------------------------------------------------ uvloop
+async def test_peer_close_mid_sink_remainder_fails_the_read():
+    """The peer dies with half of a sink remainder on the wire:
+    call_readinto raises (the sink is never handed back as complete),
+    the connection is closed, and the pool dials a new one for the next
+    call, which is served whole."""
+    size = 512 * 1024                    # twice the bulk recv buffer
+    served = []
 
+    async def serve(reader, writer):
+        total, = LEN_PREFIX.unpack(await reader.readexactly(4))
+        body = await reader.readexactly(total)
+        _, code, req_id, *_ = decode_envelope(
+            bytearray(LEN_PREFIX.pack(total) + body), 0, 4 + total)
+        frame = _frame_bytes(Message(
+            code=code, req_id=req_id, flags=Flags.RESPONSE | Flags.CHUNK,
+            data=_chunk(0, size)))
+        first = not served
+        served.append(req_id)
+        if first:
+            writer.write(frame[:len(frame) // 2])
+        else:
+            writer.write(frame + _frame_bytes(Message(
+                code=code, req_id=req_id,
+                flags=Flags.RESPONSE | Flags.EOF)))
+        await writer.drain()
+        writer.close()
 
-class _RC:
-    def __init__(self, uvloop):
-        self.uvloop = uvloop
-
-
-def test_install_event_loop_disabled_is_noop():
-    assert loops_mod.install_event_loop(None) == "asyncio"
-    assert loops_mod.install_event_loop(_RC(False)) == "asyncio"
-
-
-def test_install_event_loop_fallback_warns_once(caplog, monkeypatch):
+    srv = await asyncio.start_server(serve, "127.0.0.1", 0)
+    addr = "127.0.0.1:%d" % srv.sockets[0].getsockname()[1]
+    pool = ConnectionPool(size=1)
     try:
-        import uvloop  # noqa: F401
-    except ImportError:
-        pass
-    else:
-        pytest.skip("uvloop installed; fallback path not reachable")
-    monkeypatch.setattr(loops_mod, "_warned", False)
-    with caplog.at_level(logging.WARNING, logger="curvine_tpu.rpc.loops"):
-        assert loops_mod.install_event_loop(_RC(True)) == "asyncio"
-        assert loops_mod.install_event_loop(_RC(True)) == "asyncio"
-    warns = [r for r in caplog.records if "uvloop" in r.getMessage()]
-    assert len(warns) == 1, "fallback must warn exactly once"
-    assert loops_mod.loop_impl() == "asyncio"
+        conn = await pool.get(addr)
+        sink = alloc_aligned(size)
+        with pytest.raises(ConnectError):
+            await conn.call_readinto(9_901, memoryview(sink))
+        assert conn.closed
+        assert bytes(sink) != _chunk(0, size)
+        again = await pool.get(addr)
+        assert again is not conn and not again.closed
+        assert await again.call_readinto(9_901, memoryview(sink)) == size
+        assert bytes(sink) == _chunk(0, size)
+    finally:
+        await pool.close()
+        srv.close()
+        await srv.wait_closed()

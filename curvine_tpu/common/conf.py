@@ -197,9 +197,11 @@ class WorkerConf:
     # zero-RPC, zero-copy mmap slice. Needs os.memfd_create (Linux);
     # auto-disabled elsewhere and clients fall back to the socket path.
     shm_reads: bool = True
-    # sealed-memfd export cache entries (LRU; evictions close the
-    # worker-side fd — client-held dups stay valid, unlink semantics)
-    shm_export_cap: int = 128
+    # (the export table has no option: it is bounded by the bytes it
+    # holds — 8 GiB and never more than the MEM tiers' capacity — and
+    # by an eighth of `ulimit -n` in entries, worker/shm.py. The key
+    # `shm_export_cap`, its old bound in entries, still loads and is
+    # ignored, as any unknown key is)
     # warm-cache shm exports for the tiers BELOW mem (docs/data-plane.md):
     # a read-hot SSD/HDD block's bytes are copied ONCE into a sealed
     # memfd and served over the same SCM_RIGHTS channel as a MEM export —

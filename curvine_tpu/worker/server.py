@@ -137,17 +137,26 @@ class WorkerServer:
                                 admission=wc.cache_admission,
                                 ghost_entries=wc.cache_ghost_entries,
                                 small_ratio=wc.cache_small_ratio)
+        self.metrics = MetricsRegistry("worker")
         # shared-memory read plane (worker/shm.py): sealed-memfd export
         # cache + SCM_RIGHTS side channel for co-located clients. The
         # channel itself starts in start() (port must be final); deleted
         # blocks drop their export so a stale copy is never handed out.
-        from curvine_tpu.worker.shm import (ShmExporter, WarmShmCache,
-                                            shm_supported)
+        from curvine_tpu.worker.shm import (EXPORT_CAP_BYTES, ShmExporter,
+                                            WarmShmCache, shm_supported)
         self.shm = None
         self.shm_warm = None
         self._shm_channel = None
         if wc.shm_reads and shm_supported():
-            self.shm = ShmExporter(cap=wc.shm_export_cap)
+            # bounded in bytes: a block is copied once and kept while
+            # resident, so the bound is what the exports may cost in
+            # host memory — never more than the MEM tiers could hold
+            mem_cap = sum(t.capacity for t in tiers
+                          if t.storage_type == StorageType.MEM
+                          and not isinstance(t, BdevTier))
+            self.shm = ShmExporter(
+                cap_bytes=min(EXPORT_CAP_BYTES, mem_cap),
+                metrics=self.metrics)
             if wc.shm_warm_cap_mb > 0:
                 # warm-cache exports for the tiers below MEM: read-hot
                 # SSD/HDD blocks earn a byte-bounded sealed-memfd copy,
@@ -170,7 +179,6 @@ class WorkerServer:
             tier.health.decay_s = wc.disk_error_decay_s
             tier.health.probe_failures = max(1, wc.disk_probe_failures)
             tier.health.probe_successes = max(1, wc.disk_probe_successes)
-        self.metrics = MetricsRegistry("worker")
         # observability plane: server spans per dispatch + per-code
         # rpc.<name> histograms; the io engine reports submit→complete
         # latency into the same registry
@@ -1264,14 +1272,25 @@ class WorkerServer:
             info = self.store.get(block_id, touch=False)
         except err.CurvineError:
             raise LookupError(f"block {block_id}") from None
-        if self._shm_servable(info):
-            fd, length = self.shm.export(block_id, info.path, info.len)
-            self.metrics.inc("shm.grants")
-            return fd, length
-        if self._shm_warm_servable(info):
-            fd, length = self.shm_warm.export(block_id, info.path,
-                                              info.len)
-            self.metrics.inc("shm.warm_grants")
+        for table, servable, counter in (
+                (self.shm, self._shm_servable, "shm.grants"),
+                (self.shm_warm, self._shm_warm_servable,
+                 "shm.warm_grants")):
+            if not servable(info):
+                continue
+
+            def resident() -> bool:
+                # asked after a new copy entered the table: a delete or
+                # a tier move that ran meanwhile found nothing to drop
+                try:
+                    now = self.store.get(block_id, touch=False)
+                except err.CurvineError:
+                    return False
+                return now.tier is info.tier and servable(now)
+
+            fd, length = table.export(block_id, info.path, info.len,
+                                      resident)
+            self.metrics.inc(counter)
             return fd, length
         raise LookupError(f"block {block_id} not shm-servable")
 

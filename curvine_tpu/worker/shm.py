@@ -2,11 +2,13 @@
 
 The worker-side half of the shm short-circuit read plane
 (docs/data-plane.md). For MEM-tier file-layout blocks the worker keeps
-a bounded cache of sealed memfd copies; a co-located client that saw
-the ``shm``/``shm_sock`` capability flags on its GET_BLOCK_INFO probe
-connects to the unix side channel, sends the block id, and receives the
-fd in SCM_RIGHTS ancillary data — after which every read of the block
-is an mmap slice with zero RPCs and zero copies.
+a table of sealed memfd copies, bounded in bytes: a block is copied on
+its first grant and stays exported while it is resident and the table
+has room. A co-located client that saw the ``shm``/``shm_sock``
+capability flags on its GET_BLOCK_INFO probe connects to the unix side
+channel, sends the block id, and receives the fd in SCM_RIGHTS
+ancillary data — after which every read of the block is an mmap slice
+with zero RPCs and zero copies.
 
 Shape: HDFS short-circuit local reads (DfsClientShm / the
 DomainSocket fd-passing plane), adapted to sealed memfds so the handed
@@ -30,6 +32,7 @@ import socket
 import struct
 import tempfile
 import threading
+import time
 
 log = logging.getLogger(__name__)
 
@@ -49,6 +52,30 @@ def shm_supported() -> bool:
     return hasattr(os, "memfd_create") and hasattr(socket, "SCM_RIGHTS")
 
 
+# what the MEM table's copies may cost in host memory, beside the tier
+# and never more than the tier's capacity (worker/server.py): the 128
+# blocks of 64 MiB the table held when it was bounded in entries alone
+EXPORT_CAP_BYTES = 8 << 30
+
+# an entry is an open fd, so a table is bounded in entries too: this
+# share of the process's limit on open files, read once. Under the
+# usual soft limit of 1,024 that is the 128 entries the table held when
+# entries were its only bound
+_FD_SHARE = 8
+
+
+def _fd_limit() -> int:
+    """The soft RLIMIT_NOFILE as it stands (it is left alone)."""
+    import resource
+    soft, _hard = resource.getrlimit(resource.RLIMIT_NOFILE)
+    return 1 << 20 if soft == resource.RLIM_INFINITY else soft
+
+
+def entry_bound() -> int:
+    """Entries a table may hold in this process by default."""
+    return max(1, _fd_limit() // _FD_SHARE)
+
+
 def channel_path(port: int) -> str:
     """Side-channel socket path: short (AF_UNIX caps sun_path at ~108
     bytes, so the worker's data dir — often a deep tmp path in tests —
@@ -65,48 +92,168 @@ def _seal(fd: int) -> None:
 
 
 class ShmExporter:
-    """Bounded LRU of sealed-memfd block copies.
+    """Byte-bounded table of sealed-memfd block copies, LRU past the
+    bound.
 
-    ``export`` returns a worker-owned fd for a committed MEM-tier block:
-    a memfd the block file's bytes were sendfile'd into, then sealed.
-    Eviction (LRU past ``cap``) and ``invalidate`` (block deleted) close
-    the worker's fd only — dups already handed to clients stay valid.
-    Thread-safe: called from the side-channel thread and the event
-    loop."""
+    A committed MEM-tier block is copied ONCE — its file's bytes
+    sendfile'd into a memfd, then sealed — and stays exported for as
+    long as it is resident and the table has room; every later grant of
+    it is a ``dup``. ``export`` hands the caller an fd of its own (taken
+    under the lock, so no eviction can close or recycle it on the way
+    to the client); the caller closes it once it is sent. Eviction (LRU
+    past ``cap_bytes``) and ``invalidate`` (block deleted or moved)
+    close the table's fd only — dups already handed to clients stay
+    valid. A block larger than the whole bound is copied, served and
+    not kept. Thread-safe: called from the side channel's threads and
+    the event loop.
 
-    def __init__(self, cap: int = 128):
-        self.cap = max(1, cap)
+    An entry is an open fd, and the process has a limit on those: the
+    table is bounded in entries as well (``cap_entries``; by default
+    ``entry_bound()``, an eighth of ``ulimit -n`` as the table is
+    made), LRU past either bound, so many small blocks are held by
+    their number before they are held by their bytes. The bound is the
+    table's share, not a promise that the rest is free: fds are handed
+    out lowest first, so a grant's own fd numbered n says at least n
+    are open, and one numbered within half the table's bound of the
+    limit finds a process about to run out (a restore that leaks its
+    sockets: ROADMAP Speed 0). The table then closes what it holds and
+    keeps nothing while that lasts — every grant a copy, as before
+    there was a table — because a process out of fds loses its reads,
+    the socket rung's with them.
+
+    With ``metrics`` (the worker's registry) the table publishes what
+    it costs where the tier's occupancy is: counters ``shm.exports``
+    (copies made) and ``shm.export_evictions``, gauges
+    ``shm.export_bytes`` and ``shm.export_entries``, all written under
+    the table's lock."""
+
+    def __init__(self, cap_bytes: int, metrics=None,
+                 cap_entries: int | None = None):
+        self.cap_bytes = max(0, cap_bytes)
+        self.cap_entries = (entry_bound() if cap_entries is None
+                            else max(1, cap_entries))
+        self._fd_mark = _fd_limit() - self.cap_entries // 2
         self._lock = threading.Lock()
-        # block_id -> (memfd, length); dict order is the LRU order
+        # block_id -> (memfd, length); for this class dict order is the
+        # LRU order (a subclass's policy may own the order instead)
         self._fds: dict[int, tuple[int, int]] = {}
+        self.bytes = 0
         self.exports = 0        # memfd copies materialized
-        self.hits = 0           # grants served from the cache
+        self.hits = 0           # grants served from the table
         self.evictions = 0
+        self._metrics = metrics
+        self._publish_locked()
 
-    def export(self, block_id: int, path: str, length: int) -> tuple[int, int]:
-        """(memfd, length) for the block file at ``path``; cached."""
+    def export(self, block_id: int, path: str, length: int,
+               resident=None) -> tuple[int, int]:
+        """(fd, length) for the block file at ``path``; the fd is the
+        caller's to close. ``resident()`` is asked once after a new
+        copy entered the table: a block deleted or moved while it was
+        being copied had nothing to invalidate, so the entry is taken
+        out again and the grant refused (LookupError)."""
         with self._lock:
-            ent = self._fds.pop(block_id, None)
-            if ent is not None:
-                self._fds[block_id] = ent       # refresh LRU position
-                self.hits += 1
-                return ent
+            got = self._hit_locked(block_id)
+        if got is not None:
+            return got
         fd = self._copy_to_memfd(block_id, path, length)
         with self._lock:
-            ent = self._fds.pop(block_id, None)
-            if ent is not None:
+            got = self._hit_locked(block_id)
+            if got is not None:
                 # raced with another grant: keep the first copy
-                self._fds[block_id] = ent
-                self.hits += 1
                 self._close(fd)
-                return ent
-            while len(self._fds) >= self.cap:
-                old_fd, _n = self._fds.pop(next(iter(self._fds)))
-                self._close(old_fd)
-                self.evictions += 1
-            self._fds[block_id] = (fd, length)
+                return got
             self.exports += 1
-            return fd, length
+            if length > self.cap_bytes or self._short_of_fds_locked(fd):
+                # served, not kept: emptying the table for one block
+                # that cannot stay would cost every other block a copy,
+                # and a process short of fds is not given one more
+                self._publish_locked()
+                return fd, length
+            try:
+                mine = os.dup(fd)
+            except OSError:
+                self._close(fd)
+                raise
+            self._evict_locked(length)
+            self._fds[block_id] = (fd, length)
+            self.bytes += length
+            self._admit_locked(block_id, length)
+            self._publish_locked()
+        if resident is not None and not resident():
+            self.invalidate(block_id)
+            self._close(mine)
+            raise LookupError(f"block {block_id} left while exported")
+        return mine, length
+
+    def _hit_locked(self, block_id: int) -> tuple[int, int] | None:
+        ent = self._fds.get(block_id)
+        if ent is None:
+            return None
+        mine = os.dup(ent[0])
+        self.hits += 1
+        self._touch_locked(block_id)
+        if self._short_of_fds_locked(mine):
+            self._publish_locked()
+        return mine, ent[1]
+
+    def _short_of_fds_locked(self, fd: int) -> bool:
+        """``fd`` was just handed out: at the mark or over it, give the
+        table's own back (class docstring)."""
+        if fd < self._fd_mark:
+            return False
+        for block_id in list(self._fds):
+            self._remove_locked(block_id, evicted=True)
+            self.evictions += 1
+        return True
+
+    # the policy: what a hit, an admission and a removal mean to the
+    # eviction order, and the order itself
+    def _touch_locked(self, block_id: int) -> None:
+        self._fds[block_id] = self._fds.pop(block_id)
+
+    def _admit_locked(self, block_id: int, length: int) -> None:
+        pass
+
+    def _dropped_locked(self, block_id: int, evicted: bool) -> None:
+        pass
+
+    def _victims_locked(self):
+        """Victims in eviction order; each is removed before the next
+        is asked for."""
+        while self._fds:
+            yield next(iter(self._fds))
+
+    def _evict_locked(self, need: int) -> None:
+        """Make room for one more entry of ``need`` bytes, closing
+        victims in policy order."""
+        def room() -> bool:
+            return (self.bytes + need <= self.cap_bytes
+                    and len(self._fds) < self.cap_entries)
+        if room():
+            return
+        for victim in self._victims_locked():
+            if room():
+                break
+            if self._remove_locked(victim, evicted=True):
+                self.evictions += 1
+
+    def _remove_locked(self, block_id: int, evicted: bool) -> bool:
+        ent = self._fds.pop(block_id, None)
+        if ent is None:
+            return False
+        self._close(ent[0])
+        self.bytes -= ent[1]
+        self._dropped_locked(block_id, evicted)
+        return True
+
+    def _publish_locked(self) -> None:
+        m = self._metrics
+        if m is None:
+            return
+        m.counters["shm.exports"] = self.exports
+        m.counters["shm.export_evictions"] = self.evictions
+        m.gauge("shm.export_bytes", self.bytes)
+        m.gauge("shm.export_entries", len(self._fds))
 
     @staticmethod
     def _copy_to_memfd(block_id: int, path: str, length: int) -> int:
@@ -141,125 +288,11 @@ class ShmExporter:
             pass
 
     def invalidate(self, block_id: int) -> None:
+        """Block deleted or moved tiers: drop its copy (a plain
+        removal, not an eviction — the block is gone)."""
         with self._lock:
-            ent = self._fds.pop(block_id, None)
-        if ent is not None:
-            self._close(ent[0])
-
-    def __len__(self) -> int:
-        with self._lock:
-            return len(self._fds)
-
-    def close(self) -> None:
-        with self._lock:
-            fds, self._fds = list(self._fds.values()), {}
-        for fd, _n in fds:
-            self._close(fd)
-
-
-class WarmShmCache:
-    """Byte-bounded warm cache of sealed-memfd copies for blocks BELOW
-    the MEM tier (docs/data-plane.md).
-
-    A read-hot SSD/HDD block (heat over ``worker.shm_warm_min_reads``,
-    accumulated through the SC_READ_REPORT rail) gets its bytes copied
-    once into a sealed memfd; from then on co-located clients serve it
-    exactly like a MEM export — zero RPCs, zero syscalls per read. The
-    cache is bounded in BYTES (``worker.shm_warm_cap_mb``) because warm
-    copies are anonymous memory the MEM tier doesn't account for, and
-    eviction runs through the same admission policy family as the MEM
-    tier (S3-FIFO by default): a one-touch scan that sneaks a copy in
-    leaves through the probationary queue without displacing the warm
-    working set. Eviction and invalidation close the WORKER's fd only —
-    client-held dups and mappings stay valid (unlink semantics), same
-    contract as ShmExporter."""
-
-    def __init__(self, cap_bytes: int, admission: str = "s3fifo",
-                 ghost_entries: int = 1024):
-        from curvine_tpu.common.cache import make_policy
-        self.cap_bytes = max(0, cap_bytes)
-        self.policy = make_policy(admission, ghost_entries=ghost_entries)
-        self._lock = threading.Lock()
-        # block_id -> (memfd, length); insertion order only (the policy
-        # owns the eviction order, not this dict)
-        self._fds: dict[int, tuple[int, int]] = {}
-        self._atime: dict[int, float] = {}
-        self.bytes = 0
-        self.exports = 0        # warm copies materialized
-        self.hits = 0           # grants served from the cache
-        self.evictions = 0
-
-    def export(self, block_id: int, path: str, length: int) -> tuple[int, int]:
-        """(memfd, length) for the block file at ``path``; copies once,
-        then serves from the cache. Raises LookupError for blocks larger
-        than the whole cache (never worth evicting everything for)."""
-        import time as _time
-        with self._lock:
-            ent = self._fds.get(block_id)
-            if ent is not None:
-                self.hits += 1
-                self._atime[block_id] = _time.time()
-                self.policy.hits += 1
-                self.policy.on_access(block_id)
-                return ent
-        if length > self.cap_bytes:
-            raise LookupError(
-                f"block {block_id} ({length}B) exceeds warm cache")
-        fd = ShmExporter._copy_to_memfd(block_id, path, length)
-        with self._lock:
-            ent = self._fds.get(block_id)
-            if ent is not None:
-                # raced with another grant: keep the first copy
-                self.hits += 1
-                self._close(fd)
-                return ent
-            self._evict_locked(length)
-            self._fds[block_id] = (fd, length)
-            self._atime[block_id] = _time.time()
-            self.bytes += length
-            self.policy.on_admit(block_id, length)
-            self.exports += 1
-            return fd, length
-
-    def _evict_locked(self, need: int) -> None:
-        """Make room for ``need`` bytes, closing victims in policy
-        order (S3-FIFO: probationary one-touch copies first)."""
-        if self.bytes + need <= self.cap_bytes:
-            return
-        order = iter(self.policy.victim_order(
-            [(k, self._atime.get(k, 0.0)) for k in self._fds]))
-        while self.bytes + need > self.cap_bytes and self._fds:
-            victim = next(order, None)
-            if victim is None or victim not in self._fds:
-                if victim is None:          # policy ran dry: FIFO rest
-                    victim = next(iter(self._fds))
-                else:
-                    continue
-            fd, n = self._fds.pop(victim)
-            self._atime.pop(victim, None)
-            self._close(fd)
-            self.bytes -= n
-            self.policy.on_remove(victim, evicted=True)
-            self.evictions += 1
-
-    def invalidate(self, block_id: int) -> None:
-        """Block deleted or moved tiers: drop the warm copy (a plain
-        removal, not an eviction — no ghost entry, the block is gone)."""
-        with self._lock:
-            ent = self._fds.pop(block_id, None)
-            if ent is not None:
-                self._atime.pop(block_id, None)
-                self.bytes -= ent[1]
-                self.policy.on_remove(block_id, evicted=False)
-        if ent is not None:
-            self._close(ent[0])
-
-    @staticmethod
-    def _close(fd: int) -> None:
-        try:
-            os.close(fd)
-        except OSError:
-            pass
+            if self._remove_locked(block_id, evicted=False):
+                self._publish_locked()
 
     def __contains__(self, block_id: int) -> bool:
         with self._lock:
@@ -271,19 +304,71 @@ class WarmShmCache:
 
     def stats(self) -> dict:
         with self._lock:
-            out = {"entries": len(self._fds), "bytes": self.bytes,
-                   "exports": self.exports, "hits": self.hits,
-                   "evictions": self.evictions}
-        out.update({f"policy_{k}": v for k, v in self.policy.stats().items()})
-        return out
+            return {"entries": len(self._fds), "bytes": self.bytes,
+                    "exports": self.exports, "hits": self.hits,
+                    "evictions": self.evictions}
 
     def close(self) -> None:
         with self._lock:
-            fds, self._fds = list(self._fds.values()), {}
-            self._atime.clear()
-            self.bytes = 0
-        for fd, _n in fds:
-            self._close(fd)
+            for block_id in list(self._fds):
+                self._remove_locked(block_id, evicted=False)
+            self._publish_locked()
+
+
+class WarmShmCache(ShmExporter):
+    """The same table for blocks BELOW the MEM tier, under another
+    policy (docs/data-plane.md).
+
+    A read-hot SSD/HDD block (heat over ``worker.shm_warm_min_reads``,
+    accumulated through the SC_READ_REPORT rail) gets its bytes copied
+    once into a sealed memfd; from then on co-located clients serve it
+    exactly like a MEM export — zero RPCs, zero syscalls per read. The
+    bound (``worker.shm_warm_cap_mb``) is small because warm copies are
+    anonymous memory no tier accounts for, and eviction runs through
+    the same admission policy family as the MEM tier (S3-FIFO by
+    default): a one-touch scan that sneaks a copy in leaves through the
+    probationary queue without displacing the warm working set. A block
+    larger than the whole cache is refused, not served from a copy made
+    each time."""
+
+    def __init__(self, cap_bytes: int, admission: str = "s3fifo",
+                 ghost_entries: int = 1024):
+        from curvine_tpu.common.cache import make_policy
+        super().__init__(cap_bytes)
+        self.policy = make_policy(admission, ghost_entries=ghost_entries)
+        self._atime: dict[int, float] = {}
+
+    def export(self, block_id: int, path: str, length: int,
+               resident=None) -> tuple[int, int]:
+        if length > self.cap_bytes:
+            raise LookupError(
+                f"block {block_id} ({length}B) exceeds warm cache")
+        return super().export(block_id, path, length, resident)
+
+    def _touch_locked(self, block_id: int) -> None:
+        self._atime[block_id] = time.time()
+        self.policy.hits += 1
+        self.policy.on_access(block_id)
+
+    def _admit_locked(self, block_id: int, length: int) -> None:
+        self._atime[block_id] = time.time()
+        self.policy.on_admit(block_id, length)
+
+    def _dropped_locked(self, block_id: int, evicted: bool) -> None:
+        self._atime.pop(block_id, None)
+        self.policy.on_remove(block_id, evicted=evicted)
+
+    def _victims_locked(self):
+        """S3-FIFO: probationary one-touch copies first; insertion
+        order for whatever the policy does not name."""
+        yield from self.policy.victim_order(
+            [(k, self._atime.get(k, 0.0)) for k in self._fds])
+        yield from super()._victims_locked()
+
+    def stats(self) -> dict:
+        out = super().stats()
+        out.update({f"policy_{k}": v for k, v in self.policy.stats().items()})
+        return out
 
 
 class ShmChannel:
@@ -293,7 +378,8 @@ class ShmChannel:
     (resolve the block, check the tier, export through the
     ShmExporter); it runs on the channel's threads, so it must only
     touch thread-safe state (BlockStore and ShmExporter both take their
-    own locks)."""
+    own locks). The fd it returns is the channel's: closed once the
+    reply has carried it (SCM_RIGHTS installs the receiver's own)."""
 
     def __init__(self, path: str, grant):
         self.path = path
@@ -351,7 +437,11 @@ class ShmChannel:
                     log.debug("shm grant for %d failed: %s", block_id, e)
                     self._reply(conn, ERROR, 0, None)
                     continue
-                if not self._reply(conn, OK, length, fd):
+                try:
+                    sent = self._reply(conn, OK, length, fd)
+                finally:
+                    os.close(fd)
+                if not sent:
                     return
 
     @staticmethod

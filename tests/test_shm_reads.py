@@ -42,6 +42,21 @@ def _fd_target(fd: str) -> str:
         return ""
 
 
+def _export_fds() -> int:
+    """Open fds of this process that are block exports (the tables' own
+    and every dup of one)."""
+    return sum("memfd:cv-blk-" in _fd_target(fd)
+               for fd in os.listdir("/proc/self/fd"))
+
+
+def _grant(table, block_id: int, path: str, length: int) -> None:
+    """One grant as the channel makes it: the fd is the caller's, and
+    goes once it is sent."""
+    fd, n = table.export(block_id, path, length)
+    os.close(fd)
+    assert n == length
+
+
 # ---------------- the hit path: zero-RPC data plane ----------------
 
 async def test_shm_read_skips_rpc_data_plane(tmp_path):
@@ -134,19 +149,21 @@ async def test_shm_fd_lru_churn_no_leak(tmp_path):
     closes its memfd."""
     conf = ClusterConf()
     conf.data_dir = str(tmp_path)
-    conf.worker.shm_export_cap = 4
+    blk = 256 * 1024
     async with MiniCluster(workers=1, conf=conf, base_dir=str(tmp_path),
-                           block_size=64 * 1024) as mc:
+                           block_size=blk) as mc:
+        table = mc.workers[0].shm
+        table.cap_bytes = MB             # four blocks of 256 KiB
         c = mc.client()
         n_blocks = 16
-        payload = os.urandom(n_blocks * 64 * 1024)
+        payload = os.urandom(n_blocks * blk)
         await c.write_all("/shm/churn.bin", payload)
         r = await c.open("/shm/churn.bin")
         r._SC_CACHE_CAP = 4          # shadow the class FIFO bound
 
         async def churn(rounds: int) -> None:
             for i in range(rounds):
-                off = (i % n_blocks) * 64 * 1024
+                off = (i % n_blocks) * blk
                 # two at once: each first read of a block is a race of
                 # two fetches, and the loser's mapping must go too
                 for got in await asyncio.gather(r.pread_view(off, 4096),
@@ -161,8 +178,11 @@ async def test_shm_fd_lru_churn_no_leak(tmp_path):
         assert _fd_count() <= base + 2, \
             "fd table grew under shm block churn (leaked memfd/mmap)"
         assert len(r._shm_maps) <= r._SC_CACHE_CAP
-        assert len(mc.workers[0].shm) <= 4
-        assert mc.workers[0].shm.evictions > 0
+        assert len(table) <= 4 and table.bytes <= MB
+        assert table.evictions > 0
+        gauges = mc.workers[0].metrics.gauges
+        assert gauges["shm.export_bytes"] == table.bytes
+        assert gauges["shm.export_entries"] == len(table)
         await r.close()
         assert not r._shm_maps
         await c.close()
@@ -570,9 +590,12 @@ async def test_span_view_falls_back_whole(tmp_path, monkeypatch, case):
             assert c.counters.get("read.shm_fallbacks", 0) == 1
             assert victim not in r._shm_sock
             # every granted fd is closed: what is left is the worker's
-            assert len([fd for fd in os.listdir("/proc/self/fd")
-                        if "cv-blk-" in _fd_target(fd)]) \
-                == len(mc.workers[0].shm)
+            # (the channel's thread closes its own once it is sent)
+            for _ in range(100):
+                if _export_fds() == len(mc.workers[0].shm):
+                    break
+                await asyncio.sleep(0.02)
+            assert _export_fds() == len(mc.workers[0].shm)
         monkeypatch.setattr(wshm, "fetch_block_fd", real_fetch)
         assert await r.read_all() == payload
         await r.close()
@@ -685,25 +708,387 @@ async def test_shm_exporter_seals_and_lru(tmp_path):
         p = tmp_path / f"b{i}"
         p.write_bytes(bytes([i]) * 4096)
         blocks[i] = str(p)
-    ex = wshm.ShmExporter(cap=2)
+    ex = wshm.ShmExporter(cap_bytes=2 * 4096)
+    base = _export_fds()
+    fd0 = fd0b = -1
     try:
         fd0, n0 = ex.export(0, blocks[0], 4096)
         assert n0 == 4096
         seals = fcntl.fcntl(fd0, fcntl.F_GET_SEALS)
-        assert seals & fcntl.F_SEAL_WRITE and seals & fcntl.F_SEAL_SEAL
+        assert seals == (fcntl.F_SEAL_SHRINK | fcntl.F_SEAL_GROW
+                         | fcntl.F_SEAL_WRITE | fcntl.F_SEAL_SEAL)
         assert os.pread(fd0, 4096, 0) == b"\x00" * 4096
         with pytest.raises(OSError):
             os.pwrite(fd0, b"x", 0)          # sealed: immutable
         fd0b, _ = ex.export(0, blocks[0], 4096)
-        assert fd0b == fd0 and ex.hits == 1  # cache hit, same fd
-        ex.export(1, blocks[1], 4096)
-        ex.export(2, blocks[2], 4096)        # evicts block 0 (LRU)
-        assert len(ex) == 2 and ex.evictions == 1
-        with pytest.raises(OSError):
-            os.fstat(fd0)                    # eviction closed it
+        # a hit: the caller's own fd onto the same memfd, no new copy
+        assert fd0b != fd0 and ex.hits == 1 and ex.exports == 1
+        assert os.fstat(fd0b).st_ino == os.fstat(fd0).st_ino
+        _grant(ex, 1, blocks[1], 4096)
+        _grant(ex, 2, blocks[2], 4096)       # evicts block 0 (LRU)
+        assert len(ex) == 2 and ex.evictions == 1 and 0 not in ex
+        assert ex.bytes == 2 * 4096
+        # eviction closed the table's fd only: ours still read block 0
+        assert _export_fds() == base + 2 + 2
+        assert os.pread(fd0, 4096, 0) == b"\x00" * 4096
     finally:
         ex.close()
-    assert len(ex) == 0
+        for fd in (fd0, fd0b):
+            if fd >= 0:
+                os.close(fd)
+    assert len(ex) == 0 and ex.bytes == 0 and _export_fds() == base
+
+
+def _small_blocks(tmp_path, n: int, size: int) -> dict[int, str]:
+    out = {}
+    for i in range(n):
+        p = tmp_path / f"s{i}"
+        p.write_bytes(i.to_bytes(2, "little") * (size // 2))
+        out[i] = str(p)
+    return out
+
+
+def test_export_table_copies_a_resident_block_once(tmp_path):
+    """300 small blocks under a bound that holds them all — far more
+    entries than the 128 the table once stopped at: 300 copies on the
+    first pass, none on the second, and a second grant is an fd onto
+    the very memfd the first one got."""
+    from curvine_tpu.common.metrics import MetricsRegistry
+    size = 4096
+    blocks = _small_blocks(tmp_path, 300, size)
+    m = MetricsRegistry("worker")
+    ex = wshm.ShmExporter(cap_bytes=300 * size, metrics=m,
+                          cap_entries=300)
+    assert m.counters["shm.exports"] == 0    # published from the start
+    base = _export_fds()
+    try:
+        first = {i: ex.export(i, blocks[i], size)[0] for i in blocks}
+        assert ex.exports == 300 and ex.hits == 0 and ex.evictions == 0
+        assert len(ex) == 300 and ex.bytes == 300 * size
+        for i in blocks:
+            fd, n = ex.export(i, blocks[i], size)
+            try:
+                assert n == size
+                assert os.fstat(fd).st_ino == os.fstat(first[i]).st_ino
+                assert os.pread(fd, size, 0) == \
+                    i.to_bytes(2, "little") * (size // 2)
+            finally:
+                os.close(fd)
+        assert ex.exports == 300 and ex.hits == 300 and ex.evictions == 0
+        assert m.counters["shm.exports"] == 300
+        assert m.counters["shm.export_evictions"] == 0
+        assert m.gauges["shm.export_bytes"] == 300 * size
+        assert m.gauges["shm.export_entries"] == 300
+        for fd in first.values():
+            os.close(fd)
+        assert _export_fds() == base + 300   # one fd a block, no more
+    finally:
+        ex.close()
+    assert _export_fds() == base
+    assert m.gauges["shm.export_bytes"] == 0
+    assert m.gauges["shm.export_entries"] == 0
+
+
+def test_export_table_is_lru_within_its_byte_bound(tmp_path):
+    """A bound smaller than the set: the least recently granted block
+    leaves first, blocks of different sizes are counted by their bytes,
+    and the bytes held never pass the bound."""
+    from curvine_tpu.common.metrics import MetricsRegistry
+    sizes = {0: 4096, 1: 8192, 2: 4096, 3: 12288, 4: 4096}
+    paths = {}
+    for i, n in sizes.items():
+        p = tmp_path / f"l{i}"
+        p.write_bytes(bytes([i]) * n)
+        paths[i] = str(p)
+    m = MetricsRegistry("worker")
+    ex = wshm.ShmExporter(cap_bytes=16384, metrics=m)
+    seen = []
+
+    def grant(i):
+        _grant(ex, i, paths[i], sizes[i])
+        assert ex.bytes <= ex.cap_bytes
+        assert m.gauges["shm.export_bytes"] == ex.bytes
+        seen.append(ex.bytes)
+
+    try:
+        grant(0), grant(1), grant(2)             # 16 KiB: full
+        assert ex.evictions == 0 and ex.bytes == 16384
+        grant(0)                                 # a hit: 0 is the newest
+        grant(4)                                 # 4 KiB more: 1 goes (8 KiB)
+        assert 1 not in ex and 0 in ex and 2 in ex and 4 in ex
+        assert ex.evictions == 1 and ex.bytes == 12288
+        grant(3)                                 # 12 KiB: 2, then 0 go
+        assert [i in ex for i in range(5)] == [False, False, False,
+                                               True, True]
+        assert ex.evictions == 3 and ex.bytes == 16384
+        assert m.counters["shm.export_evictions"] == 3
+        assert max(seen) <= 16384
+    finally:
+        ex.close()
+
+
+def test_export_table_is_lru_within_its_entry_bound(tmp_path):
+    """An entry is an open fd, so the table is bounded in entries as
+    well as in bytes: with room in bytes for every block, the least
+    recently granted one still leaves when the entries are full, and
+    the table never holds more fds than its bound."""
+    from curvine_tpu.common.metrics import MetricsRegistry
+    blocks = _small_blocks(tmp_path, 6, 4096)
+    m = MetricsRegistry("worker")
+    ex = wshm.ShmExporter(cap_bytes=MB, metrics=m, cap_entries=4)
+    base = _export_fds()
+    try:
+        for i in range(4):
+            _grant(ex, i, blocks[i], 4096)
+        assert len(ex) == 4 and ex.evictions == 0
+        _grant(ex, 0, blocks[0], 4096)           # a hit: 0 is the newest
+        _grant(ex, 4, blocks[4], 4096)           # a fifth: 1 goes
+        _grant(ex, 5, blocks[5], 4096)           # a sixth: 2 goes
+        assert [i in ex for i in range(6)] == [True, False, False,
+                                               True, True, True]
+        assert ex.evictions == 2 == m.counters["shm.export_evictions"]
+        assert ex.bytes == 4 * 4096 == m.gauges["shm.export_bytes"]
+        assert m.gauges["shm.export_entries"] == 4
+        assert _export_fds() == base + 4
+        _grant(ex, 1, blocks[1], 4096)           # copied again, served
+        assert ex.exports == 7 and len(ex) == 4 and 3 not in ex
+    finally:
+        ex.close()
+    assert _export_fds() == base
+
+
+def test_export_table_keeps_nothing_in_a_process_short_of_fds(tmp_path):
+    """A grant whose own fd is numbered within half the table's entry
+    bound of the process's limit finds it about to run out: the table
+    closes what it holds, serves that grant and every later one from a
+    copy it does not keep, and is the table it was once fds are
+    handed out below the mark again."""
+    from curvine_tpu.common.metrics import MetricsRegistry
+    blocks = _small_blocks(tmp_path, 10, 4096)
+    m = MetricsRegistry("worker")
+    ex = wshm.ShmExporter(cap_bytes=MB, metrics=m, cap_entries=16)
+    assert ex._fd_mark == wshm._fd_limit() - 8
+    base = _export_fds()
+    try:
+        for i in range(8):
+            _grant(ex, i, blocks[i], 4096)
+        assert len(ex) == 8 and ex.evictions == 0
+        high, ex._fd_mark = ex._fd_mark, 0       # every fd is "too high"
+        for i in (7, 8):                         # a hit, then a miss
+            fd, n = ex.export(i, blocks[i], 4096)
+            try:
+                assert os.pread(fd, n, 0) == i.to_bytes(2, "little") * 2048
+            finally:
+                os.close(fd)
+            assert len(ex) == 0 and _export_fds() == base
+        assert ex.evictions == 8 == m.counters["shm.export_evictions"]
+        assert m.gauges["shm.export_entries"] == 0
+        assert m.gauges["shm.export_bytes"] == 0
+        ex._fd_mark = high                       # the burst is over
+        _grant(ex, 8, blocks[8], 4096)
+        _grant(ex, 8, blocks[8], 4096)
+        assert 8 in ex and len(ex) == 1 and ex.hits == 2
+        assert ex.exports == 8 + 2
+    finally:
+        ex.close()
+
+
+def test_entry_bound_is_an_eighth_of_the_fd_limit_when_made():
+    """The default entry bound is read once, as the table is made, from
+    the process's soft limit on open files, which it leaves as it was:
+    under the usual 1,024 a table holds the 128 entries it always
+    did."""
+    import resource
+    soft, hard = resource.getrlimit(resource.RLIMIT_NOFILE)
+    low = 1024 if soft == resource.RLIM_INFINITY else min(soft, 1024)
+    try:
+        resource.setrlimit(resource.RLIMIT_NOFILE, (low, hard))
+        ex = wshm.ShmExporter(cap_bytes=MB)
+        warm = wshm.WarmShmCache(cap_bytes=MB)
+        assert resource.getrlimit(resource.RLIMIT_NOFILE) == (low, hard)
+    finally:
+        resource.setrlimit(resource.RLIMIT_NOFILE, (soft, hard))
+    assert ex.cap_entries == warm.cap_entries == low // 8
+    assert wshm.ShmExporter(cap_bytes=MB, cap_entries=0).cap_entries == 1
+
+
+def test_export_larger_than_the_bound_is_served_not_kept(tmp_path):
+    """A block larger than the whole bound is still granted — copied,
+    sealed, handed over — but not kept, and the table is not emptied to
+    make room it could never make."""
+    blocks = _small_blocks(tmp_path, 3, 4096)
+    big = tmp_path / "big"
+    big.write_bytes(b"\x07" * 32768)
+    ex = wshm.ShmExporter(cap_bytes=3 * 4096)
+    base = _export_fds()
+    try:
+        for i in blocks:
+            _grant(ex, i, blocks[i], 4096)
+        for _ in range(2):
+            fd, n = ex.export(9, str(big), 32768)
+            try:
+                assert n == 32768
+                assert os.pread(fd, n, 0) == b"\x07" * n
+                assert fcntl.fcntl(fd, fcntl.F_GET_SEALS) \
+                    & fcntl.F_SEAL_WRITE
+            finally:
+                os.close(fd)
+        assert 9 not in ex and len(ex) == 3 and ex.bytes == 3 * 4096
+        assert ex.evictions == 0
+        assert ex.exports == 5                   # each grant of it a copy
+        assert _export_fds() == base + 3         # and its copies are gone
+    finally:
+        ex.close()
+
+
+def test_export_mapping_outlives_eviction_and_invalidate(tmp_path):
+    """What a client mapped stays readable after the table evicted the
+    block, and after the block was invalidated: the table closes its
+    own fd, never the pages a client holds."""
+    blocks = _small_blocks(tmp_path, 3, 4096)
+    ex = wshm.ShmExporter(cap_bytes=2 * 4096)
+    try:
+        maps = []
+        for i in (0, 1):
+            fd, n = ex.export(i, blocks[i], 4096)
+            maps.append(mmap.mmap(fd, n, prot=mmap.PROT_READ))
+            os.close(fd)                         # the client keeps the map
+        _grant(ex, 2, blocks[2], 4096)           # evicts 0
+        ex.invalidate(1)
+        assert 0 not in ex and 1 not in ex and ex.bytes == 4096
+        assert ex.evictions == 1                 # an invalidate is not one
+        for i, mm in enumerate(maps):
+            assert mm[:] == i.to_bytes(2, "little") * 2048
+            mm.close()
+    finally:
+        ex.close()
+
+
+@pytest.mark.parametrize("table", ["mem", "warm"])
+def test_export_of_a_block_that_left_while_copied_is_dropped(tmp_path,
+                                                              table):
+    """A delete that runs while the first grant is still copying finds
+    nothing to invalidate; the table asks once more after the copy went
+    in, takes it out again and refuses the grant."""
+    blocks = _small_blocks(tmp_path, 2, 4096)
+    ex = (wshm.ShmExporter(cap_bytes=8192) if table == "mem"
+          else wshm.WarmShmCache(cap_bytes=8192))
+    base = _export_fds()
+    try:
+        with pytest.raises(LookupError):
+            ex.export(0, blocks[0], 4096, lambda: False)
+        assert 0 not in ex and ex.bytes == 0 and _export_fds() == base
+        asked = []
+        fd, _ = ex.export(1, blocks[1], 4096,
+                          lambda: asked.append(1) or True)
+        os.close(fd)
+        fd, _ = ex.export(1, blocks[1], 4096,
+                          lambda: asked.append(1) or True)
+        os.close(fd)
+        assert asked == [1]                      # not asked on a hit
+        assert 1 in ex and ex.bytes == 4096
+    finally:
+        ex.close()
+
+
+def test_export_table_concurrent_grants_stay_exact(tmp_path):
+    """Eight threads granting 40 blocks through a table that holds ten:
+    the counts add up, the gauge never passes the bound, every fd read
+    is the block asked for, and no fd is left."""
+    from curvine_tpu.common.metrics import MetricsRegistry
+    size = 4096
+    blocks = _small_blocks(tmp_path, 40, size)
+    m = MetricsRegistry("worker")
+    ex = wshm.ShmExporter(cap_bytes=10 * size, metrics=m)
+    base = _export_fds()
+    over, wrong = [], []
+
+    def worker(k: int) -> None:
+        for j in range(200):
+            i = (j * 7 + k * 13) % 40
+            fd, n = ex.export(i, blocks[i], size)
+            if os.pread(fd, 2, 0) != i.to_bytes(2, "little"):
+                wrong.append(i)
+            os.close(fd)
+            if m.gauges["shm.export_bytes"] > ex.cap_bytes:
+                over.append(m.gauges["shm.export_bytes"])
+
+    try:
+        threads = [threading.Thread(target=worker, args=(k,))
+                   for k in range(8)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join()
+        assert not wrong and not over
+        st = ex.stats()
+        # a raced copy that lost is a hit of the first one's memfd
+        assert st["hits"] + st["exports"] == 8 * 200
+        assert st["exports"] - st["evictions"] == st["entries"] <= 10
+        assert m.counters["shm.exports"] == st["exports"]
+        assert _export_fds() == base + st["entries"]
+    finally:
+        ex.close()
+    assert _export_fds() == base
+
+
+@pytest.mark.parametrize("leaves_by", ["delete", "tier_move"])
+async def test_export_leaves_with_its_block(tmp_path, leaves_by):
+    """An entry leaves the table when its block does — deleted, or
+    moved to another tier: the gauge falls by its bytes and the next
+    grant over the side channel is NOT_FOUND, never the old copy."""
+    conf = _ssd_conf(tmp_path, with_mem=True)
+    async with MiniCluster(workers=1, conf=conf, base_dir=str(tmp_path),
+                           block_size=MB) as mc:
+        w = mc.workers[0]
+        c = mc.client()
+        await c.write_all("/shm/a.bin", os.urandom(MB))
+        await c.write_all("/shm/b.bin", os.urandom(MB // 2))
+        bids = []
+        for name in ("a", "b"):
+            r = await c.open(f"/shm/{name}.bin")
+            await r.pread_view(0, 4096)
+            bids.append(r.blocks.block_locs[0].block.id)
+            await r.close()
+        assert w.shm.cap_bytes == 64 * MB        # the MEM tier's, not 8 GiB
+        assert w.shm.cap_entries == wshm.entry_bound()
+        assert bids[0] in w.shm and bids[1] in w.shm
+        assert w.metrics.gauges["shm.export_bytes"] == MB + MB // 2
+        assert w.metrics.counters["shm.exports"] == 2
+        sock = w._shm_channel.path
+        fd, n = await asyncio.to_thread(wshm.fetch_block_fd, sock, bids[0])
+        os.close(fd)
+        assert n == MB and w.metrics.counters["shm.exports"] == 2
+        if leaves_by == "delete":
+            w.store.delete(bids[0])
+        else:
+            ssd = next(t for t in w.store.tiers
+                       if t.storage_type.name == "SSD")
+            assert w.store._move_block(bids[0], ssd)
+        assert bids[0] not in w.shm and bids[1] in w.shm
+        assert w.metrics.gauges["shm.export_bytes"] == MB // 2
+        assert w.metrics.gauges["shm.export_entries"] == 1
+        assert w.metrics.counters["shm.export_evictions"] == 0
+        with pytest.raises(LookupError):
+            await asyncio.to_thread(wshm.fetch_block_fd, sock, bids[0])
+        assert bids[0] not in w.shm
+        await c.close()
+
+
+def test_conf_with_the_old_export_key_loads(tmp_path):
+    """`worker.shm_export_cap` counted entries; a conf file that still
+    carries it loads and the key is ignored: the table has no option
+    now, its bound in bytes is the 8 GiB that 128 blocks of 64 MiB came
+    to (under the MEM tiers' capacity, test_export_leaves_with_its_
+    block)."""
+    path = tmp_path / "old.toml"
+    path.write_text("[worker]\nshm_reads = true\nshm_export_cap = 128\n"
+                    "shm_warm_cap_mb = 32\n")
+    conf = ClusterConf.load(str(path), env={})
+    assert conf.worker.shm_reads and conf.worker.shm_warm_cap_mb == 32
+    assert not hasattr(conf.worker, "shm_export_cap")
+    assert not hasattr(conf.worker, "shm_export_cap_mb")
+    assert wshm.EXPORT_CAP_BYTES == 128 * 64 * MB
 
 
 async def test_shm_channel_fd_handoff(tmp_path):
@@ -716,7 +1101,7 @@ async def test_shm_channel_fd_handoff(tmp_path):
     def grant(block_id: int):
         if block_id != 7:
             raise LookupError(block_id)
-        return fd, len(data)
+        return os.dup(fd), len(data)         # the channel's to close
 
     path = wshm.channel_path(os.getpid() % 60_000)
     ch = wshm.ShmChannel(path, grant)
@@ -727,6 +1112,16 @@ async def test_shm_channel_fd_handoff(tmp_path):
         assert got_fd != fd                  # a dup, not the original
         assert os.pread(got_fd, n, 0) == data
         os.close(got_fd)
+        # the channel closed what the grant gave it, once it was sent
+        base = _fd_count()
+        for _ in range(20):
+            os.close((await asyncio.to_thread(
+                wshm.fetch_block_fd, path, 7))[0])
+        for _ in range(50):
+            if _fd_count() <= base:
+                break
+            await asyncio.sleep(0.02)        # the serving threads' exit
+        assert _fd_count() <= base
         with pytest.raises(LookupError):
             await asyncio.to_thread(wshm.fetch_block_fd, path, 8)
     finally:
@@ -941,25 +1336,24 @@ def test_warm_cache_unit_eviction_and_scan_resistance(tmp_path):
     cache = wshm.WarmShmCache(cap_bytes=4 * blk, admission="s3fifo")
     try:
         # working set: two blocks, each re-touched (freq >= 1)
+        base = _export_fds()
         for h in (0, 1):
-            cache.export(h, paths[h], blk)
-            cache.export(h, paths[h], blk)       # hit -> on_access
+            _grant(cache, h, paths[h], blk)
+            _grant(cache, h, paths[h], blk)      # hit -> on_access
         assert cache.hits == 2 and cache.exports == 2
-        fd_scan, _ = cache.export(2, paths[2], blk)   # one-touch
-        dup = os.dup(fd_scan)                    # a client-held dup
+        dup, _ = cache.export(2, paths[2], blk)  # one-touch; a client's
         try:
             # one-touch scan far past capacity: probationary entries
             # leave, the re-touched working set never gets displaced
             for s in range(3, 12):
-                cache.export(s, paths[s], blk)
+                _grant(cache, s, paths[s], blk)
             assert 0 in cache and 1 in cache
             assert 2 not in cache
             assert cache.evictions > 0
             assert cache.policy.scan_evicted > 0
             assert cache.stats()["bytes"] <= 4 * blk
             # eviction closed the worker's fd, not the client's dup
-            with pytest.raises(OSError):
-                os.fstat(fd_scan)
+            assert _export_fds() == base + len(cache) + 1
             assert os.pread(dup, blk, 0) == bytes([2]) * blk
         finally:
             os.close(dup)

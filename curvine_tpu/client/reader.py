@@ -659,6 +659,7 @@ class FsReader:
             self._shm_hit(lb.block.id)
         self._count("read.zero_copy_bytes", n)
         self._count("read.span_views")
+        self._count("read.span_view_blocks", len(lbs))
         self._count("read.span_view_bytes", n)
         return whole[start:start + n]
 

@@ -424,6 +424,7 @@ async def test_span_view_is_one_zero_copy_view(tmp_path, offset, n):
         covered = sum(min(MB, len(payload) - i * MB)
                       for i in range(offset // MB, offset // MB + k))
         assert grew("read.span_views") == 1
+        assert grew("read.span_view_blocks") == k
         assert grew("read.span_view_bytes") == n
         assert grew("read.zero_copy_bytes") == n
         assert grew("read.shm_hits") == k

@@ -71,6 +71,8 @@ class CurvineClient:
         # the meta client accounts its calls (and the master's own time
         # from each reply) into the same dict: meta.*
         self.meta.counters = self.counters
+        # and so do both pools, to the master and to the workers: rpc.*
+        self.meta.pool.counters = self.pool.counters = self.counters
         # meta lease cache hit/miss/invalidation counters ride the same
         # METRICS_REPORT flush (master shows them as client.meta_cache.*)
         if self.meta.cache is not None:

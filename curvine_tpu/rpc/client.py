@@ -73,15 +73,21 @@ class Connection:
     async def connect(self) -> "Connection":
         host, port = self.addr.rsplit(":", 1)
         self._loop = asyncio.get_running_loop()
+        sock = None
         try:
             sock = socket.socket(socket.AF_INET, socket.SOCK_STREAM)
             sock.setblocking(False)
             sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
             await asyncio.wait_for(
                 self._loop.sock_connect(sock, (host, int(port))), self.timeout)
+            self._sock = sock
         except (OSError, asyncio.TimeoutError) as e:
             raise ConnectError(f"connect {self.addr}: {e}") from e
-        self._sock = sock
+        finally:
+            # a dial refused, timed out or cancelled (the pool closing):
+            # its socket goes now, not when the collector finds it
+            if self._sock is None and sock is not None:
+                sock.close()
         rc = self.rpc_conf
         self._writer = CoalescedWriter(
             sock, self._loop,
@@ -404,17 +410,33 @@ class Connection:
 
 
 class ConnectionPool:
-    """Per-address connection pool with lazy dial and broken-conn eviction."""
+    """Per-address connection pool with lazy dial and broken-conn
+    eviction. A pool of `size` is at most `size` sockets an address,
+    open and being dialled together, at every instant: a caller that
+    arrives while the pool is short shares what the pool has — an open
+    connection at once, else the dials in flight — and never dials
+    beyond `size` (`Connection` multiplexes by req_id, so a burst over
+    four connections is served as concurrently as over a thousand).
+
+    `counters`, where given (CurvineClient hands both of its pools its
+    own), counts `rpc.dials` (dials started) and `rpc.dial_joins` (gets
+    that found the pool short with every missing connection already
+    being dialled, and were served without a dial of their own)."""
 
     def __init__(self, size: int = 4, timeout_ms: int = 30_000,
-                 rpc_conf=None, metrics=None):
+                 rpc_conf=None, metrics=None,
+                 counters: dict | None = None):
         self.size = size
         self.timeout_ms = timeout_ms
         self.rpc_conf = rpc_conf
         self.metrics = metrics
+        self.counters = counters
         self._conns: dict[str, list[Connection]] = {}
+        # dials in flight, each a task of the POOL's own: a caller's
+        # wait_for / cancellation must not cancel a dial that others
+        # wait on, nor leave its connection to no one
+        self._dials: dict[str, list[asyncio.Task]] = {}
         self._rr: dict[str, int] = {}
-        self._lock = asyncio.Lock()
         # client-side fault hook, inherited by every dialed Connection
         # (FaultInjector.install_client); see Connection.fault_hook
         self.fault_hook = None
@@ -424,7 +446,7 @@ class ConnectionPool:
 
     def set_fault_hook(self, hook) -> None:
         """Install/remove the client fault hook on this pool AND every
-        already-dialed connection (new dials inherit it)."""
+        already-dialed connection (a dial takes it as it registers)."""
         self.fault_hook = hook
         for conns in self._conns.values():
             for c in conns:
@@ -432,59 +454,91 @@ class ConnectionPool:
 
     def set_push_handler(self, handler) -> None:
         """Install/remove the server-push receiver on this pool AND
-        every already-dialed connection (new dials inherit it)."""
+        every already-dialed connection (a dial takes it as it
+        registers)."""
         self.push_handler = handler
         for conns in self._conns.values():
             for c in conns:
                 c.on_push = handler
 
+    def _count(self, key: str) -> None:
+        if self.counters is not None:
+            self.counters[key] = self.counters.get(key, 0) + 1
+
     async def get(self, addr: str) -> Connection:
-        async with self._lock:
-            conns = self._conns.setdefault(addr, [])
-            conns[:] = [c for c in conns if not c.closed]
-            if len(conns) >= self.size:
-                i = self._rr[addr] = (self._rr.get(addr, -1) + 1) % len(conns)
-                return conns[i]
-        # dial outside the lock: slow/retrying connects must not stall
-        # other addresses
-        conn = await self._dial(addr)
-        try:
-            async with self._lock:
-                conns = self._conns.setdefault(addr, [])
-                if len(conns) < self.size:
-                    conns.append(conn)
-                return conn
-        except asyncio.CancelledError:
-            # a caller deadline (wait_for) can cancel between dial success
-            # and registration: close the orphan or its read loop holds
-            # the socket open forever
-            await conn.close()
-            raise
+        # no await down to the wait below: the loop runs nothing else
+        # between the look at the pool and the dial it starts
+        conns = self._conns.setdefault(addr, [])
+        conns[:] = [c for c in conns if not c.closed]
+        dials = self._dials.setdefault(addr, [])
+        dials[:] = [t for t in dials if not t.done()]
+        if len(conns) < self.size:
+            if len(conns) + len(dials) < self.size:
+                self._count("rpc.dials")
+                dials.append(asyncio.ensure_future(self._dial(addr)))
+                dials[-1].add_done_callback(_retrieve)
+            else:
+                self._count("rpc.dial_joins")
+        if not conns:
+            # nothing open yet: share the first dial in flight. wait()
+            # leaves it running if this caller is cancelled; if it fails
+            # it fails its waiters as their own dial would have, and the
+            # next get, finding it done, dials anew
+            dial = dials[0]
+            await asyncio.wait([dial])
+            conns = [c for c in self._conns.get(addr, ()) if not c.closed]
+            if not conns:
+                if dial.cancelled():
+                    raise ConnectError(f"connect {addr}: pool closed")
+                failed = dial.exception()
+                if failed is not None:
+                    raise ConnectError(str(failed)) from failed
+                return dial.result()
+        i = self._rr[addr] = (self._rr.get(addr, -1) + 1) % len(conns)
+        return conns[i]
 
     async def _dial(self, addr: str, attempts: int = 3) -> Connection:
+        """One dial in flight: connect, take the pool's hooks as they
+        are NOW (not as they were when the dial began), register."""
         # transient connect failures (sandboxed loopback occasionally
         # returns ENOENT) are retried here so every caller benefits
         last: Exception | None = None
         for i in range(attempts):
             try:
-                conn = Connection(addr, self.timeout_ms,
-                                  rpc_conf=self.rpc_conf,
-                                  metrics=self.metrics)
-                conn.fault_hook = self.fault_hook
-                conn.on_push = self.push_handler
-                return await conn.connect()
+                conn = await Connection(addr, self.timeout_ms,
+                                        rpc_conf=self.rpc_conf,
+                                        metrics=self.metrics).connect()
             except ConnectError as e:
                 last = e
                 await asyncio.sleep(0.05 * (2 ** i))
+            else:
+                conn.fault_hook = self.fault_hook
+                conn.on_push = self.push_handler
+                self._conns.setdefault(addr, []).append(conn)
+                return conn
         assert last is not None
         raise last
 
     async def close(self) -> None:
-        async with self._lock:
-            for conns in self._conns.values():
-                for c in conns:
-                    await c.close()
-            self._conns.clear()
+        # dials first: one that came up all the same has registered its
+        # connection by the time it is done, and is closed with the rest
+        dials = [t for ts in self._dials.values() for t in ts]
+        self._dials.clear()
+        for t in dials:
+            t.cancel()
+        if dials:
+            await asyncio.wait(dials)
+        conns = [c for cs in self._conns.values() for c in cs]
+        self._conns.clear()
+        for c in conns:
+            await c.close()
+
+
+def _retrieve(task: asyncio.Task) -> None:
+    """A dial nobody waited on (its starter was served by an open
+    connection) must not die as 'exception was never retrieved'."""
+    if not task.cancelled():
+        task.exception()
 
 
 class RetryPolicy:

@@ -2,10 +2,13 @@
 
 The reference's "LLM model distribution acceleration" use case
 (README.md Case 3): pull checkpoint bytes once from the cache (warmed from
-S3 by a load job), materialize tensors host-side, and fan them out to all
-devices — replicated params ride the ICI mesh via device_put with a
-replicated NamedSharding, sharded params land directly in their TP layout
-(no full-size copy per chip).
+S3 by a load job) and fan them out to all devices. Replicated params are
+dispatched to the mesh tensor by tensor as their views land (device_put
+with a replicated NamedSharding, no host copy). Params restored under a
+layout (``spec_tree``) are first loaded whole into host memory — one
+full owning copy of the checkpoint — and then placed leaf by leaf, each
+chip receiving its own shard only; the layout is checked against the
+manifest before the first tensor is opened.
 
 Checkpoint format: a msgpack manifest ``<name>.json`` + raw tensor files,
 or a single .npz — both cache-native (written/read through CurvineClient).
@@ -15,6 +18,7 @@ from __future__ import annotations
 
 import json
 import logging
+import math
 import time
 from contextlib import contextmanager
 
@@ -142,12 +146,14 @@ async def load_checkpoint(client: CurvineClient, path: str,
     with _restore(client, path):
         manifest, skel, treedef = await _load_manifest(client, path,
                                                        allow_pickle)
-        # (no placer: own the memory past the reader's close)
         flat = await asyncio.gather(*(
-            _load_tensor(client, path, t, placer or np.array)
-            for t in manifest))
+            _load_tensor(client, path, t, placer) for t in manifest))
         if placer is not None:
             flat = _wait_ready(client, flat)
+    return _unflatten(skel, treedef, flat)
+
+
+def _unflatten(skel, treedef, flat):
     if skel is not None:
         return _tree_build(skel, flat)
     return jax.tree.unflatten(treedef, flat)
@@ -177,8 +183,9 @@ async def _load_tensor(client: CurvineClient, path: str, t: dict, place,
     by side) where the shm rung serves all of it, else as a
     copy through ``read_all``; with ``peer_hbm`` from a peer's HBM tier
     first. ``place(arr)`` is timed as ckpt.place (an async dispatch: the
-    device copies while the next tensor is read); the reader closes
-    after it."""
+    device copies while the next tensor is read); with no ``place`` the
+    view is copied into host memory that outlives the reader, timed as
+    ckpt.host_copy (with its bytes). The reader closes after either."""
     name = f"{path}/{t['name']}"
     with client.tracer.span("ckpt.tensor", attrs={"name": t["name"]},
                             detail=True) as sp:
@@ -196,12 +203,26 @@ async def _load_tensor(client: CurvineClient, path: str, t: dict, place,
             sp.set_attr("served_by", reader.served_by())
         sp.set_attr("bytes", arr.nbytes)
         arr = arr.view(np.dtype(t["dtype"])).reshape(t["shape"])
-        with Timed(client.counters, "ckpt.place",
-                   client.tracer.span("ckpt.place", detail=True)):
-            out = place(arr)
+        if place is None:
+            out = _host_copy(client, arr)
+        else:
+            with Timed(client.counters, "ckpt.place",
+                       client.tracer.span("ckpt.place", detail=True)):
+                out = place(arr)
         if reader is not None:
             await reader.close()
         return out
+
+
+def _host_copy(client: CurvineClient, arr: np.ndarray) -> np.ndarray:
+    """Own the tensor's bytes past its reader's close: one copy of the
+    view, on the caller's thread."""
+    c = client.counters
+    with Timed(c, "ckpt.host_copy",
+               client.tracer.span("ckpt.host_copy", detail=True)):
+        out = np.array(arr)
+    c["ckpt.host_copy.bytes"] = c.get("ckpt.host_copy.bytes", 0) + out.nbytes
+    return out
 
 
 def _wait_ready(client: CurvineClient, flat: list) -> list:
@@ -221,14 +242,95 @@ async def _read_all(client: CurvineClient, path: str) -> bytes:
 
 def broadcast_params(params, mesh: Mesh, spec_tree=None):
     """Place host params onto the mesh. spec_tree=None → fully replicated
-    (classic model distribution); otherwise each leaf lands sharded in its
-    TP layout directly (never materializing full copies per chip)."""
+    (classic model distribution); otherwise each leaf is placed under
+    its PartitionSpec: ``params`` is one full copy on the host, and each
+    chip receives its own shard of it, never a full copy per chip."""
     if spec_tree is None:
         sharding = NamedSharding(mesh, P())
         return jax.tree.map(lambda x: jax.device_put(x, sharding), params)
     return jax.tree.map(
         lambda x, s: jax.device_put(x, NamedSharding(mesh, s)),
         params, spec_tree)
+
+
+def _leaf_shardings(path: str, manifest: list, skel, treedef, mesh: Mesh,
+                    spec_tree) -> list:
+    """The NamedSharding of every tensor of the manifest, in file order,
+    from a ``spec_tree`` of PartitionSpecs. Same tree as the checkpoint,
+    every named axis in the mesh, every sharded dimension divisible:
+    else one ValueError that names the leaf. Reads no tensor."""
+    from jax.tree_util import keystr, tree_flatten_with_path
+    index_of = {keystr(k): i for k, i in tree_flatten_with_path(
+        _unflatten(skel, treedef, list(range(len(manifest)))))[0]}
+    spec_of = {keystr(k): s for k, s in tree_flatten_with_path(
+        spec_tree, is_leaf=lambda x: isinstance(x, P))[0]}
+
+    def refuse(leaf: str, why: str):
+        return ValueError(f"checkpoint {path!r} cannot be placed under "
+                          f"this spec_tree: leaf {leaf} {why}")
+
+    for leaf in index_of.keys() - spec_of.keys():
+        raise refuse(leaf, "has no PartitionSpec in the spec_tree")
+    for leaf in spec_of.keys() - index_of.keys():
+        raise refuse(leaf, "is in the spec_tree and not in the checkpoint")
+    out: list = [None] * len(manifest)
+    for leaf, i in index_of.items():
+        spec, shape = spec_of[leaf], manifest[i]["shape"]
+        if not isinstance(spec, P):
+            raise refuse(leaf, f"has {spec!r} where a PartitionSpec "
+                               f"belongs")
+        if len(spec) > len(shape):
+            raise refuse(leaf, f"of shape {shape} has fewer dimensions "
+                               f"than {spec}")
+        for dim, axes in zip(shape, spec):
+            axes = () if axes is None else \
+                axes if isinstance(axes, tuple) else (axes,)
+            ways = 1
+            for axis in axes:
+                if axis not in mesh.shape:
+                    raise refuse(leaf, f"names axis {axis!r}, and the "
+                                       f"mesh has {tuple(mesh.shape)}")
+                ways *= mesh.shape[axis]
+            if dim % ways:
+                raise refuse(leaf, f"of shape {shape} has a dimension "
+                                   f"of {dim} that {ways} chips along "
+                                   f"{spec} do not divide")
+        out[i] = NamedSharding(mesh, spec)
+    return out
+
+
+async def _distribute_sharded(client: CurvineClient, path: str, mesh: Mesh,
+                              spec_tree, allow_pickle: bool = False):
+    """A restore under a layout, as one restore: the layout checked
+    against the manifest, every tensor loaded into host memory (one full
+    copy: ckpt.host_copy), then each placed under its PartitionSpec
+    (ckpt.place, one sharded device_put a leaf: a chip receives its own
+    shard only), then the ready sweep. ckpt.bytes counts the checkpoint
+    once, ckpt.placed_bytes what all chips together received, from the
+    shardings' shard shapes."""
+    import asyncio
+    c = client.counters
+    with _restore(client, path):
+        manifest, skel, treedef = await _load_manifest(client, path,
+                                                       allow_pickle)
+        shardings = _leaf_shardings(path, manifest, skel, treedef, mesh,
+                                    spec_tree)
+        host = await asyncio.gather(*(
+            _load_tensor(client, path, t, None) for t in manifest))
+        flat, once, placed = [], 0, 0
+        for t, arr, sharding in zip(manifest, host, shardings):
+            with Timed(c, "ckpt.place", client.tracer.span(
+                    "ckpt.place", detail=True,
+                    attrs={"name": t["name"], "spec": str(sharding.spec)})):
+                flat.append(jax.device_put(arr, sharding))
+            once += arr.nbytes
+            placed += mesh.size * arr.itemsize * math.prod(
+                sharding.shard_shape(arr.shape))
+        del host
+        flat = _wait_ready(client, flat)
+        c["ckpt.bytes"] = c.get("ckpt.bytes", 0) + once
+        c["ckpt.placed_bytes"] = c.get("ckpt.placed_bytes", 0) + placed
+    return _unflatten(skel, treedef, flat)
 
 
 async def _hbm_source(client: CurvineClient, path: str,
@@ -322,18 +424,22 @@ async def _distribute_tree(client: CurvineClient, path: str, mesh: Mesh,
         counters["ici.broadcast_ms"] = \
             counters.get("ici.broadcast_ms", 0) \
             + int((time.perf_counter() - t_read) * 1000)
-    if skel is not None:
-        return _tree_build(skel, flat)
-    return jax.tree.unflatten(treedef, flat)
+    return _unflatten(skel, treedef, flat)
 
 
 async def distribute_checkpoint(client: CurvineClient, path: str,
                                 mesh: Mesh, spec_tree=None,
                                 schedule: str = "tree",
                                 allow_pickle: bool = False):
-    """cache → pod in one overlapped pass: each tensor is dispatched to
-    its mesh placement the moment its bytes land (replicated when
-    spec_tree is None, else directly in its TP layout).
+    """cache → pod as one restore; what comes back is ready on every
+    chip. With ``spec_tree`` None every tensor is replicated, in one
+    overlapped pass: each is dispatched to the mesh the moment its bytes
+    land. With a ``spec_tree`` (a PartitionSpec a leaf) the checkpoint
+    is restored under that layout: checked against the manifest before a
+    tensor is opened (ValueError naming the leaf that cannot be placed),
+    loaded whole into host memory, then placed leaf by leaf, each chip
+    receiving its own shard (_distribute_sharded: load, then place — not
+    yet overlapped, and one full host copy).
 
     ``schedule`` picks the replicated rail: "tree" (default) is the
     topology-scheduled path — LPT tensor order, peer-HBM device-domain
@@ -347,8 +453,8 @@ async def distribute_checkpoint(client: CurvineClient, path: str,
         return await load_checkpoint(
             client, path, placer=lambda a: jax.device_put(a, sharding),
             allow_pickle=allow_pickle)
-    host = await load_checkpoint(client, path, allow_pickle=allow_pickle)
-    return broadcast_params(host, mesh, spec_tree)
+    return await _distribute_sharded(client, path, mesh, spec_tree,
+                                     allow_pickle=allow_pickle)
 
 
 async def distribute_checkpoint_to_device(client: CurvineClient, path: str,

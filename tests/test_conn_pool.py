@@ -23,6 +23,10 @@ ECHO = 9_900
 
 
 def _fd_count() -> int:
+    """Fds the process holds, with nothing unreachable among them: an
+    earlier test file's garbage (sockets in reference cycles) collected
+    while a case runs would read as fds this pool gave back."""
+    gc.collect()
     return len(os.listdir("/proc/self/fd"))
 
 

@@ -442,6 +442,17 @@ class FsClient:
                               deadline=deadline)
         return FileBlocks.from_wire(rep["file_blocks"])
 
+    async def get_block_locations_batch(
+            self, paths: list[str]) -> list[FileBlocks | err.CurvineError]:
+        """get_block_locations for a list of paths in one round trip:
+        positional, a path's error beside the others' block lists (an
+        exception of the type its own call would raise, not raised)."""
+        rep = await self.call(RpcCode.GET_BLOCK_LOCATIONS_BATCH,
+                              {"paths": paths})
+        return [err.CurvineError.from_wire(r.get("error_code", 0), r["error"])
+                if "error" in r else FileBlocks.from_wire(r["file_blocks"])
+                for r in rep["responses"]]
+
     async def master_info(self) -> MasterInfo:
         rep = await self.call(RpcCode.GET_MASTER_INFO, {})
         return MasterInfo.from_wire(rep["info"])

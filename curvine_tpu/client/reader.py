@@ -42,6 +42,96 @@ def _block_crc(algo: str, data) -> tuple[int | None, int]:
     return None, 0
 
 
+def pick_loc(lb: LocatedBlock, client_host: str):
+    """The location a read of `lb` starts at: local-first (same host),
+    else the first one the master listed."""
+    if not lb.locs:
+        raise err.BlockNotFound(
+            f"block {lb.block.id} has no live locations")
+    for loc in lb.locs:
+        if client_host and client_host in (loc.hostname, loc.ip_addr):
+            return loc
+    return lb.locs[0]
+
+
+def probe_addr(lb: LocatedBlock, client_host: str) -> str | None:
+    """The worker a short-circuit probe of `lb` goes to — the block's
+    preferred location, where that is on this host — or None: nobody
+    asks for the local path of a block read over the socket."""
+    loc = pick_loc(lb, client_host)
+    if client_host in (loc.hostname, loc.ip_addr) or \
+            loc.ip_addr in ("127.0.0.1", "localhost"):
+        return FsReader._addr(loc)
+    return None
+
+
+def sc_reads_by_worker(into: dict[str, dict[int, int]],
+                       reads: dict[int, int],
+                       addr_of: dict[int, str]) -> None:
+    """Add per-block short-circuit read counts to `into`, keyed by the
+    worker that granted each block."""
+    for bid, n in reads.items():
+        addr = addr_of.get(bid)
+        if addr is not None:
+            per = into.setdefault(addr, {})
+            per[bid] = per.get(bid, 0) + n
+
+
+async def report_sc_reads(pool: ConnectionPool, addr: str,
+                          block_reads: dict[int, int]) -> dict:
+    """One SC_READ_REPORT to the granting worker (heat accounting only:
+    a failure is logged, not raised) → the warm-cache adverts its reply
+    piggybacks, block id → shm socket: blocks whose heat just crossed
+    the worker's shm_warm threshold."""
+    try:
+        conn = await pool.get(addr)
+        rep = await conn.call(RpcCode.SC_READ_REPORT,
+                              data=pack({"block_reads": block_reads}))
+    except (err.CurvineError, OSError) as e:
+        log.debug("sc read report to %s failed: %s", addr, e)
+        return {}
+    hdr = rep.header if isinstance(rep.header, dict) else {}
+    return hdr.get("shm_warm") or {}
+
+
+class Primed:
+    """What a client holds for a caller that named its files up front
+    (CurvineClient.prime), one answer a peer for the whole list where
+    each reader would have asked for itself: the master's answer a path
+    (`files`: its FileBlocks, or the error its open raises), the
+    co-located workers' GET_BLOCK_INFO answer a block (`blocks`: info
+    and the batch's send time, the lease clock), and the short-circuit
+    read counts of the readers closed since, by granting worker
+    (`reads`), until CurvineClient.flush_reports sends them. An entry
+    serves once: it is popped by the open, or the probe, that uses it."""
+
+    def __init__(self):
+        from curvine_tpu.worker.shm import ShmConns
+        self.files: dict[str, FileBlocks | err.CurvineError] = {}
+        self.blocks: dict[int, tuple[dict, float]] = {}
+        self.reads: dict[str, dict[int, int]] = {}
+        # the list's readers fetch their blocks' fds over connections
+        # to the workers' shm channels that stay open between them
+        self.conns = ShmConns()
+
+    def close(self) -> None:
+        """Drop what was not taken and the kept connections; `reads`
+        is the client's to send first (flush_reports)."""
+        self.files.clear()
+        self.blocks.clear()
+        self.conns.close()
+
+    def take_block(self, block_id: int) -> tuple[dict, float] | None:
+        """The primed answer for a block unless its lease has run out
+        (the reader then probes for itself, as if nothing was primed)."""
+        ent = self.blocks.pop(block_id, None)
+        if ent is not None:
+            lease = ent[0].get("lease_ms")
+            if lease and time.time() >= ent[1] + lease / 1000:
+                return None
+        return ent
+
+
 class ReadDetector:
     """Sequential/random access-pattern detector driving prefetch.
 
@@ -89,7 +179,11 @@ class FsReader:
                  counters: dict | None = None,
                  smart_prefetch: bool = True, seq_threshold: int = 3,
                  health=None, op_deadline_ms: int = 0, tracer=None,
-                 verify: bool = True):
+                 verify: bool = True, primed: Primed | None = None):
+        # the client's primed answers, where this reader was opened from
+        # one (CurvineClient.open): its probes look there first and its
+        # read counts merge there at close. None: it asks for itself
+        self.primed = primed
         # shared per-client WorkerHealth scoreboard (client/health.py):
         # replica choice deprioritizes open-circuit workers and every
         # remote outcome feeds back into it
@@ -215,14 +309,7 @@ class FsReader:
         return lb, offset - lb.offset
 
     def _pick_loc(self, lb: LocatedBlock):
-        if not lb.locs:
-            raise err.BlockNotFound(
-                f"block {lb.block.id} has no live locations")
-        host = self.fs.client_host
-        for loc in lb.locs:
-            if host and host in (loc.hostname, loc.ip_addr):
-                return loc
-        return lb.locs[0]
+        return pick_loc(lb, self.fs.client_host)
 
     @staticmethod
     def _addr(loc) -> str:
@@ -335,12 +422,16 @@ class FsReader:
         if not lb.locs:
             return None          # EC stripe (or locationless): no probe
         path = None
-        if self.short_circuit:
-            loc = self._pick_loc(lb)
-            if self.fs.client_host in (loc.hostname, loc.ip_addr) or \
-                    loc.ip_addr in ("127.0.0.1", "localhost"):
+        addr = probe_addr(lb, self.fs.client_host) \
+            if self.short_circuit else None
+        if addr is not None:
+            ent = self.primed.take_block(bid) \
+                if self.primed is not None else None
+            if ent is not None:
+                self._count("read.primed.blocks")
+                path = self._use_block_info(bid, *ent, addr)
+            else:
                 try:
-                    addr = f"{loc.ip_addr or loc.hostname}:{loc.rpc_port}"
                     with self._phase("probe"):
                         conn = await self.pool.get(addr)
                         # lease clocks start at request SEND, not reply
@@ -356,37 +447,43 @@ class FsReader:
                     if srv is not None:
                         # the worker's own share of the probe's wall
                         self._count("read.probe.srv_handle_s", srv[1])
-                    info = rep.header or unpack(rep.data) or {}
-                    if info.get("direct_io"):
-                        self.direct_queue_depth = max(
-                            self.direct_queue_depth,
-                            int(info.get("queue_depth", 0)))
-                    if info.get("crc32") is not None:
-                        self._block_crc[bid] = (
-                            info["crc32"], info.get("crc_algo", "crc32"))
-                    p = info.get("path")
-                    if p and os.path.exists(p):
-                        path = p
-                        self._local_offs[bid] = info.get("offset", 0)
-                        self._sc_addr[bid] = addr
-                        lease = info.get("lease_ms")
-                        if lease:
-                            self._local_expiry[bid] = \
-                                sent_at + lease / 1000
-                        if info.get("shm") and info.get("shm_sock"):
-                            # worker offers the sealed-memfd side
-                            # channel for this block: the next read
-                            # fetches the fd and maps it (shm wins
-                            # over the preadv fd path)
-                            self._shm_sock[bid] = info["shm_sock"]
-                            if info.get("shm_warm"):
-                                self._shm_warm.add(bid)
+                    path = self._use_block_info(
+                        bid, rep.header or unpack(rep.data) or {},
+                        sent_at, addr)
                 except err.CurvineError as e:
                     log.debug("short-circuit probe failed for %d: %s", bid, e)
         while len(self._local_paths) >= self._SC_CACHE_CAP:
             self._drop_local(next(iter(self._local_paths)))
         self._local_paths[bid] = path
         return path
+
+    def _use_block_info(self, bid: int, info: dict, sent_at: float,
+                        addr: str) -> str | None:
+        """Take in the GET_BLOCK_INFO answer of the worker at `addr` for
+        a block, asked at `sent_at` by this reader or for it (Primed) →
+        the block's local path, or None where this host cannot see it."""
+        if info.get("direct_io"):
+            self.direct_queue_depth = max(
+                self.direct_queue_depth, int(info.get("queue_depth", 0)))
+        if info.get("crc32") is not None:
+            self._block_crc[bid] = (
+                info["crc32"], info.get("crc_algo", "crc32"))
+        p = info.get("path")
+        if not p or not os.path.exists(p):
+            return None
+        self._local_offs[bid] = info.get("offset", 0)
+        self._sc_addr[bid] = addr
+        lease = info.get("lease_ms")
+        if lease:
+            self._local_expiry[bid] = sent_at + lease / 1000
+        if info.get("shm") and info.get("shm_sock"):
+            # worker offers the sealed-memfd side channel for this
+            # block: the next read fetches the fd and maps it (shm wins
+            # over the preadv fd path)
+            self._shm_sock[bid] = info["shm_sock"]
+            if info.get("shm_warm"):
+                self._shm_warm.add(bid)
+        return p
 
     async def _revalidate(self, lb: LocatedBlock) -> None:
         """A leased (bdev-extent) grant expired: re-probe GET_BLOCK_INFO
@@ -525,9 +622,12 @@ class FsReader:
         mapped there, beside its neighbours, not on its own.
         → (fd, granted length, mapping or None, checksum or None, bytes
         copied to hash); touches nothing of the reader's state."""
-        from curvine_tpu.worker.shm import fetch_block_fd
+        from curvine_tpu.worker import shm
         with self._phase("grant", spent):
-            fd, length = fetch_block_fd(spath, lb.block.id)
+            # a primed reader is one of many: over a kept connection
+            fetch = shm.fetch_block_fd if self.primed is None \
+                else self.primed.conns.fetch
+            fd, length = fetch(spath, lb.block.id)
         mm = got = None
         copied = 0
         if length == lb.block.len and length > 0:
@@ -814,26 +914,16 @@ class FsReader:
         reads, self._sc_reads = self._sc_reads, {}
         self._sc_pending = 0
         by_addr: dict[str, dict[int, int]] = {}
-        for bid, n in reads.items():
-            addr = self._sc_addr.get(bid)
-            if addr is not None:
-                by_addr.setdefault(addr, {})[bid] = n
+        sc_reads_by_worker(by_addr, reads, self._sc_addr)
         for addr, block_reads in by_addr.items():
-            try:
-                conn = await self.pool.get(addr)
-                rep = await conn.call(RpcCode.SC_READ_REPORT,
-                                      data=pack({"block_reads": block_reads}))
-                # The reply piggybacks warm-cache adverts: blocks whose
-                # heat just crossed the worker's shm_warm threshold.  The
-                # GET_BLOCK_INFO probe ran before the heat accrued, so
-                # this is how the very client that created the heat
-                # learns it can switch to the shm_warm rung.
-                hdr = rep.header if isinstance(rep.header, dict) else {}
-                for bid, sock in (hdr.get("shm_warm") or {}).items():
-                    self._shm_sock[int(bid)] = sock
-                    self._shm_warm.add(int(bid))
-            except (err.CurvineError, OSError) as e:
-                log.debug("sc read report to %s failed: %s", addr, e)
+            # The reply piggybacks warm-cache adverts.  The
+            # GET_BLOCK_INFO probe ran before the heat accrued, so
+            # this is how the very client that created the heat
+            # learns it can switch to the shm_warm rung.
+            warm = await report_sc_reads(self.pool, addr, block_reads)
+            for bid, sock in warm.items():
+                self._shm_sock[int(bid)] = sock
+                self._shm_warm.add(int(bid))
 
     # ---------------- reads ----------------
 
@@ -1599,7 +1689,14 @@ class FsReader:
                     pass
             elif not t.cancelled():
                 t.exception()     # retrieve, or the loop warns later
-        if self._sc_reads:
+        if self._sc_reads and self.primed is not None:
+            # one report a worker for all the caller's files, sent by
+            # CurvineClient.flush_reports before the caller is done
+            sc_reads_by_worker(self.primed.reads, self._sc_reads,
+                               self._sc_addr)
+            self._sc_reads, self._sc_pending = {}, 0
+            self._count("read.reports.merged")
+        elif self._sc_reads:
             await self._flush_sc_reads()
         for fd, _path in self._local_fds.values():
             try:

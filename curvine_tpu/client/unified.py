@@ -8,16 +8,22 @@ falls back to reading straight from the UFS, optionally warming the cache
 
 from __future__ import annotations
 
+import asyncio
 import logging
+import time
 
 from curvine_tpu.common import errors as err
 from curvine_tpu.common.conf import ClusterConf
-from curvine_tpu.common.types import StorageState, StorageType
+from curvine_tpu.common.types import FileBlocks, StorageState, StorageType
 from curvine_tpu.client.fs_client import FsClient
-from curvine_tpu.client.reader import FsReader
+from curvine_tpu.client.reader import (
+    FsReader, Primed, probe_addr, report_sc_reads,
+)
 from curvine_tpu.client.writer import FsWriter
 from curvine_tpu.obs.trace import Timed, Tracer
+from curvine_tpu.rpc import RpcCode
 from curvine_tpu.rpc.client import ConnectionPool
+from curvine_tpu.rpc.frame import pack, unpack
 
 log = logging.getLogger(__name__)
 
@@ -61,6 +67,9 @@ class CurvineClient:
         # that close() can cancel them; the master keeps one live load a
         # path, whoever asks and however often
         self._load_submits: set = set()
+        # what prime() asked for a list of files at once, until the
+        # opens and readers it was asked for have taken it
+        self._primed = Primed()
         # client-side IO counters: short-circuit reads/writes bypass the
         # worker entirely, so their bytes are invisible to worker metrics
         # — pushed to the master (METRICS_REPORT) so dashboards see the
@@ -84,7 +93,9 @@ class CurvineClient:
             self._metrics_task = None
         for t in list(self._load_submits):
             t.cancel()
+        self._primed.close()
         try:
+            await self.flush_reports()
             await self.flush_metrics()
         except Exception:      # noqa: BLE001 — best-effort on teardown
             pass
@@ -97,7 +108,6 @@ class CurvineClient:
         async entry points (construction can be outside a loop)."""
         if self._metrics_task is not None:
             return
-        import asyncio
 
         async def loop():
             while True:
@@ -137,7 +147,6 @@ class CurvineClient:
             await self.flush_metrics()
         except err.CurvineError:
             pass                       # collect may still answer
-        from curvine_tpu.rpc import RpcCode
         rep = await self.meta.call(RpcCode.GET_SPANS,
                                    {"trace_id": trace_id, "collect": True})
         return rep.get("spans", [])
@@ -182,13 +191,88 @@ class CurvineClient:
         w.pos = fb.status.len
         return w
 
+    async def prime(self, paths: list[str]) -> None:
+        """Name the files the caller is about to open, all at once: the
+        control exchanges of a read then cross once a peer for the list
+        and not once a file. One GET_BLOCK_LOCATIONS_BATCH to the master
+        (phase `locate`), then one list-taking GET_BLOCK_INFO to each
+        co-located worker for the blocks the readers would probe (phase
+        `probe`); each path's next `open` and its reader's probes find
+        their answers here, and the readers' short-circuit read counts
+        gather here at close until `flush_reports`. Advisory: where the
+        master or a worker does not answer the list (an older one, a
+        sharded router), nothing is held for it and every open and
+        probe asks for itself, as for a path never primed."""
+        self._ensure_metrics_task()
+        c = self.counters
+        with self.tracer.span("prime", attrs={"files": len(paths)}) as sp:
+            c["read.prime.calls"] = c.get("read.prime.calls", 0) + 1
+            try:
+                with Timed(c, "read.phase.locate"):
+                    answers = await self.meta.get_block_locations_batch(
+                        paths)
+            except err.CurvineError as e:
+                log.debug("prime of %d files not answered: %s",
+                          len(paths), e)
+                return
+            self._primed.files.update(zip(paths, answers))
+            by_worker: dict[str, list[int]] = {}
+            if self.conf.client.short_circuit:
+                for lb in (lb for fb in answers if isinstance(fb, FileBlocks)
+                           for lb in fb.block_locs if lb.locs):
+                    addr = probe_addr(lb, self.meta.client_host)
+                    if addr is not None:
+                        by_worker.setdefault(addr, []).append(lb.block.id)
+
+            async def probe(addr: str, block_ids: list[int]) -> None:
+                try:
+                    conn = await self.pool.get(addr)
+                    # the lease clock of every block of the list: SEND
+                    # time, earlier than any reader's own would be
+                    sent_at = time.time()
+                    rep = await conn.call(RpcCode.GET_BLOCK_INFO,
+                                          data=pack({"block_ids": block_ids}))
+                except err.CurvineError as e:
+                    log.debug("prime probe of %s failed: %s", addr, e)
+                    return
+                srv = rep.srv_seconds()
+                if srv is not None:
+                    c["read.probe.srv_handle_s"] = \
+                        c.get("read.probe.srv_handle_s", 0) + srv[1]
+                for info in (unpack(rep.data) or {}).get("blocks") or ():
+                    if "error" not in info:
+                        self._primed.blocks[info["block_id"]] = (info,
+                                                                 sent_at)
+
+            if by_worker:
+                with Timed(c, "read.phase.probe"):
+                    await asyncio.gather(*(probe(a, b)
+                                           for a, b in by_worker.items()))
+            sp.set_attr("blocks", sum(map(len, by_worker.values())))
+            sp.set_attr("workers", len(by_worker))
+
+    async def flush_reports(self) -> None:
+        """Send the short-circuit read counts that readers opened from a
+        primed entry left at close: one SC_READ_REPORT a worker. The
+        caller that primed flushes before it is done, so the worker's
+        heat is complete by then."""
+        reads, self._primed.reads = self._primed.reads, {}
+        for addr, block_reads in reads.items():
+            await report_sc_reads(self.pool, addr, block_reads)
+
     async def open(self, path: str) -> FsReader:
         self._ensure_metrics_task()
         # `locate`, the first phase of a read (docs/observability.md);
         # the reader accounts the others into the same counters
         with Timed(self.counters, "read.phase.locate",
                    self.tracer.span("open", attrs={"path": path})):
-            fb = await self.meta.get_block_locations(path)
+            # a primed answer serves one open
+            fb = self._primed.files.pop(path, None)
+            primed = fb is not None
+            if isinstance(fb, err.CurvineError):
+                raise fb
+            if not primed:
+                fb = await self.meta.get_block_locations(path)
         if _freed(fb.status) and fb.status.len:
             # an FsReader over the empty block list would read the
             # whole file as a hole, zeros
@@ -196,6 +280,9 @@ class CurvineClient:
                 f"{path}: freed from the cache, its bytes live in the "
                 f"under-store alone (unified_open reads them there)")
         self.counters["read.files"] = self.counters.get("read.files", 0) + 1
+        if primed:
+            self.counters["read.primed.files"] = \
+                self.counters.get("read.primed.files", 0) + 1
         cc = self.conf.client
         return FsReader(self.meta, path, fb, self.pool,
                         chunk_size=cc.read_chunk_size,
@@ -207,7 +294,8 @@ class CurvineClient:
                         health=self.health,
                         op_deadline_ms=cc.op_deadline_ms,
                         tracer=self.tracer,
-                        verify=cc.read_verify)
+                        verify=cc.read_verify,
+                        primed=self._primed if primed else None)
 
     async def write_all(self, path: str, data: bytes, **kw) -> None:
         # one root span covers create + uploads + complete; every RPC
@@ -227,8 +315,6 @@ class CurvineClient:
         batched block upload per worker (create/add/write/complete all
         batched). Parity: CreateFilesBatch/AddBlocksBatch/WriteBlocksBatch/
         CompleteFilesBatch codes."""
-        from curvine_tpu.rpc import RpcCode
-        from curvine_tpu.rpc.frame import pack, unpack
         if not files:
             return
         cc = self.conf.client

@@ -88,7 +88,8 @@ def classify(code: int) -> str | None:
 def _op_class_table() -> dict[int, str]:
     from curvine_tpu.rpc.codes import RpcCode as C
     reads = {C.OPEN_FILE, C.FILE_STATUS, C.LIST_STATUS, C.EXISTS,
-             C.GET_BLOCK_LOCATIONS, C.GET_LOCK, C.LIST_LOCK,
+             C.GET_BLOCK_LOCATIONS, C.GET_BLOCK_LOCATIONS_BATCH,
+             C.GET_LOCK, C.LIST_LOCK,
              C.LIST_OPTIONS, C.CONTENT_SUMMARY, C.GET_MOUNT_TABLE,
              C.GET_MOUNT_INFO, C.GET_JOB_STATUS,
              C.READ_BLOCK, C.GET_BLOCK_INFO, C.SC_READ_REPORT}
@@ -164,6 +165,13 @@ class TokenBucket:
             self.tokens -= n
             return 0.0
         return (n - self.tokens) / self.rate
+
+    def debit(self, n: float, now: float | None = None) -> None:
+        """Take ``n`` tokens of work already admitted, into debt where
+        the bucket holds fewer: the calls that follow wait it out."""
+        if self.rate > 0:
+            self._refill(time.monotonic() if now is None else now)
+            self.tokens -= n
 
     def refund(self, n: float = 1.0) -> None:
         """Give back tokens taken by an inner level that then rejected
@@ -442,6 +450,22 @@ class AdmissionController:
             self.metrics.gauge(f"tenant.{ts.name}.qps",
                                round(ts.last_qps, 1))
         return AdmitToken(ts, op_class)
+
+    def charge(self, tenant_name: str | None, op_class: str,
+               n: float) -> None:
+        """A list-taking call is admitted as one request and costs one
+        an item: its handler, which alone has read the list, charges the
+        other ``n`` here, global → tenant → op-class. Nothing is
+        refused — the call is already being served — so a list longer
+        than a bucket leaves it in debt."""
+        if not self.enabled or n <= 0:
+            return
+        now = time.monotonic()
+        ts = self._tenant(tenant_name or DEFAULT_TENANT)
+        for bucket in (self.global_bucket, ts.bucket,
+                       ts.classes.get(op_class)):
+            if bucket is not None:
+                bucket.debit(n, now)
 
     def admit_msg(self, code: int, header: dict) -> AdmitToken | None:
         """RPC-dispatch entry: classify the code, pull tenant + deadline

@@ -355,6 +355,7 @@ class MasterServer:
         r(C.ADD_BLOCK, self._h(self._add_block, mutate=True))
         r(C.COMPLETE_FILE, self._h(self._complete_file, mutate=True))
         r(C.GET_BLOCK_LOCATIONS, self._h(self._get_block_locations))
+        r(C.GET_BLOCK_LOCATIONS_BATCH, self._get_block_locations_batch)
         r(C.GET_MASTER_INFO, self._h(self._master_info))
         r(C.SET_ATTR, self._h(self._set_attr, mutate=True))
         r(C.SYMLINK, self._h(self._symlink, mutate=True))
@@ -480,6 +481,14 @@ class MasterServer:
             lambda q, m: sh.r_worker_heartbeat(q, m,
                                                self._worker_heartbeat)))
         r(C.WORKER_BLOCK_REPORT, wrap(sh.r_worker_block_report))
+
+        async def not_routed(q, msg):
+            # the router's own tree holds none of the files; the client
+            # sees the refusal and opens each file for itself
+            from curvine_tpu.common import errors as cerr
+            raise cerr.Unsupported("GET_BLOCK_LOCATIONS_BATCH is not "
+                                   "routed over a sharded namespace")
+        r(C.GET_BLOCK_LOCATIONS_BATCH, wrap(not_routed))
 
     # Path-valued request fields, normalized ('.'/'..' resolved, root
     # escapes rejected) before ANY handler sees them — an S3-gateway key
@@ -817,6 +826,26 @@ class MasterServer:
     def _get_block_locations(self, q):
         self.acl.check(UserCtx.from_req(q), q["path"], R)
         return {"file_blocks": self.fs.get_block_locations(q["path"]).to_wire()}
+
+    async def _get_block_locations_batch(self, msg: Message,
+                                         conn: ServerConn):
+        """GET_BLOCK_LOCATIONS for a list of paths in one round trip:
+        each path is normalized, checked against the caller's ACL and
+        answered for itself, its error beside the others' block lists.
+        Admitted as one read, charged as one a path."""
+        from curvine_tpu.common import errors as cerr
+        from curvine_tpu.common.qos import READ, TENANT_KEY
+        q = unpack(msg.data) or {}
+        paths = q.get("paths") or []
+        self.qos.charge(msg.header.get(TENANT_KEY), READ, len(paths) - 1)
+        out = []
+        for p in paths:
+            try:
+                out.append(self._get_block_locations(
+                    self._with_identity(q, {"path": norm_path(p)})))
+            except cerr.CurvineError as e:
+                out.append({"error": str(e), "error_code": int(e.code)})
+        return {}, pack({"responses": out})
 
     def _master_info(self, q):
         info = self.fs.master_info(self.addr)

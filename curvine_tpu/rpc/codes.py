@@ -104,6 +104,14 @@ class RpcCode(enum.IntEnum):
     # warming ahead of the read cursor (master/jobs.py kind="prefetch")
     PREFETCH_WINDOW = 75
 
+    # the read's list-taking form (CurvineClient.prime): `{"paths": [...]}`
+    # → `{"responses": [...]}`, one entry a path, positional — what
+    # GET_BLOCK_LOCATIONS answers for it (`file_blocks`) or the error it
+    # would have raised (`error`, `error_code`), so one path's
+    # FileNotFound or denial fails that path alone. The worker's half is
+    # GET_BLOCK_INFO with `block_ids` for `block_id`.
+    GET_BLOCK_LOCATIONS_BATCH = 76
+
     # block interface (worker)
     WRITE_BLOCK = 80
     READ_BLOCK = 81

@@ -20,7 +20,7 @@ import json
 import logging
 import math
 import time
-from contextlib import contextmanager
+from contextlib import asynccontextmanager
 
 import jax
 import numpy as np
@@ -143,9 +143,10 @@ async def load_checkpoint(client: CurvineClient, path: str,
     soon as its bytes land — cache reads overlap device transfers instead
     of the round-2 read-everything-then-transfer-everything sequence."""
     import asyncio
-    with _restore(client, path):
+    async with _restore(client, path):
         manifest, skel, treedef = await _load_manifest(client, path,
                                                        allow_pickle)
+        await _prime(client, path, manifest)
         flat = await asyncio.gather(*(
             _load_tensor(client, path, t, placer) for t in manifest))
         if placer is not None:
@@ -159,18 +160,30 @@ def _unflatten(skel, treedef, flat):
     return jax.tree.unflatten(treedef, flat)
 
 
-@contextmanager
-def _restore(client: CurvineClient, path: str):
+@asynccontextmanager
+async def _restore(client: CurvineClient, path: str):
     """One whole restore: the span ``ckpt.restore``, the root of its
     trace — every tensor's spans share the trace id that its slow-op
     line prints — and ckpt.wall_s / ckpt.restores, a restore's mean
-    seconds on /metrics."""
+    seconds on /metrics. It ends, however it ends, by sending the read
+    counts its tensors' readers left with the client (`_prime`): the
+    worker's heat is complete when the restore returns."""
     t0 = time.perf_counter()
     with client.tracer.span("ckpt.restore", attrs={"path": path}):
-        yield
+        try:
+            yield
+        finally:
+            await client.flush_reports()
     c = client.counters
     c["ckpt.wall_s"] = c.get("ckpt.wall_s", 0.0) + time.perf_counter() - t0
     c["ckpt.restores"] = c.get("ckpt.restores", 0) + 1
+
+
+async def _prime(client: CurvineClient, path: str, manifest: list) -> None:
+    """A restore knows every file it will open once it has the manifest:
+    name them to the client at once, so that locations, block info and
+    read reports cross once a peer and not once a tensor."""
+    await client.prime([f"{path}/{t['name']}" for t in manifest])
 
 
 async def _load_tensor(client: CurvineClient, path: str, t: dict, place,
@@ -310,11 +323,12 @@ async def _distribute_sharded(client: CurvineClient, path: str, mesh: Mesh,
     shardings' shard shapes."""
     import asyncio
     c = client.counters
-    with _restore(client, path):
+    async with _restore(client, path):
         manifest, skel, treedef = await _load_manifest(client, path,
                                                        allow_pickle)
         shardings = _leaf_shardings(path, manifest, skel, treedef, mesh,
                                     spec_tree)
+        await _prime(client, path, manifest)
         host = await asyncio.gather(*(
             _load_tensor(client, path, t, None) for t in manifest))
         flat, once, placed = [], 0, 0
@@ -390,9 +404,10 @@ async def _distribute_tree(client: CurvineClient, path: str, mesh: Mesh,
     Bit-exact with the flat path — only the sourcing and order differ."""
     import asyncio
     from curvine_tpu.tpu import ici_plane
-    with _restore(client, path):
+    async with _restore(client, path):
         manifest, skel, treedef = await _load_manifest(client, path,
                                                        allow_pickle)
+        await _prime(client, path, manifest)
         counters = client.counters
         devs = mesh.devices.reshape(-1)
         sched = ici_plane.broadcast_schedule(

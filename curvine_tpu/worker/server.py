@@ -1178,11 +1178,30 @@ class WorkerServer:
         return {}
 
     async def _get_block_info(self, msg: Message, conn: ServerConn):
-        """Metadata + local path (enables client short-circuit reads)."""
+        """Metadata + local path (enables client short-circuit reads):
+        of `block_id`, or of every id of `block_ids` (CurvineClient.prime)
+        as a list `blocks`, where a block this worker cannot serve is an
+        error entry beside the others. Admitted as one read, charged as
+        one a block."""
         q = unpack(msg.data) or {}
+        if "block_ids" not in q:
+            return self._block_info(q["block_id"])
+        from curvine_tpu.common.qos import READ, TENANT_KEY
+        self.qos.charge(msg.header.get(TENANT_KEY), READ,
+                        len(q["block_ids"]) - 1)
+        out = []
+        for bid in q["block_ids"]:
+            try:
+                out.append(self._block_info(bid))
+            except err.CurvineError as e:
+                out.append({"block_id": bid, "error": str(e),
+                            "error_code": int(e.code)})
+        return {}, pack({"blocks": out})
+
+    def _block_info(self, block_id: int) -> dict:
         # lookup + lease recording are one atomic store operation: a
         # free slipping in between would lease an already-freed extent
-        info, lease_ms = self.store.grant_sc(q["block_id"])
+        info, lease_ms = self.store.grant_sc(block_id)
         rep = {"block_id": info.block_id, "len": info.len,
                "storage_type": int(info.tier.storage_type),
                "path": os.path.abspath(info.path),
@@ -1219,7 +1238,7 @@ class WorkerServer:
             rep["shm_sock"] = self._shm_channel.path
         exports = getattr(self.hbm, "exports", None)
         if exports is not None and self.conf.worker.ici_transfer:
-            e = exports.get(q["block_id"])
+            e = exports.get(block_id)
             if e is not None:
                 # peer-addressable HBM advertisement (docs/ici-plane.md):
                 # an ICI-capable consumer can fetch the device buffer

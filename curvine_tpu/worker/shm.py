@@ -501,26 +501,94 @@ def fetch_block_fd(sock_path: str, block_id: int,
     with socket.socket(socket.AF_UNIX, socket.SOCK_STREAM) as s:
         s.settimeout(timeout)
         s.connect(sock_path)
-        s.sendall(_REQ.pack(block_id))
-        data, anc, _flags, _addr = s.recvmsg(
-            _REP.size, socket.CMSG_SPACE(array.array("i").itemsize))
-        if len(data) < _REP.size:
-            raise ConnectionResetError("shm channel closed mid-reply")
-        status, length = _REP.unpack(data)
-        fds = array.array("i")
-        for level, ctype, cdata in anc:
-            if level == socket.SOL_SOCKET and ctype == socket.SCM_RIGHTS:
-                fds.frombytes(cdata[:len(cdata)
-                                    - (len(cdata) % fds.itemsize)])
-        if status == NOT_FOUND:
-            for fd in fds:
-                os.close(fd)
-            raise LookupError(f"block {block_id} not shm-served")
-        if status != OK or not fds:
-            for fd in fds:
-                os.close(fd)
-            raise OSError(f"shm grant failed (status {status})")
-        fd = fds[0]
-        for extra in list(fds)[1:]:
-            os.close(extra)
-        return fd, length
+        return _ask(s, block_id)
+
+
+def _ask(s: socket.socket, block_id: int) -> tuple[int, int]:
+    """One request and its reply over a connection to a side channel."""
+    s.sendall(_REQ.pack(block_id))
+    data, anc, _flags, _addr = s.recvmsg(
+        _REP.size, socket.CMSG_SPACE(array.array("i").itemsize))
+    if len(data) < _REP.size:
+        raise ConnectionResetError("shm channel closed mid-reply")
+    status, length = _REP.unpack(data)
+    fds = array.array("i")
+    for level, ctype, cdata in anc:
+        if level == socket.SOL_SOCKET and ctype == socket.SCM_RIGHTS:
+            fds.frombytes(cdata[:len(cdata)
+                                - (len(cdata) % fds.itemsize)])
+    if status == NOT_FOUND:
+        for fd in fds:
+            os.close(fd)
+        raise LookupError(f"block {block_id} not shm-served")
+    if status != OK or not fds:
+        for fd in fds:
+            os.close(fd)
+        raise OSError(f"shm grant failed (status {status})")
+    fd = fds[0]
+    for extra in list(fds)[1:]:
+        os.close(extra)
+    return fd, length
+
+
+class ShmConns:
+    """Connections to workers' side channels that stay open between
+    grants, for a client whose fetch threads ask often. Over a kept
+    connection a grant is a request and a reply; a new one a grant also
+    costs the worker an accept and a thread of its own, and on a host
+    with several cores every one of those hand-overs is paid in waits
+    for the interpreter (PERF.md §6, PR 36). One fetch thread at a time
+    takes a connection and puts it back. The worker closes a connection
+    it has not heard from for 5 s, so a grant that fails on a kept one
+    is asked again on a new one."""
+
+    def __init__(self):
+        self._idle: dict[str, list[socket.socket]] | None = {}
+        self._lock = threading.Lock()
+
+    def fetch(self, sock_path: str, block_id: int,
+              timeout: float = 5.0) -> tuple[int, int]:
+        """fetch_block_fd over a kept connection, or a new one to keep."""
+        with self._lock:
+            idle = (self._idle or {}).get(sock_path)
+            s = idle.pop() if idle else None
+        if s is not None:
+            try:
+                return self._ask(sock_path, s, block_id)
+            except OSError:
+                pass                 # idled out at the worker: a new one
+        s = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
+        try:
+            s.settimeout(timeout)
+            s.connect(sock_path)
+        except OSError:
+            s.close()
+            raise
+        return self._ask(sock_path, s, block_id)
+
+    def _ask(self, sock_path: str, s: socket.socket,
+             block_id: int) -> tuple[int, int]:
+        try:
+            got = _ask(s, block_id)
+        except LookupError:
+            self._keep(sock_path, s)     # an answer: the connection is fine
+            raise
+        except BaseException:
+            s.close()
+            raise
+        self._keep(sock_path, s)
+        return got
+
+    def _keep(self, sock_path: str, s: socket.socket) -> None:
+        with self._lock:
+            if self._idle is not None:
+                self._idle.setdefault(sock_path, []).append(s)
+                return
+        s.close()                        # the client closed meanwhile
+
+    def close(self) -> None:
+        with self._lock:
+            idle, self._idle = self._idle, None
+        for conns in (idle or {}).values():
+            for s in conns:
+                s.close()

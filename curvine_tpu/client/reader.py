@@ -339,10 +339,13 @@ class FsReader:
         the client's counters as read.phase.<phase>.s / .n, always on,
         and the span phase.<phase>. A fetch thread never writes the
         counters: it times into a dict of its own (`into`), which the
-        loop adds when the thread has handed back."""
+        loop adds when the thread has handed back; a step there never
+        yields, so its thread's CPU seconds come beside the wall, on a
+        sample of the steps (read.phase.<phase>.cpu_s / .cpu_wall_s)."""
         return Timed(self.counters if into is None else into,
                      f"read.phase.{phase}",
-                     self._span(f"phase.{phase}", detail=True))
+                     self._span(f"phase.{phase}", detail=True),
+                     cpu=into is not None)
 
     # ---------------- hole regions ----------------
 
@@ -598,17 +601,25 @@ class FsReader:
     def _count_fetch(self, spent: dict, t_submit: float) -> None:
         """What a fetch thread stamped into `spent`, counted here, on
         the loop: fetch threads never write the counters. `resume` is
-        what the hand-off cost beside the work in the thread: submit →
-        thread running, thread returned → this task running again (the
-        loop, the GIL)."""
-        wall = time.perf_counter() - t_submit
-        for key, v in spent.items():
+        what the hand-off cost beside the work in the thread, in two
+        parts by the thread's stamps: `queue`, submit → the thread
+        running (the pool of fetch threads, the GIL), and `wake`, the
+        thread returned → this task running again (the client's loop).
+        `resume` is their sum: the thread's own statements between its
+        phases (microseconds) are in neither. A task cancelled while its
+        thread runs finds no return stamp: its hand-off is `queue`."""
+        now = time.perf_counter()
+        started = spent.pop("t_start", None)
+        ended = spent.pop("t_end", now)
+        if started is None:
+            return                  # no thread ran: no hand-off to count
+        for key, v in list(spent.items()):
             self._count(key, v)
-            if key.endswith(".s"):
-                wall -= v
-        if spent:
-            self._count("read.phase.resume.s", wall)
-            self._count("read.phase.resume.n")
+        queue, wake = started - t_submit, now - min(ended, now)
+        self._count("read.phase.resume.s", queue + wake)
+        self._count("read.phase.resume.n")
+        self._count("read.resume.queue.s", queue)
+        self._count("read.resume.wake.s", wake)
 
     def _fetch_shm(self, spath: str, lb: LocatedBlock, algo: str | None,
                    spent: dict, into: tuple | None = None) -> tuple:
@@ -621,35 +632,41 @@ class FsReader:
         `into` = (SpanMap, offset): the block is one of a range's and is
         mapped there, beside its neighbours, not on its own.
         → (fd, granted length, mapping or None, checksum or None, bytes
-        copied to hash); touches nothing of the reader's state."""
+        copied to hash); touches nothing of the reader's state.
+        It stamps when it starts and when it returns (`t_start`, `t_end`:
+        no phase of their own) for `_count_fetch`'s split of the hand-off."""
         from curvine_tpu.worker import shm
-        with self._phase("grant", spent):
-            # a primed reader is one of many: over a kept connection
-            fetch = shm.fetch_block_fd if self.primed is None \
-                else self.primed.conns.fetch
-            fd, length = fetch(spath, lb.block.id)
-        mm = got = None
-        copied = 0
-        if length == lb.block.len and length > 0:
-            # a block that is verified has every page read right away:
-            # the kernel maps them all in this one call (MAP_POPULATE)
-            # at a tenth of what a trap a page costs the hash (0.6
-            # against 6.3 us a page on a v5e host's VM, and the traps
-            # of all threads of a process take turns). mmap() runs
-            # without the GIL. Read-only either way (PROT_READ)
-            flags = mmap.MAP_SHARED | (
-                mmap.MAP_POPULATE if algo is not None else 0)
-            try:
-                with self._phase("map", spent):
-                    mm = mmap.mmap(fd, length, flags=flags,
-                                   prot=mmap.PROT_READ) if into is None \
-                        else into[0].map(fd, length, into[1], flags)
-            except (OSError, ValueError):
-                pass
-        if mm is not None and algo is not None:
-            with self._phase("verify", spent):
-                got, copied = _block_crc(algo, mm)
-        return fd, length, mm, got, copied
+        spent["t_start"] = time.perf_counter()
+        try:
+            with self._phase("grant", spent):
+                # a primed reader is one of many: over a kept connection
+                fetch = shm.fetch_block_fd if self.primed is None \
+                    else self.primed.conns.fetch
+                fd, length = fetch(spath, lb.block.id)
+            mm = got = None
+            copied = 0
+            if length == lb.block.len and length > 0:
+                # a block that is verified has every page read right away:
+                # the kernel maps them all in this one call (MAP_POPULATE)
+                # at a tenth of what a trap a page costs the hash (0.6
+                # against 6.3 us a page on a v5e host's VM, and the traps
+                # of all threads of a process take turns). mmap() runs
+                # without the GIL. Read-only either way (PROT_READ)
+                flags = mmap.MAP_SHARED | (
+                    mmap.MAP_POPULATE if algo is not None else 0)
+                try:
+                    with self._phase("map", spent):
+                        mm = mmap.mmap(fd, length, flags=flags,
+                                       prot=mmap.PROT_READ) if into is None \
+                            else into[0].map(fd, length, into[1], flags)
+                except (OSError, ValueError):
+                    pass
+            if mm is not None and algo is not None:
+                with self._phase("verify", spent):
+                    got, copied = _block_crc(algo, mm)
+            return fd, length, mm, got, copied
+        finally:
+            spent["t_end"] = time.perf_counter()
 
     async def _shm_read_into(self, lb: LocatedBlock, block_off: int,
                              out) -> int:

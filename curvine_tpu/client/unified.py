@@ -20,6 +20,7 @@ from curvine_tpu.client.reader import (
     FsReader, Primed, probe_addr, report_sc_reads,
 )
 from curvine_tpu.client.writer import FsWriter
+from curvine_tpu.obs import loop_meter
 from curvine_tpu.obs.trace import Timed, Tracer
 from curvine_tpu.rpc import RpcCode
 from curvine_tpu.rpc.client import ConnectionPool
@@ -77,6 +78,10 @@ class CurvineClient:
         self.counters: dict[str, float] = {}
         self._reported: dict[str, float] = {}
         self._metrics_task = None
+        # the meter of the loop this client runs on (obs/loop_meter.py):
+        # loop.* count into self.counters from the first async entry
+        # point to close()
+        self._loop_meter = None
         # the meta client accounts its calls (and the master's own time
         # from each reply) into the same dict: meta.*
         self.meta.counters = self.counters
@@ -101,6 +106,9 @@ class CurvineClient:
             pass
         await self.meta.close()
         await self.pool.close()
+        if self._loop_meter is not None:
+            self._loop_meter.detach(self.counters)
+            self._loop_meter = None
 
     def _ensure_metrics_task(self) -> None:
         """Periodic flush so dashboards see long-running jobs' sc bytes
@@ -108,6 +116,8 @@ class CurvineClient:
         async entry points (construction can be outside a loop)."""
         if self._metrics_task is not None:
             return
+        if self._loop_meter is None:
+            self._loop_meter = loop_meter.attach(self.counters)
 
         async def loop():
             while True:
@@ -426,6 +436,7 @@ class CurvineClient:
         `auto_cache` mount a miss also asks the master for an
         asynchronous load of the file (docs/caching.md, "Auto-cache on
         open"); this read is served from the UFS either way."""
+        self._ensure_metrics_task()
         with self.tracer.span("unified_open", attrs={"path": path}) as sp:
             st = await self.meta.file_status(path)
             try:

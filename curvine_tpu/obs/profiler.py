@@ -51,14 +51,6 @@ class StepProfiler:
         self.steps += 1
         self.metrics.inc("steps")
 
-    def publish_fractions(self) -> None:
-        """Gauge each stage's share of accounted pipeline time as
-        ``stage.<name>.frac``. For a caller that wants the gauges on
-        /metrics, at its own pace: ten quantile walks, so not a thing
-        for every step of a feed."""
-        for stage, frac in self.summary()["fractions"].items():
-            self.metrics.gauge(f"stage.{stage}.frac", frac)
-
     # ---------------- reporting ----------------
 
     def snapshot(self) -> dict:

@@ -240,29 +240,57 @@ class _NullSpan:
 NULL_SPAN = _NullSpan()
 
 
+# A thread's CPU clock is a system call where the wall clock is not: on
+# the chip host one read costs 5.9 us with the GIL held, at 10 ms
+# resolution (PR 37). So a Timed that counts CPU reads the clock on one
+# block in CPU_SAMPLE, drawn at random, and counts that block's wall
+# beside its CPU: the sum of the one over the other is the blocks' share
+# on a CPU, at an eighth of the reads.
+CPU_SAMPLE = 8
+_draw = random.Random().random
+
+
 class Timed:
     """One measurement, two sinks. ``with Timed(counters, key, span):``
     adds the block's seconds to ``counters[key + ".s"]`` and one to
     ``counters[key + ".n"]`` — always on, what `/metrics` and the
     benchmark read — and runs the block under ``span``, which is what
-    `cv trace`, the slow-op log and the profiler's timeline show."""
+    `cv trace`, the slow-op log and the profiler's timeline show.
+    ``cpu=True`` also counts, on a sample of the blocks (`CPU_SAMPLE`),
+    the CPU seconds of the thread that runs the block
+    (`time.thread_time`: user + system) into ``key + ".cpu_s"`` and the
+    same blocks' wall into ``key + ".cpu_wall_s"`` — for a block that
+    never yields: one that awaits would count the other tasks the loop
+    ran meanwhile."""
 
-    __slots__ = ("counters", "_s", "_n", "span", "_t0")
+    __slots__ = ("counters", "_s", "_n", "_cpu", "span", "_t0", "_c0")
 
-    def __init__(self, counters: dict, key: str, span=NULL_SPAN):
+    def __init__(self, counters: dict, key: str, span=NULL_SPAN,
+                 cpu: bool = False):
         self.counters = counters
         self._s = key + ".s"
         self._n = key + ".n"
+        self._cpu = key if cpu else None
         self.span = span
 
     def __enter__(self):
         self._t0 = time.perf_counter()
+        self._c0 = time.thread_time() if self._cpu is not None \
+            and _draw() * CPU_SAMPLE < 1 else None
         return self.span.__enter__()
 
     def __exit__(self, et, ev, tb) -> bool:
         self.span.__exit__(et, ev, tb)
         c = self.counters
-        c[self._s] = c.get(self._s, 0.0) + time.perf_counter() - self._t0
+        if self._c0 is None:
+            wall = time.perf_counter() - self._t0
+        else:
+            # read inside the wall clock's two reads: cpu <= wall
+            cpu = time.thread_time() - self._c0
+            wall = time.perf_counter() - self._t0
+            for k, v in ((".cpu_s", cpu), (".cpu_wall_s", wall)):
+                c[self._cpu + k] = c.get(self._cpu + k, 0.0) + v
+        c[self._s] = c.get(self._s, 0.0) + wall
         c[self._n] = c.get(self._n, 0) + 1
         return False
 

@@ -28,6 +28,7 @@ from typing import Awaitable, Callable
 
 from curvine_tpu.common.errors import CurvineError, Throttled
 from curvine_tpu.common.qos import TENANT_KEY
+from curvine_tpu.obs import loop_meter
 from curvine_tpu.rpc.frame import (
     FIXED_LEN, LEN_PREFIX, SRV_KEY, Flags, Message, error_for, response_for,
 )
@@ -161,6 +162,7 @@ class RpcServer:
         # optional MetricsRegistry: per-code dispatch latency histograms
         # (rpc.<code_name>), uniform across master and worker
         self.metrics = None
+        self._loop_meter = None
         # optional AdmissionController (common/qos.py): tenant admission
         # runs synchronously in the conn loop BEFORE the dispatch task
         # is created — a throttled request never queues, never runs a
@@ -187,10 +189,17 @@ class RpcServer:
         if self.port == 0:
             self.port = sock.getsockname()[1]
         self._accept_task = asyncio.ensure_future(self._accept_loop(loop))
+        if self.metrics is not None:
+            # the loop this server runs on, counted into its registry
+            # until stop() (loop.busy_s / cpu_s / runs: obs/loop_meter.py)
+            self._loop_meter = loop_meter.attach(self.metrics.counters)
         log.info("%s server listening on %s:%d", self.name, self.host,
                  self.port)
 
     async def stop(self) -> None:
+        if self._loop_meter is not None:
+            self._loop_meter.detach(self.metrics.counters)
+            self._loop_meter = None
         accept = self._accept_task
         if accept is not None:
             accept.cancel()

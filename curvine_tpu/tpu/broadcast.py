@@ -220,7 +220,8 @@ async def _load_tensor(client: CurvineClient, path: str, t: dict, place,
             out = _host_copy(client, arr)
         else:
             with Timed(client.counters, "ckpt.place",
-                       client.tracer.span("ckpt.place", detail=True)):
+                       client.tracer.span("ckpt.place", detail=True),
+                       cpu=True):
                 out = place(arr)
         if reader is not None:
             await reader.close()
@@ -232,7 +233,7 @@ def _host_copy(client: CurvineClient, arr: np.ndarray) -> np.ndarray:
     view, on the caller's thread."""
     c = client.counters
     with Timed(c, "ckpt.host_copy",
-               client.tracer.span("ckpt.host_copy", detail=True)):
+               client.tracer.span("ckpt.host_copy", detail=True), cpu=True):
         out = np.array(arr)
     c["ckpt.host_copy.bytes"] = c.get("ckpt.host_copy.bytes", 0) + out.nbytes
     return out
@@ -335,7 +336,8 @@ async def _distribute_sharded(client: CurvineClient, path: str, mesh: Mesh,
         for t, arr, sharding in zip(manifest, host, shardings):
             with Timed(c, "ckpt.place", client.tracer.span(
                     "ckpt.place", detail=True,
-                    attrs={"name": t["name"], "spec": str(sharding.spec)})):
+                    attrs={"name": t["name"], "spec": str(sharding.spec)}),
+                    cpu=True):
                 flat.append(jax.device_put(arr, sharding))
             once += arr.nbytes
             placed += mesh.size * arr.itemsize * math.prod(

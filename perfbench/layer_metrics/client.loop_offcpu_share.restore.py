@@ -1,0 +1,10 @@
+"""Share of the window the client's event loop held work without a CPU
+(client counters loop.busy_s less loop.cpu_s, summed over the
+restores' clients): its wait for the GIL the fetch threads hold, a
+blocking call, or descheduled."""
+
+from perfbench import loop_readers
+
+
+def read(run):
+    return loop_readers.offcpu_share(run, "client")

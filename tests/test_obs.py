@@ -399,13 +399,11 @@ def test_timed_counts_and_spans_and_step_done_walks_no_quantiles():
     with Timed(counters, "read.phase.x", off.span("phase.x")):
         pass
     assert counters["read.phase.x.n"] == 4
-    # the feed's hot path gauges nothing; a caller still may
+    # the feed's hot path gauges nothing
     p = StepProfiler()
     p.record("decode", 0.002)
     p.step_done()
     assert not p.metrics.gauges
-    p.publish_fractions()
-    assert p.metrics.gauges["stage.decode.frac"] == 1.0
 
 
 class _SlowOpLines(logging.Handler):
@@ -555,6 +553,82 @@ async def test_short_circuit_read_accounts_every_phase(tmp_path):
         assert {f"phase.{p}" for p in READ_PHASES
                 if p not in ("locate", "resume")} <= ops
         assert "open" in ops          # the span of `locate`
+
+
+def test_timed_samples_the_cpu_clock(monkeypatch):
+    """`cpu=True` reads the thread's CPU clock on one block in
+    CPU_SAMPLE and counts that block's wall beside it; the other blocks
+    count their wall alone."""
+    import time
+    from curvine_tpu.obs import trace
+    c: dict = {}
+    monkeypatch.setattr(trace, "CPU_SAMPLE", 1)
+    for _ in range(3):
+        with trace.Timed(c, "k", cpu=True):
+            t = time.perf_counter()
+            while time.perf_counter() - t < 0.002:
+                pass
+    assert c["k.n"] == 3 and c["k.cpu_wall_s"] == pytest.approx(c["k.s"])
+    assert 0 < c["k.cpu_s"] <= c["k.cpu_wall_s"]
+    monkeypatch.setattr(trace, "CPU_SAMPLE", 1e12)
+    with trace.Timed(c, "k", cpu=True):
+        pass
+    assert c["k.n"] == 4 and c["k.s"] > c["k.cpu_wall_s"]
+    with trace.Timed(c, "plain"):
+        pass
+    assert set(c) - {k for k in c if k.startswith("k.")} \
+        == {"plain.s", "plain.n"}
+
+
+async def test_a_fetch_hand_off_splits_and_its_steps_count_cpu(
+        tmp_path, monkeypatch):
+    """The hand-off of a shm fetch is its wait for a thread (`queue`) and
+    its wait for the loop (`wake`), which sum to `resume` whether the
+    block is mapped alone or beside its neighbours; the steps on the
+    fetch thread count that thread's CPU beside their wall, and an
+    owning host copy on the loop does too."""
+    import numpy as np
+    from curvine_tpu.obs import trace
+    from curvine_tpu.tpu.broadcast import load_checkpoint, save_checkpoint
+    monkeypatch.setattr(trace, "CPU_SAMPLE", 1)     # every block read
+    async with MiniCluster(workers=1, base_dir=str(tmp_path),
+                           block_size=128 * KB) as mc:
+        c = mc.client()
+        one, two = os.urandom(64 * KB), os.urandom(256 * KB)
+        await c.write_all("/ho/one.bin", one)
+        await c.write_all("/ho/two.bin", two)
+        before = dict(c.counters)
+        for path, data in (("/ho/one.bin", one), ("/ho/two.bin", two)):
+            r = await c.open(path)
+            assert bytes(await r.mmap_view(0, r.len)) == data
+            await r.close()
+
+        def grew(k):
+            return c.counters.get(k, 0) - before.get(k, 0)
+
+        assert grew("read.phase.resume.n") == 3      # one block, then two
+        queue, wake = grew("read.resume.queue.s"), grew("read.resume.wake.s")
+        assert queue > 0 and wake > 0
+        assert abs(queue + wake - grew("read.phase.resume.s")) < 1e-9
+        for p in ("grant", "map", "verify"):
+            assert grew(f"read.phase.{p}.n") == 3, p
+            assert 0 <= grew(f"read.phase.{p}.cpu_s") \
+                <= grew(f"read.phase.{p}.s"), p
+            assert grew(f"read.phase.{p}.cpu_wall_s") \
+                == pytest.approx(grew(f"read.phase.{p}.s")), p
+        # the phases that await count no CPU: other tasks ran meanwhile
+        assert not any(k.endswith(".cpu_s") for k in c.counters
+                       if k.startswith(("read.phase.probe",
+                                        "read.phase.locate",
+                                        "read.phase.close")))
+        # no placer: each tensor copied into host memory on the loop
+        params = {"w": np.arange(4096, dtype=np.float32)}
+        await save_checkpoint(c, "/ho/ck", params)
+        back = await load_checkpoint(c, "/ho/ck")
+        np.testing.assert_array_equal(back["w"], params["w"])
+        assert c.counters["ckpt.host_copy.n"] == 1
+        assert 0 <= c.counters["ckpt.host_copy.cpu_s"] \
+            <= c.counters["ckpt.host_copy.s"]
 
 
 async def test_srv_rides_the_reply_and_leaves_the_header(tmp_path):

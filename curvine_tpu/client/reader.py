@@ -8,9 +8,12 @@ the path the reference takes for fuse/local clients."""
 from __future__ import annotations
 
 import asyncio
+import collections
+import contextvars
 import logging
 import mmap
 import os
+import threading
 import time
 import zlib
 from contextlib import nullcontext
@@ -94,6 +97,193 @@ async def report_sc_reads(pool: ConnectionPool, addr: str,
     return hdr.get("shm_warm") or {}
 
 
+class _Fetch:
+    """One block's fetch, queued in a BatchFetcher or served by it: what
+    `FsReader._fetch_shm` is given, the waiter's future and context, and
+    what the batch thread brings back (`result` or `exc`)."""
+
+    __slots__ = ("reader", "lb", "algo", "spent", "into", "fut", "ctx",
+                 "result", "exc", "hop")
+
+    def __init__(self, reader, lb, algo, spent, into, fut):
+        self.reader, self.lb, self.algo = reader, lb, algo
+        self.spent, self.into, self.fut = spent, into, fut
+        self.ctx = contextvars.copy_context()
+        self.result = self.exc = None
+        self.hop = False         # the first block of its batch
+
+
+class BatchFetcher:
+    """The shm fetches of a primed client's readers, in batches
+    (docs/data-plane.md). A reader's block is queued here and awaited;
+    up to THREADS batch threads each take what is queued for one
+    worker's channel, up to CAP blocks, without waiting for more, and
+    send all of the batch's requests at once over one kept connection
+    (`ShmConns.pipeline`). As each reply is read, that block is mapped
+    and verified on the batch thread (`FsReader._map_verify`, the body a
+    thread of its own runs for an unprimed reader) and its result posted
+    to a list the loop drains: only a post to an empty list wakes the
+    loop, so one wake delivers every result finished since the last.
+    A block's refusal, stale grant, failed map or bad checksum is its
+    own (the waiter falls back or flags as from a thread of its own).
+    A waiter cancelled before its result is delivered leaves that result
+    to the fetcher, which closes its fd and mapping. A thread that finds
+    nothing queued exits; `close` stops them.
+
+    Counted on the loop, into the reader's counters: read.fetch.hops
+    (one a batch: a thread taking work) and read.fetch.batched_blocks.
+    Span `fetch_batch` (attrs blocks, worker) on the batch thread; each
+    block's phases run in its waiter's context, under its own spans."""
+
+    # chosen by a sweep of a primed restore on a TPU v5e host (PERF.md §6):
+    # eight threads were slower than seventeen of a block each, two best
+    THREADS = 2
+    CAP = 64
+
+    def __init__(self, conns):
+        self.conns = conns
+        self._lock = threading.Lock()
+        self._queued: dict[str, collections.deque] = {}
+        self._done: list[_Fetch] = []
+        self._threads: set[threading.Thread] = set()
+        self._closed = False
+
+    def fetch(self, reader, spath: str, lb: LocatedBlock, algo, spent: dict,
+              into: tuple | None = None) -> asyncio.Future:
+        """Queue a block's fetch from the channel at `spath` → the
+        future of `FsReader._fetch_shm`'s tuple (or of its error)."""
+        job = _Fetch(reader, lb, algo, spent, into,
+                     asyncio.get_running_loop().create_future())
+        t = None
+        with self._lock:
+            if self._closed:
+                raise OSError("the client is closed")
+            self._queued.setdefault(spath, collections.deque()).append(job)
+            if len(self._threads) < self.THREADS:
+                t = threading.Thread(target=self._work, daemon=True,
+                                     name="cv-fetch-batch")
+                self._threads.add(t)
+        if t is not None:
+            t.start()
+        return job.fut
+
+    def _work(self) -> None:
+        me = threading.current_thread()
+        while True:
+            with self._lock:
+                got = self._take()
+                if got is None:
+                    self._threads.discard(me)
+                    return
+            self._serve(*got)
+
+    def _take(self) -> tuple[str, list[_Fetch]] | None:
+        """Under the lock: up to CAP queued fetches of one channel, the
+        channel then moved behind the others."""
+        for spath in list(self._queued):
+            q = self._queued.pop(spath)
+            jobs = []
+            while q and len(jobs) < self.CAP:
+                job = q.popleft()
+                if not job.fut.done():      # its waiter is gone: skip it
+                    jobs.append(job)
+            if q:
+                self._queued[spath] = q
+            if jobs:
+                return spath, jobs
+        return None
+
+    def _serve(self, spath: str, jobs: list[_Fetch]) -> None:
+        first = jobs[0]
+        first.hop = True
+        replies = self.conns.pipeline(spath, [j.lb.block.id for j in jobs])
+        try:
+            # in the trace of the first block's waiter
+            with first.ctx.run(first.reader._span, "fetch_batch",
+                               blocks=len(jobs), worker=spath):
+                for job in jobs:
+                    job.ctx.run(self._one, job, replies)
+                    self._post(job)
+        finally:
+            replies.close()
+
+    @staticmethod
+    def _one(job: _Fetch, replies) -> None:
+        """One block of a batch, in its waiter's context, as a thread of
+        its own runs it: its reply (`grant`: the wait for it from when
+        the thread turns to this block; the first block's holds the
+        batch's send), then `_map_verify`. `t_start` is that turn, so the
+        wait behind the batch-mates ahead is the hand-off's `queue`;
+        `t_end` is the post."""
+        reader, spent = job.reader, job.spent
+        spent["t_start"] = time.perf_counter()
+        try:
+            with reader._phase("grant", spent):
+                got = next(replies)
+            if isinstance(got, Exception):
+                job.exc = got
+            else:
+                job.result = reader._map_verify(*got, job.lb, job.algo,
+                                                spent, job.into)
+        except Exception as e:  # noqa: BLE001 — the waiter's to handle
+            job.exc = e
+        finally:
+            spent["t_end"] = time.perf_counter()
+
+    def _post(self, job: _Fetch) -> None:
+        with self._lock:
+            self._done.append(job)
+            if len(self._done) > 1:
+                return               # a wake is on its way already
+        try:
+            job.fut.get_loop().call_soon_threadsafe(self._deliver)
+        except RuntimeError:         # the loop is closed: nobody takes them
+            with self._lock:
+                done, self._done = self._done, []
+            for j in done:
+                self._discard(j)
+
+    def _deliver(self) -> None:
+        """On the loop: every result posted since the last wake."""
+        with self._lock:
+            done, self._done = self._done, []
+        for job in done:
+            c = job.reader.counters
+            c["read.fetch.batched_blocks"] = \
+                c.get("read.fetch.batched_blocks", 0) + 1
+            if job.hop:
+                c["read.fetch.hops"] = c.get("read.fetch.hops", 0) + 1
+            if job.fut.done():
+                self._discard(job)
+            elif job.exc is not None:
+                job.fut.set_exception(job.exc)
+            else:
+                job.fut.set_result(job.result)
+
+    @staticmethod
+    def _discard(job: _Fetch) -> None:
+        """Close what a fetch whose waiter is gone was owed; a block of a
+        range leaves its mapping to the range (unmapped with it)."""
+        if job.result is not None:
+            fd, _length, mm, _got, _copied = job.result
+            FsReader._unmap(fd, mm if job.into is None else None)
+
+    def close(self) -> None:
+        """Refuse new fetches, fail those no thread has taken (their
+        readers fall back), and wait for the batches in hand: no thread
+        outlives the client."""
+        with self._lock:
+            self._closed = True
+            queued, self._queued = self._queued, {}
+            threads = list(self._threads)
+        for q in queued.values():
+            for job in q:
+                if not job.fut.done():
+                    job.fut.set_exception(OSError("the client is closed"))
+        for t in threads:
+            t.join(timeout=10.0)
+
+
 class Primed:
     """What a client holds for a caller that named its files up front
     (CurvineClient.prime), one answer a peer for the whole list where
@@ -110,15 +300,18 @@ class Primed:
         self.files: dict[str, FileBlocks | err.CurvineError] = {}
         self.blocks: dict[int, tuple[dict, float]] = {}
         self.reads: dict[str, dict[int, int]] = {}
-        # the list's readers fetch their blocks' fds over connections
-        # to the workers' shm channels that stay open between them
+        # the list's readers fetch their blocks' fds in batches, over
+        # connections to the workers' shm channels that stay open
         self.conns = ShmConns()
+        self.fetcher = BatchFetcher(self.conns)
 
     def close(self) -> None:
-        """Drop what was not taken and the kept connections; `reads`
-        is the client's to send first (flush_reports)."""
+        """Drop what was not taken, stop the batch fetcher and close the
+        kept connections; `reads` is the client's to send first
+        (flush_reports)."""
         self.files.clear()
         self.blocks.clear()
+        self.fetcher.close()
         self.conns.close()
 
     def take_block(self, block_id: int) -> tuple[dict, float] | None:
@@ -542,9 +735,10 @@ class FsReader:
         socket → thread; asyncio can't carry ancillary fds), map the
         sealed memfd read-only, verify the full block ONCE against the
         commit-time checksum — after which every read of the block is a
-        pure memory access. All three are one hand-off to a fetch thread
-        (`_fetch_shm`); the loop compares the checksum it brings back
-        and does everything that touches this reader's state.
+        pure memory access. All three run on a fetch thread (`_fetch`:
+        a batch's, for a primed reader); the loop compares the checksum
+        it brings back and does everything that touches this reader's
+        state.
         None → caller uses the fd/socket paths."""
         bid = lb.block.id
         ent = self._shm_maps.get(bid)
@@ -563,8 +757,8 @@ class FsReader:
         spent: dict[str, float] = {}
         t_submit = time.perf_counter()
         try:
-            fd, length, mm, got, copied = await asyncio.to_thread(
-                self._fetch_shm, spath, lb, algo, spent)
+            fd, length, mm, got, copied = await self._fetch(
+                spath, lb, algo, spent)
         except (LookupError, OSError, ValueError) as e:
             # worker dropped the export / channel gone: stop retrying
             # this block, serve it through fd/socket instead
@@ -621,52 +815,70 @@ class FsReader:
         self._count("read.resume.queue.s", queue)
         self._count("read.resume.wake.s", wake)
 
+    def _fetch(self, spath: str, lb: LocatedBlock, algo: str | None,
+               spent: dict, into: tuple | None = None):
+        """The hand-off of one block's fetch to a thread → awaitable of
+        `_fetch_shm`'s tuple. A primed reader is one of many: its block
+        joins the client's batches (`BatchFetcher`), a thread hop and a
+        wake of the loop for many blocks. Any other reader's is a thread
+        hop of its own, counted here (read.fetch.hops)."""
+        if self.primed is not None:
+            return self.primed.fetcher.fetch(self, spath, lb, algo, spent,
+                                             into)
+        self._count("read.fetch.hops")
+        return asyncio.to_thread(self._fetch_shm, spath, lb, algo, spent,
+                                 into)
+
     def _fetch_shm(self, spath: str, lb: LocatedBlock, algo: str | None,
                    spent: dict, into: tuple | None = None) -> tuple:
-        """On the fetch thread: `fetch_block_fd` (phase `grant`), map the
-        memfd (`map`) and, given the commit-time `algo`, checksum the
-        mapping where it lies (`verify`). The first touch of every page
-        and the hash run here, without the GIL, not on the loop.
-        Each phase has its span and leaves its seconds in `spent`, so the
-        awaiting task can tell the work from its own wait to run again.
-        `into` = (SpanMap, offset): the block is one of a range's and is
-        mapped there, beside its neighbours, not on its own.
-        → (fd, granted length, mapping or None, checksum or None, bytes
-        copied to hash); touches nothing of the reader's state.
-        It stamps when it starts and when it returns (`t_start`, `t_end`:
-        no phase of their own) for `_count_fetch`'s split of the hand-off."""
+        """On the fetch thread: `fetch_block_fd` (phase `grant`), then
+        `_map_verify`. → its tuple; touches nothing of the reader's
+        state. It stamps when it starts and when it returns (`t_start`,
+        `t_end`: no phase of their own) for `_count_fetch`'s split of
+        the hand-off."""
         from curvine_tpu.worker import shm
         spent["t_start"] = time.perf_counter()
         try:
             with self._phase("grant", spent):
-                # a primed reader is one of many: over a kept connection
-                fetch = shm.fetch_block_fd if self.primed is None \
-                    else self.primed.conns.fetch
-                fd, length = fetch(spath, lb.block.id)
-            mm = got = None
-            copied = 0
-            if length == lb.block.len and length > 0:
-                # a block that is verified has every page read right away:
-                # the kernel maps them all in this one call (MAP_POPULATE)
-                # at a tenth of what a trap a page costs the hash (0.6
-                # against 6.3 us a page on a v5e host's VM, and the traps
-                # of all threads of a process take turns). mmap() runs
-                # without the GIL. Read-only either way (PROT_READ)
-                flags = mmap.MAP_SHARED | (
-                    mmap.MAP_POPULATE if algo is not None else 0)
-                try:
-                    with self._phase("map", spent):
-                        mm = mmap.mmap(fd, length, flags=flags,
-                                       prot=mmap.PROT_READ) if into is None \
-                            else into[0].map(fd, length, into[1], flags)
-                except (OSError, ValueError):
-                    pass
-            if mm is not None and algo is not None:
-                with self._phase("verify", spent):
-                    got, copied = _block_crc(algo, mm)
-            return fd, length, mm, got, copied
+                fd, length = shm.fetch_block_fd(spath, lb.block.id)
+            return self._map_verify(fd, length, lb, algo, spent, into)
         finally:
             spent["t_end"] = time.perf_counter()
+
+    def _map_verify(self, fd: int, length: int, lb: LocatedBlock,
+                    algo: str | None, spent: dict,
+                    into: tuple | None = None) -> tuple:
+        """On a fetch thread, for a granted memfd: map it (`map`) and,
+        given the commit-time `algo`, checksum the mapping where it lies
+        (`verify`). The first touch of every page and the hash run here,
+        without the GIL, not on the loop. Each phase has its span and
+        leaves its seconds in `spent`, so the awaiting task can tell the
+        work from its own wait to run again. `into` = (SpanMap, offset):
+        the block is one of a range's and is mapped there, beside its
+        neighbours, not on its own. → (fd, granted length, mapping or
+        None, checksum or None, bytes copied to hash)."""
+        mm = got = None
+        copied = 0
+        if length == lb.block.len and length > 0:
+            # a block that is verified has every page read right away:
+            # the kernel maps them all in this one call (MAP_POPULATE)
+            # at a tenth of what a trap a page costs the hash (0.6
+            # against 6.3 us a page on a v5e host's VM, and the traps
+            # of all threads of a process take turns). mmap() runs
+            # without the GIL. Read-only either way (PROT_READ)
+            flags = mmap.MAP_SHARED | (
+                mmap.MAP_POPULATE if algo is not None else 0)
+            try:
+                with self._phase("map", spent):
+                    mm = mmap.mmap(fd, length, flags=flags,
+                                   prot=mmap.PROT_READ) if into is None \
+                        else into[0].map(fd, length, into[1], flags)
+            except (OSError, ValueError):
+                pass
+        if mm is not None and algo is not None:
+            with self._phase("verify", spent):
+                got, copied = _block_crc(algo, mm)
+        return fd, length, mm, got, copied
 
     async def _shm_read_into(self, lb: LocatedBlock, block_off: int,
                              out) -> int:
@@ -802,9 +1014,8 @@ class FsReader:
             spent: dict[str, float] = {}
             t_submit = time.perf_counter()
             try:
-                return await asyncio.to_thread(
-                    self._fetch_shm, spath, lb, want[1], spent,
-                    (span, lb.offset - lbs[0].offset))
+                return await self._fetch(spath, lb, want[1], spent,
+                                         (span, lb.offset - lbs[0].offset))
             finally:
                 self._count_fetch(spent, t_submit)
 

@@ -507,6 +507,17 @@ def fetch_block_fd(sock_path: str, block_id: int,
 def _ask(s: socket.socket, block_id: int) -> tuple[int, int]:
     """One request and its reply over a connection to a side channel."""
     s.sendall(_REQ.pack(block_id))
+    got = _answer(s, block_id)
+    if isinstance(got, Exception):
+        raise got
+    return got
+
+
+def _answer(s: socket.socket, block_id: int) -> tuple[int, int] | Exception:
+    """The next reply on a connection, the one to the request for
+    `block_id` → (fd, length), or the refusal the worker answered with
+    (LookupError: not served; OSError: the grant failed), returned and
+    not raised. Raises OSError where the channel itself fails."""
     data, anc, _flags, _addr = s.recvmsg(
         _REP.size, socket.CMSG_SPACE(array.array("i").itemsize))
     if len(data) < _REP.size:
@@ -520,11 +531,11 @@ def _ask(s: socket.socket, block_id: int) -> tuple[int, int]:
     if status == NOT_FOUND:
         for fd in fds:
             os.close(fd)
-        raise LookupError(f"block {block_id} not shm-served")
+        return LookupError(f"block {block_id} not shm-served")
     if status != OK or not fds:
         for fd in fds:
             os.close(fd)
-        raise OSError(f"shm grant failed (status {status})")
+        return OSError(f"shm grant failed (status {status})")
     fd = fds[0]
     for extra in list(fds)[1:]:
         os.close(extra)
@@ -537,26 +548,62 @@ class ShmConns:
     connection a grant is a request and a reply; a new one a grant also
     costs the worker an accept and a thread of its own, and on a host
     with several cores every one of those hand-overs is paid in waits
-    for the interpreter (PERF.md §6, PR 36). One fetch thread at a time
-    takes a connection and puts it back. The worker closes a connection
-    it has not heard from for 5 s, so a grant that fails on a kept one
-    is asked again on a new one."""
+    for the interpreter (PERF.md §6). One fetch thread at a time takes a
+    connection and puts it back, and may ask for many blocks at once
+    over it (`pipeline`): the worker answers a connection's requests one
+    after another, in order. The worker closes a connection it has not
+    heard from for 5 s, so a channel that fails under a batch is asked
+    again, once, on a new connection."""
 
     def __init__(self):
         self._idle: dict[str, list[socket.socket]] | None = {}
         self._lock = threading.Lock()
 
-    def fetch(self, sock_path: str, block_id: int,
-              timeout: float = 5.0) -> tuple[int, int]:
-        """fetch_block_fd over a kept connection, or a new one to keep."""
+    def pipeline(self, sock_path: str, block_ids: list[int],
+                 timeout: float = 5.0):
+        """Grants of `block_ids`, their requests sent at once over one
+        connection → yields each block's answer, in order, as it is
+        read: (fd, length), or the exception its grant failed with
+        (returned, not raised: LookupError where the worker does not
+        serve the block, OSError where the grant or the channel failed).
+        Where the channel fails, the blocks not answered yet are asked
+        again on a new connection, once; after that each gets the error.
+        Every fd yielded is the caller's. A generator left before its
+        end closes its connection: answers are still on the way."""
+        left = list(block_ids)
+        s = None
         with self._lock:
             idle = (self._idle or {}).get(sock_path)
-            s = idle.pop() if idle else None
-        if s is not None:
-            try:
-                return self._ask(sock_path, s, block_id)
-            except OSError:
-                pass                 # idled out at the worker: a new one
+            if idle:
+                s = idle.pop()
+        tries = 2
+        try:
+            while left:
+                try:
+                    if s is None:
+                        s = self._dial(sock_path, timeout)
+                    s.sendall(b"".join(_REQ.pack(b) for b in left))
+                    while left:
+                        got = _answer(s, left[0])
+                        left.pop(0)
+                        yield got
+                except OSError as e:
+                    if s is not None:
+                        s.close()
+                        s = None
+                    tries -= 1
+                    while left and not tries:
+                        left.pop(0)
+                        yield e
+        finally:
+            if s is not None:
+                if left:
+                    s.close()
+                else:
+                    self._keep(sock_path, s)
+
+    @staticmethod
+    def _dial(sock_path: str, timeout: float) -> socket.socket:
         s = socket.socket(socket.AF_UNIX, socket.SOCK_STREAM)
         try:
             s.settimeout(timeout)
@@ -564,20 +611,7 @@ class ShmConns:
         except OSError:
             s.close()
             raise
-        return self._ask(sock_path, s, block_id)
-
-    def _ask(self, sock_path: str, s: socket.socket,
-             block_id: int) -> tuple[int, int]:
-        try:
-            got = _ask(s, block_id)
-        except LookupError:
-            self._keep(sock_path, s)     # an answer: the connection is fine
-            raise
-        except BaseException:
-            s.close()
-            raise
-        self._keep(sock_path, s)
-        return got
+        return s
 
     def _keep(self, sock_path: str, s: socket.socket) -> None:
         with self._lock:

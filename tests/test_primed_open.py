@@ -6,8 +6,11 @@ they land on and the worker's heat are those of the per-file path; a
 path the master refuses fails its own open alone; and a file never
 primed makes the calls it always made."""
 
+import asyncio
 import os
 import socket
+import sys
+import threading
 import time
 
 import numpy as np
@@ -17,6 +20,7 @@ import jax
 from jax.sharding import PartitionSpec as P
 
 from curvine_tpu.client import CurvineClient
+from curvine_tpu.client.reader import BatchFetcher
 from curvine_tpu.common import errors as err
 from curvine_tpu.common.conf import ClusterConf
 from curvine_tpu.common.qos import READ, AdmissionController
@@ -133,10 +137,9 @@ async def test_a_primed_restore_crosses_once_a_peer(tmp_path, entry,
         accepted = count_connections(monkeypatch)
         back, grew, master, worker, heated = await restored(mc, entry, path)
         # the manifest's grant on a connection of its own, the tensors'
-        # 17 over connections that stay open: one a fetch thread at most
+        # over connections that stay open: one a batch thread at most
         assert grew("read.shm_hits") == blocks_of(params) + 1
-        assert 2 <= len(accepted) <= 1 + min(blocks_of(params), 32,
-                                             (os.cpu_count() or 1) + 4)
+        assert 2 <= len(accepted) <= 1 + BatchFetcher.THREADS
 
         for want, got in zip(leaves, jax.tree.leaves(back)):
             assert np.asarray(got).tobytes() == want.tobytes()
@@ -376,13 +379,19 @@ async def test_the_worker_answers_a_list_block_for_block(tmp_path):
         assert {b: heat(mc)[b] - h0[b] for b in ids} == {ids[0]: 2, ids[1]: 1}
 
 
-def test_kept_connections_serve_grant_after_grant(tmp_path, monkeypatch):
+@pytest.fixture
+def memfd_channel(tmp_path, monkeypatch):
+    """A side channel whose grant of block n is a memfd of n bytes of
+    b"x" (404: not served; 500: the grant fails) → (its path, the
+    connections it accepted, the blocks it granted, in order)."""
     accepted = count_connections(monkeypatch)
     granted = []
 
     def grant(block_id):
         if block_id == 404:
             raise LookupError(block_id)
+        if block_id == 500:
+            raise RuntimeError(block_id)
         granted.append(block_id)
         fd = os.memfd_create(f"t{block_id}")
         os.write(fd, b"x" * block_id)
@@ -391,18 +400,29 @@ def test_kept_connections_serve_grant_after_grant(tmp_path, monkeypatch):
     path = str(tmp_path / "shm.sock")
     channel = wshm.ShmChannel(path, grant)
     channel.start()
+    yield path, accepted, granted
+    channel.stop()
+
+
+def test_kept_connections_serve_grant_after_grant(tmp_path, memfd_channel):
+    path, accepted, granted = memfd_channel
     conns = wshm.ShmConns()
+
+    def one(sock_path, n):
+        (got,) = conns.pipeline(sock_path, [n])
+        return got
+
     try:
         for n in (5, 6, 7):
-            fd, length = conns.fetch(path, n)
+            fd, length = one(path, n)
             assert length == n and os.pread(fd, 16, 0) == b"x" * n
             os.close(fd)
-        with pytest.raises(LookupError):
-            conns.fetch(path, 404)             # an answer: still kept
+        # an answer: still kept
+        assert isinstance(one(path, 404), LookupError)
         assert len(accepted) == 1 and len(conns._idle[path]) == 1
         # the worker closes a connection that idles: asked again on a new
         conns._idle[path][0].shutdown(socket.SHUT_RDWR)
-        fd, length = conns.fetch(path, 8)
+        fd, length = one(path, 8)
         os.close(fd)
         assert length == 8 and len(accepted) == 2
         assert granted == [5, 6, 7, 8] and len(conns._idle[path]) == 1
@@ -414,15 +434,274 @@ def test_kept_connections_serve_grant_after_grant(tmp_path, monkeypatch):
         (kept,) = conns._idle[path]
         conns.close()
         assert kept.fileno() == -1
-        fd, length = conns.fetch(path, 3)
+        fd, length = one(path, 3)
         os.close(fd)
         assert length == 3 and conns._idle is None
-        with pytest.raises(OSError):
-            conns.fetch(str(tmp_path / "nobody.sock"), 1)
+        assert isinstance(one(str(tmp_path / "nobody.sock"), 1), OSError)
     finally:
         conns.close()
-        channel.stop()
 
+
+def answers(got: list) -> list:
+    """A pipeline's answers as (length, bytes), LookupError or OSError;
+    each fd closed."""
+    out = []
+    for a in got:
+        if isinstance(a, Exception):
+            assert isinstance(a, (LookupError, OSError)), a
+            out.append(LookupError if isinstance(a, LookupError)
+                       else OSError)
+            continue
+        fd, length = a
+        out.append((length, os.pread(fd, 1024, 0)))
+        os.close(fd)
+    return out
+
+
+def test_pipelined_grants_answer_in_order_on_one_connection(memfd_channel):
+    path, accepted, granted = memfd_channel
+    conns = wshm.ShmConns()
+    try:
+        got = answers(conns.pipeline(path, [5, 404, 6, 500, 7]))
+        # each block its own fd and length, in the order asked; a refusal
+        # and a failed grant are answers of their own block alone
+        assert got == [(5, b"x" * 5), LookupError, (6, b"x" * 6), OSError,
+                       (7, b"x" * 7)]
+        assert granted == [5, 6, 7]
+        # one connection for the batch, kept for the next
+        assert len(accepted) == 1 and len(conns._idle[path]) == 1
+        assert answers(conns.pipeline(path, [8, 9])) \
+            == [(8, b"x" * 8), (9, b"x" * 9)]
+        assert len(accepted) == 1
+        # left before its end: the connection has answers on the way
+        gen = conns.pipeline(path, [10, 11])
+        answers([next(gen)])
+        gen.close()
+        assert not conns._idle[path]
+    finally:
+        conns.close()
+
+
+@pytest.mark.parametrize("drops, want", [
+    # dropped once: the unanswered blocks asked again on a new connection
+    ((3,), [(5, b"x" * 5), (6, b"x" * 6), (7, b"x" * 7), (8, b"x" * 8)]),
+    # dropped again there: asked once only, the rest get the error
+    ((3, 4), [(5, b"x" * 5), (6, b"x" * 6), OSError, OSError]),
+])
+def test_a_channel_that_drops_mid_batch_is_asked_again_once(
+        memfd_channel, monkeypatch, drops, want):
+    path, accepted, granted = memfd_channel
+    replies, reply = [], wshm.ShmChannel._reply
+
+    def dropping(conn, status, length, fd):
+        replies.append(length)
+        if len(replies) in drops:
+            conn.shutdown(socket.SHUT_RDWR)     # the channel fails here
+            return False
+        return reply(conn, status, length, fd)
+
+    monkeypatch.setattr(wshm.ShmChannel, "_reply", staticmethod(dropping))
+    conns = wshm.ShmConns()
+    try:
+        assert answers(conns.pipeline(path, [5, 6, 7, 8])) == want
+        # a new connection once, however often the channel drops
+        assert len(accepted) == 2
+        # the grant the channel dropped was asked for again
+        assert granted[:4] == [5, 6, 7, 7]
+    finally:
+        conns.close()
+
+
+
+def gate_batches(monkeypatch) -> tuple[threading.Event, list]:
+    """Batch threads wait for the event before they take anything, and
+    each batch's block ids are kept, in the order sent."""
+    gate, batches = threading.Event(), []
+    work, pipeline = BatchFetcher._work, wshm.ShmConns.pipeline
+
+    def late(self):
+        gate.wait(10)
+        work(self)
+
+    def recorded(self, spath, ids, timeout=5.0):
+        batches.append(list(ids))
+        return pipeline(self, spath, ids, timeout)
+
+    monkeypatch.setattr(BatchFetcher, "_work", late)
+    monkeypatch.setattr(wshm.ShmConns, "pipeline", recorded)
+    return gate, batches
+
+
+async def written(mc, data: dict) -> dict:
+    """`data` written, one block a file → path: its block id."""
+    w = mc.client()
+    for p, b in data.items():
+        await w.write_all(p, b)
+    return {p: (await w.meta.get_block_locations(p)).block_locs[0].block.id
+            for p in data}
+
+
+async def test_a_spoiled_block_of_a_batch_falls_back_alone(tmp_path,
+                                                           monkeypatch):
+    data = {f"/b/f{i}": bytes([i]) * BLOCK for i in range(7)}
+    async with MiniCluster(workers=1, base_dir=str(tmp_path),
+                           block_size=BLOCK) as mc:
+        ids = await written(mc, data)
+        gone, stale, bad = ids["/b/f2"], ids["/b/f3"], ids["/b/f4"]
+        channel = mc.workers[0]._shm_channel
+        grant = channel.grant
+
+        def spoiled(block_id):
+            if block_id == gone:
+                raise LookupError(block_id)        # NOT_FOUND
+            fd, length = grant(block_id)
+            if block_id not in (stale, bad):
+                return fd, length
+            os.close(fd)
+            # another length than the block's, or its length with
+            # bytes its checksum does not match
+            fake = os.memfd_create("spoiled")
+            os.write(fake, b"\xff" * (length + (length if block_id == stale
+                                                else 0)))
+            return fake, os.fstat(fake).st_size
+
+        channel.grant = spoiled
+        gate, batches = gate_batches(monkeypatch)
+        c = mc.client()
+        await c.prime(list(data))
+        readers = [await c.open(p) for p in data]
+        before = dict(c.counters)
+        views = asyncio.gather(*(r.mmap_view(0, r.len) for r in readers))
+        await asyncio.sleep(0.05)        # every block queued before a take
+        gate.set()
+        for p, r, view in zip(data, readers, await views):
+            if view is None:             # the bad copy, flagged: by socket
+                view = await r.read_all()
+            assert bytes(view) == data[p], p
+            await r.close()
+        grew = grown(c.counters, before)
+        assert batches == [list(ids.values())]
+        assert grew("read.fetch.hops") == 1
+        assert grew("read.fetch.batched_blocks") == 7
+        assert grew("read.shm_hits") == 4 and grew("read.shm_fallbacks") == 3
+        assert grew("read.checksum_mismatch") == 1
+
+
+def open_fds() -> int:
+    return len(os.listdir("/proc/self/fd"))
+
+
+def block_maps() -> int:
+    with open("/proc/self/maps") as f:
+        return sum("memfd:cv-blk" in line for line in f)
+
+
+async def test_a_waiter_cancelled_in_a_batch_leaves_nothing_open(
+        tmp_path, monkeypatch):
+    data = {f"/c/f{i}": bytes([i + 1]) * BLOCK for i in range(2)}
+    monkeypatch.setattr(BatchFetcher, "THREADS", 1)   # one kept connection
+    async with MiniCluster(workers=1, base_dir=str(tmp_path),
+                           block_size=BLOCK) as mc:
+        await written(mc, data)
+        c = mc.client()
+        for _ in range(2):               # exports made, the connection kept
+            await c.prime(list(data))
+            for p in data:
+                r = await c.open(p)
+                assert bytes(await r.mmap_view(0, r.len)) == data[p]
+                await r.close()
+        gate = threading.Event()
+        pipeline = wshm.ShmConns.pipeline
+
+        def held(self, spath, ids, timeout=5.0):
+            gate.wait(10)
+            yield from pipeline(self, spath, ids, timeout)
+
+        monkeypatch.setattr(wshm.ShmConns, "pipeline", held)
+        await c.prime(list(data))
+        readers = [await c.open(p) for p in data]
+        fds, maps = open_fds(), block_maps()
+        first, second = (asyncio.ensure_future(r.mmap_view(0, r.len))
+                         for r in readers)
+        await asyncio.sleep(0.05)        # the first in a batch, held
+        first.cancel()
+        with pytest.raises(asyncio.CancelledError):
+            await first
+        gate.set()
+        assert bytes(await second) == data["/c/f1"]
+        del second                       # its view holds the mapping open
+        for r in readers:
+            await r.close()
+        # the cancelled block was granted, mapped and verified all the
+        # same; the fetcher closed what it was owed
+        for _ in range(100):
+            if (open_fds(), block_maps()) == (fds, maps):
+                break
+            await asyncio.sleep(0.02)
+        assert (open_fds(), block_maps()) == (fds, maps)
+        assert c.counters["read.fetch.batched_blocks"] == 3 * len(data)
+
+
+@pytest.mark.parametrize("threads, cap, switch_s", [
+    (BatchFetcher.THREADS, BatchFetcher.CAP, None),
+    (8, 3, 1e-5),                      # many small batches, threads racing
+])
+async def test_a_primed_restore_hands_its_blocks_off_in_batches(
+        tmp_path, monkeypatch, threads, cap, switch_s):
+    params = {f"t{i:02d}": np.full(256, i, np.float32) for i in range(64)}
+    n = len(params)                    # a block each
+    monkeypatch.setattr(BatchFetcher, "THREADS", threads)
+    monkeypatch.setattr(BatchFetcher, "CAP", cap)
+    switch = sys.getswitchinterval()
+    try:
+        if switch_s is not None:
+            sys.setswitchinterval(switch_s)
+        async with MiniCluster(workers=1, base_dir=str(tmp_path),
+                               block_size=BLOCK) as mc:
+            path = await saved(mc, params)
+            c = mc.client()
+            back = await asyncio.wait_for(flat(c, path), 60)
+            fetcher = c._primed.fetcher
+    finally:
+        sys.setswitchinterval(switch)
+    for k, v in params.items():
+        assert np.asarray(back[k]).tobytes() == v.tobytes(), k
+    grew = grown(c.counters, {})
+    assert grew("read.fetch.batched_blocks") == n
+    # the manifest's blocks are a hop each (it is not primed)
+    manifest = grew("read.phase.grant.n") - n
+    assert manifest in (1, 2)
+    hops = grew("read.fetch.hops") - manifest
+    assert -(-n // cap) <= hops <= (n // 4 if switch_s is None else n)
+    # a block each: the phases, and a hand-off split into its two waits
+    for p in ("map", "verify", "resume"):
+        assert grew(f"read.phase.{p}.n") == n + manifest, p
+    assert abs(grew("read.resume.queue.s") + grew("read.resume.wake.s")
+               - grew("read.phase.resume.s")) < 1e-9
+    assert grew("read.resume.queue.s") > 0 and grew("read.resume.wake.s") > 0
+    # closed with the client: no batch thread outlives it
+    assert not fetcher._threads and fetcher._closed
+
+
+async def test_an_unprimed_reader_hops_a_block_and_never_batches(
+        tmp_path, monkeypatch):
+    def refused(*args, **kw):
+        raise AssertionError("an unprimed reader joined a batch")
+
+    monkeypatch.setattr(BatchFetcher, "fetch", refused)
+    async with MiniCluster(workers=1, base_dir=str(tmp_path),
+                           block_size=BLOCK) as mc:
+        c = mc.client()
+        await c.write_all("/u", b"u" * (3 * BLOCK))
+        before = dict(c.counters)
+        r = await c.open("/u")
+        assert r.primed is None
+        assert bytes(await r.mmap_view(0, r.len)) == b"u" * (3 * BLOCK)
+        await r.close()
+        grew = grown(c.counters, before)
+        assert grew("read.fetch.hops") == 3 == grew("read.phase.resume.n")
+        assert "read.fetch.batched_blocks" not in c.counters
+        assert not c._primed.fetcher._threads
 
 def test_a_list_is_charged_an_item():
     q = AdmissionController()

@@ -97,6 +97,11 @@ async def report_sc_reads(pool: ConnectionPool, addr: str,
     return hdr.get("shm_warm") or {}
 
 
+# what a waiter of a block's fetch is told when that fetch ended without
+# an answer (its task cancelled, an error raised): fetch it yourself
+_AGAIN = object()
+
+
 class _Fetch:
     """One block's fetch, queued in a BatchFetcher or served by it: what
     `FsReader._fetch_shm` is given, the waiter's future and context, and
@@ -450,6 +455,18 @@ class FsReader:
         # _SC_CACHE_CAP FIFO as the fd cache (_drop_local closes both)
         self._shm_sock: dict[int, str] = {}
         self._shm_maps: dict[int, tuple[int, mmap.mmap]] = {}
+        # a block being fetched → the futures of the callers that wait
+        # for that fetch instead of starting their own (`_shm_map`)
+        self._shm_flights: dict[int, list[asyncio.Future]] = {}
+        # a file of several blocks: one range of addresses for all of
+        # them, each block mapped into its place on first use and kept
+        # there (`_file_span`); its entries in _shm_maps have fd -1.
+        # None until first asked for, False for a file of one block
+        self._range = None
+        # offsets of the range's places that hold a block or are being
+        # filled: nothing is mapped over them (`_shm_fetch`) until they
+        # are let go (`SpanMap.hold`)
+        self._slots: set[int] = set()
         # block ids whose shm capability is a WARM export (below-MEM
         # tier; docs/data-plane.md): same protocol, separate accounting
         # (read.shm_warm_hits / read.shm_warm_fallbacks, served_by
@@ -500,6 +517,15 @@ class FsReader:
             return None
         self._last_block_idx = i
         return lb, offset - lb.offset
+
+    def blocks_under(self, offset: int, n: int) -> int:
+        """How many of the file's blocks the range [offset, offset+n)
+        lies in."""
+        import bisect
+        if n <= 0:
+            return 0
+        first = max(0, bisect.bisect_right(self._block_offs, offset) - 1)
+        return bisect.bisect_left(self._block_offs, offset + n) - first
 
     def _pick_loc(self, lb: LocatedBlock):
         return pick_loc(lb, self.fs.client_host)
@@ -591,11 +617,14 @@ class FsReader:
         by a caller keeps the mapping alive past this close (BufferError
         → the mmap object stays open until the last view is released and
         GC finishes it) — eviction can never tear pages out from under a
-        live read. The fd closes either way; the map holds the pages."""
+        live read. The fd closes either way; the map holds the pages.
+        A block of the file's range (fd -1) is let go the same way: its
+        place stays mapped while a view holds it, and nothing is mapped
+        over it until the last view is collected (`SpanMap.hold`)."""
         self._shm_sock.pop(bid, None)
         self._shm_warm.discard(bid)
         ent = self._shm_maps.pop(bid, None)
-        if ent is not None:
+        if ent is not None and ent[0] >= 0:
             self._unmap(*ent)
 
     @staticmethod
@@ -729,23 +758,61 @@ class FsReader:
         self._count("read.shm_warm_fallbacks" if bid in self._shm_warm
                     else "read.shm_fallbacks")
 
-    async def _shm_map(self, lb: LocatedBlock) -> mmap.mmap | None:
-        """The block's shm mapping, fetching + sealing-checking on first
-        use: connect to the worker's SCM_RIGHTS side channel (blocking
-        socket → thread; asyncio can't carry ancillary fds), map the
-        sealed memfd read-only, verify the full block ONCE against the
-        commit-time checksum — after which every read of the block is a
-        pure memory access. All three run on a fetch thread (`_fetch`:
-        a batch's, for a primed reader); the loop compares the checksum
-        it brings back and does everything that touches this reader's
-        state.
+    async def _shm_map(self, lb: LocatedBlock):
+        """The block's shm mapping, fetched on first use (`_shm_fetch`)
+        and kept: every later read of the block is a pure memory access.
+        Single-flight: a caller that finds the block's fetch in flight
+        waits for it and takes what it brings, so however many reads
+        arrive at once a block is granted, mapped and verified once. A
+        block of a file of several blocks lies in the file's range
+        (`_file_span`): what comes back is then its slice of the range
+        (a read-only array), else an `mmap` of the block alone.
         None → caller uses the fd/socket paths."""
         bid = lb.block.id
-        ent = self._shm_maps.get(bid)
-        if ent is not None:
-            return ent[1]
+        while True:
+            ent = self._shm_maps.get(bid)
+            if ent is not None:
+                return ent[1]
+            waiting = self._shm_flights.get(bid)
+            if waiting is None:
+                break
+            fut = asyncio.get_running_loop().create_future()
+            waiting.append(fut)
+            got = await fut
+            if got is not _AGAIN:
+                return got
         if not self.short_circuit or not lb.locs:
             return None
+        self._shm_flights[bid] = waiting = []
+        got = _AGAIN                # the fetch ended without an answer
+        try:
+            got = await self._shm_fetch(lb)
+            return got
+        finally:
+            del self._shm_flights[bid]
+            for fut in waiting:
+                if not fut.done():
+                    fut.set_result(got)
+
+    async def _shm_fetch(self, lb: LocatedBlock):
+        """One block's fetch: connect to the worker's SCM_RIGHTS side
+        channel (blocking socket → thread; asyncio can't carry ancillary
+        fds), map the sealed memfd read-only — on its own, or into its
+        place in the file's range — and verify the full block ONCE
+        against the commit-time checksum. All three run on a fetch
+        thread (`_fetch`: a batch's, for a primed reader); the loop
+        compares the checksum it brings back and does everything that
+        touches this reader's state. Counted: read.block_fetches a
+        block granted, read.blocks_mapped a block kept.
+
+        A block goes into its place in the range only where that place
+        is empty: a place that holds a verified block may lie under
+        views handed out, so nothing is mapped over it until the last
+        of them and this reader have let it go (`SpanMap.hold`), and a
+        block that finds it taken is mapped on its own. A fetch that
+        ends with nothing there a view could have seen (no grant, a
+        failed map, a checksum refused) empties it at once."""
+        bid = lb.block.id
         if bid not in self._local_paths:
             await self._local_path(lb)      # probe captures shm_sock
         spath = self._shm_sock.get(bid)
@@ -754,43 +821,83 @@ class FsReader:
         want, algo = self._block_crc.get(bid, (None, None))
         if not self.verify:
             algo = None
+        span = self._file_span()
+        into = None
+        if span is not None and lb.offset % mmap.PAGESIZE == 0 \
+                and lb.offset + lb.block.len <= span.nbytes \
+                and lb.offset not in self._slots:
+            # held from here on, also by a fetch that is cancelled while
+            # its thread may still map there
+            self._slots.add(lb.offset)
+            into = (span, lb.offset)
         spent: dict[str, float] = {}
         t_submit = time.perf_counter()
         try:
             fd, length, mm, got, copied = await self._fetch(
-                spath, lb, algo, spent)
+                spath, lb, algo, spent, into)
         except (LookupError, OSError, ValueError) as e:
             # worker dropped the export / channel gone: stop retrying
             # this block, serve it through fd/socket instead
             log.debug("shm fetch for block %d failed: %s", bid, e)
+            if into is not None:
+                self._slots.discard(lb.offset)
             self._shm_sock.pop(bid, None)
             self._shm_fallback(bid)
             return None
         finally:
             self._count_fetch(spent, t_submit)
+        self._count("read.block_fetches")
         if got is not None:
             self._count_verify(length, copied)
-        other = self._shm_maps.get(bid)
-        if other is not None:
-            # lost a concurrent-fetch race: keep the first mapping
-            self._unmap(fd, mm)
-            return other[1]
+        if into is not None:
+            os.close(fd)             # the range's mapping holds the pages
+            fd = -1
         if mm is None:
             # a grant of another length than the block's is a stale
             # export: stop asking for it. A map that failed may be
             # tried again
-            os.close(fd)
+            if into is not None:
+                self._slots.discard(lb.offset)
+            else:
+                os.close(fd)
             if length != lb.block.len or length <= 0:
                 self._shm_sock.pop(bid, None)
             self._shm_fallback(bid)
             return None
         if got is not None and got != want:
             self._sc_corrupt(lb)    # flags the replica, drops the caches
-            self._unmap(fd, mm)
+            if into is None:
+                self._unmap(fd, mm)
+            else:
+                # no view lies over a place filled by this fetch
+                span.unmap(lb.offset, length)
+                self._slots.discard(lb.offset)
             self._shm_fallback(bid)
             return None
+        if into is not None:
+            mm = span.hold(lb.offset, length, self._slots.discard)
         self._shm_maps[bid] = (fd, mm)
+        self._count("read.blocks_mapped")
         return mm
+
+    def _file_span(self):
+        """The range of addresses that this reader maps the blocks of a
+        file of several blocks into, side by side (a `SpanMap` the size
+        of the file's blocks, made on first use; addresses only, no
+        memory behind a place until its block is mapped there): a read
+        that straddles blocks or spans many is then a slice of mappings
+        already held. None for a file of one block."""
+        if self._range is None:
+            self._range = False
+            locs = self.blocks.block_locs
+            if len(locs) > 1:
+                from curvine_tpu.client.spanmap import SpanMap
+                try:
+                    self._range = SpanMap(locs[-1].offset
+                                          + locs[-1].block.len)
+                except (OSError, ValueError) as e:
+                    log.debug("no address range for %s: %s", self.path, e)
+        return self._range or None
 
     def _count_fetch(self, spent: dict, t_submit: float) -> None:
         """What a fetch thread stamped into `spent`, counted here, on
@@ -909,6 +1016,7 @@ class FsReader:
         lb, block_off = located
         if block_off + n > lb.block.len:
             return await self._span_view(offset, n)
+        kept = lb.block.id in self._shm_maps
         with self._span("shm_view", detail=True, block=lb.block.id,
                         n=n) as sp:
             mm = await self._shm_map(lb)
@@ -916,6 +1024,8 @@ class FsReader:
                 sp.set_attr("served_by", "none" if mm is None else
                             "shm_warm" if lb.block.id in self._shm_warm
                             else "shm")
+                if kept:
+                    sp.set_attr("kept", True)
         if mm is None:
             return None
         import numpy as np
@@ -954,33 +1064,45 @@ class FsReader:
         return lbs
 
     async def _span_view(self, offset: int, n: int):
-        """`_shm_view` for a range that spans blocks: the same
-        algorithm — map the sealed export, verify it once, hand out a
-        slice — over each block of the range, the blocks fetched
-        together on fetch threads and mapped side by side in one
-        `SpanMap`. The loop only compares the checksums the threads
-        bring back. All blocks or nothing: None if any of them is not
-        served by the shm rung, and then no byte of the range has
-        reached the caller (a block that failed its checksum is flagged
-        as on the one-block path). The range belongs to the views
-        handed out, not to this reader: it stays mapped until the last
-        of them is collected, whatever is closed or evicted before."""
+        """`_shm_view` for a range that spans blocks: a slice of the
+        file's range (`_file_span`) once every block under it is mapped
+        there and verified (`_shm_map` each, all at once; a block mapped
+        before, by any read of this reader, is not fetched again). The
+        loop only compares the checksums the fetch threads bring back.
+        The rule is the range's own (`_span_blocks`), whatever the rest
+        of the file holds. All blocks or nothing: None if any of them is
+        not served by the shm rung or is not in its place in the range
+        (mapped on its own), and then no byte of the range has reached
+        the caller (a block that failed its checksum is flagged and
+        unmapped as on the one-block path; the blocks that passed stay
+        held). A block's place belongs to the views over it as much as
+        to this reader: it stays mapped until the last of them is
+        collected, whatever is closed or evicted before."""
         if not self.short_circuit:
             return None
         lbs = self._span_blocks(offset, n)
-        if lbs is None:
+        span = self._file_span() if lbs is not None else None
+        if span is None:
             return None
+        kept = all(lb.block.id in self._shm_maps for lb in lbs)
         with self._span("shm_view", detail=True, block=lbs[0].block.id,
                         n=n, blocks=len(lbs)) as sp:
-            whole = await self._span_map(lbs)
+            got = await asyncio.gather(*map(self._shm_map, lbs),
+                                       return_exceptions=True)
+            for res in got:
+                if isinstance(res, BaseException):
+                    raise res
+            ok = all(m is not None and self._shm_maps.get(
+                lb.block.id, (0,))[0] < 0 for lb, m in zip(lbs, got))
             if sp is not None:
-                sp.set_attr("served_by", "none" if whole is None else
+                sp.set_attr("served_by", "none" if not ok else
                             "+".join(sorted({
                                 "shm_warm" if lb.block.id in self._shm_warm
                                 else "shm" for lb in lbs})))
-        if whole is None:
+                if kept:
+                    sp.set_attr("kept", True)
+        if not ok:
             return None
-        start = offset - lbs[0].offset
         for lb in lbs:
             lo = max(offset, lb.offset)
             hi = min(offset + n, lb.offset + lb.block.len)
@@ -990,70 +1112,7 @@ class FsReader:
         self._count("read.span_views")
         self._count("read.span_view_blocks", len(lbs))
         self._count("read.span_view_bytes", n)
-        return whole[start:start + n]
-
-    async def _span_map(self, lbs: list):
-        """The blocks `lbs` mapped side by side and verified → the
-        read-only array over all of them, or None."""
-        from curvine_tpu.client.spanmap import SpanMap
-        # the probes (GET_BLOCK_INFO: shm_sock, checksum) together too
-        await asyncio.gather(*(self._local_path(lb) for lb in lbs
-                               if lb.block.id not in self._local_paths))
-        spaths = [self._shm_sock.get(lb.block.id) for lb in lbs]
-        if None in spaths:
-            return None
-        wants = [self._block_crc.get(lb.block.id, (None, None))
-                 if self.verify else (None, None) for lb in lbs]
-        try:
-            span = SpanMap(sum(lb.block.len for lb in lbs))
-        except OSError as e:
-            log.debug("no address range for %d blocks: %s", len(lbs), e)
-            return None
-
-        async def fetch(lb: LocatedBlock, spath: str, want: tuple):
-            spent: dict[str, float] = {}
-            t_submit = time.perf_counter()
-            try:
-                return await self._fetch(spath, lb, want[1], spent,
-                                         (span, lb.offset - lbs[0].offset))
-            finally:
-                self._count_fetch(spent, t_submit)
-
-        fetched = await asyncio.gather(*map(fetch, lbs, spaths, wants),
-                                       return_exceptions=True)
-        ok, raised = True, None
-        for lb, (want, _algo), res in zip(lbs, wants, fetched):
-            bid = lb.block.id
-            if isinstance(res, (LookupError, OSError, ValueError)):
-                # as in _shm_map: the export or the channel is gone
-                log.debug("shm fetch for block %d failed: %s", bid, res)
-                self._shm_sock.pop(bid, None)
-                self._shm_fallback(bid)
-                ok = False
-                continue
-            if isinstance(res, BaseException):
-                raised = res
-                continue
-            fd, length, mm, got, copied = res
-            os.close(fd)             # the mapping holds the pages
-            if got is not None:
-                self._count_verify(length, copied)
-            if mm is None:
-                # a stale export (another length), or the map failed
-                if length != lb.block.len:
-                    self._shm_sock.pop(bid, None)
-                self._shm_fallback(bid)
-                ok = False
-            elif got is not None and got != want:
-                self._sc_corrupt(lb)  # flags the replica, drops the caches
-                self._shm_fallback(bid)
-                ok = False
-        if ok and raised is None:
-            return span.view()
-        span.close()                 # no view of it was handed out
-        if raised is not None:
-            raise raised
-        return None
+        return span.window(offset, n, got)
 
     def _alloc_out(self, n: int):
         """Caller-visible read destination: page-aligned mmap-backed
@@ -1756,7 +1815,7 @@ class FsReader:
             # zero RPCs and zero syscalls
             self._note_sc_read(lb.block.id, n)
             self._shm_hit(lb.block.id)
-            return mm[block_off:block_off + n]
+            return bytes(mm[block_off:block_off + n])
         fd = await self._local_fd(lb)
         if fd is not None:
             base = self._local_offs.get(lb.block.id, 0)
@@ -1934,3 +1993,4 @@ class FsReader:
         self._local_fds.clear()
         for bid in list(self._shm_maps):
             self._drop_shm(bid)
+        self._range = None            # the views handed out keep it mapped

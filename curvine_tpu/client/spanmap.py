@@ -80,3 +80,55 @@ class SpanMap:
         _mmap(self._addr + at, length, mmap.PROT_READ,
               flags | _MAP_FIXED, fd)
         return self.view()[at:at + length]
+
+    def unmap(self, at: int, length: int) -> None:
+        """Take back what was mapped at byte `at` for `length` bytes:
+        that part of the range is reserved again, no memory behind it
+        (a block that failed its checksum leaves nothing readable)."""
+        if at % mmap.PAGESIZE or at < 0 or length <= 0 \
+                or at + length > self.nbytes:
+            raise ValueError(f"unmap [{at}, {at + length}) of a span of "
+                             f"{self.nbytes} bytes")
+        _mmap(self._addr + at, length, _PROT_NONE,
+              mmap.MAP_PRIVATE | mmap.MAP_ANONYMOUS | _MAP_FIXED, -1)
+
+    def hold(self, at: int, length: int, free):
+        """What `map` placed at byte `at`, as a read-only array that
+        keeps it there: when the last array over it is collected, that
+        part of the range is reserved again (`unmap`) and `free(at)` is
+        told the place is empty. A place that no array holds any more
+        can be mapped into again; one that an array holds is never."""
+        place = _Window(self._addr + at, length, self)
+        weakref.finalize(place, self._release, at, length, free)
+        return _array(place)
+
+    def _release(self, at: int, length: int, free) -> None:
+        self.unmap(at, length)
+        free(at)
+
+    def window(self, at: int, n: int, held: list):
+        """`n` bytes from byte `at` of the range as one read-only array
+        that keeps `held` — the arrays `hold` gave for the places under
+        it — alive, and so mapped, as long as it is."""
+        return _array(_Window(self._addr + at, n, (self, tuple(held))))
+
+
+class _Window:
+    """`n` bytes at address `addr` as numpy sees them (read-only); what
+    it `keeps` lives as long as an array over it does."""
+
+    __slots__ = ("_iface", "keeps", "__weakref__")
+
+    def __init__(self, addr: int, n: int, keeps):
+        self._iface = {"version": 3, "shape": (n,), "typestr": "|u1",
+                       "data": (addr, True)}
+        self.keeps = keeps
+
+    @property
+    def __array_interface__(self) -> dict:
+        return self._iface
+
+
+def _array(window: _Window):
+    import numpy as np
+    return np.asarray(window)

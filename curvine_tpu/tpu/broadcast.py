@@ -10,8 +10,11 @@ full owning copy of the checkpoint — and then placed leaf by leaf, each
 chip receiving its own shard only; the layout is checked against the
 manifest before the first tensor is opened.
 
-Checkpoint format: a msgpack manifest ``<name>.json`` + raw tensor files,
-or a single .npz — both cache-native (written/read through CurvineClient).
+Checkpoint formats: a JSON manifest ``manifest.json`` + one raw file a
+tensor (what ``save_checkpoint`` writes), and the Hugging Face
+safetensors layout — an index beside shard files of many tensors each,
+read as byte ranges of the shards (``load_safetensors``). Both are
+read through CurvineClient.
 """
 
 from __future__ import annotations
@@ -23,10 +26,12 @@ import time
 from contextlib import asynccontextmanager
 
 import jax
+import ml_dtypes
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from curvine_tpu.client import CurvineClient
+from curvine_tpu.common import errors as err
 from curvine_tpu.obs.trace import Timed
 
 log = logging.getLogger(__name__)
@@ -146,7 +151,7 @@ async def load_checkpoint(client: CurvineClient, path: str,
     async with _restore(client, path):
         manifest, skel, treedef = await _load_manifest(client, path,
                                                        allow_pickle)
-        await _prime(client, path, manifest)
+        await _prime(client, path, (t["name"] for t in manifest))
         flat = await asyncio.gather(*(
             _load_tensor(client, path, t, placer) for t in manifest))
         if placer is not None:
@@ -158,6 +163,217 @@ def _unflatten(skel, treedef, flat):
     if skel is not None:
         return _tree_build(skel, flat)
     return jax.tree.unflatten(treedef, flat)
+
+
+# ------------------------------------------------ Hugging Face safetensors
+
+SAFETENSORS_INDEX = "model.safetensors.index.json"
+
+# safetensors' dtype names → numpy's (bfloat16 and the 8-bit floats as
+# JAX has them, through ml_dtypes)
+_ST_DTYPES = {
+    "BOOL": np.bool_, "U8": np.uint8, "I8": np.int8, "U16": np.uint16,
+    "I16": np.int16, "U32": np.uint32, "I32": np.int32, "U64": np.uint64,
+    "I64": np.int64, "F16": np.float16, "BF16": ml_dtypes.bfloat16,
+    "F32": np.float32, "F64": np.float64,
+    "F8_E4M3": ml_dtypes.float8_e4m3fn, "F8_E5M2": ml_dtypes.float8_e5m2,
+}
+
+
+async def load_safetensors(client: CurvineClient, root: str, placer=None,
+                           select=None) -> dict:
+    """A checkpoint in the Hugging Face safetensors layout, as one
+    restore: the index ``model.safetensors.index.json`` (``weight_map``:
+    tensor name → shard file) beside its shard files, each an 8-byte
+    little-endian header length, a JSON header (each tensor's
+    ``dtype``, ``shape`` and ``data_offsets`` from the header's end) and
+    the tensors' bytes. → name → array for every tensor of the index
+    that ``select(name)`` keeps (all, with no ``select``), in the index's
+    order; with ``placer`` each is placed as `load_checkpoint` places a
+    file, with none an owning host copy.
+
+    First the index is read (span ``ckpt.index``; ``ckpt.index.s`` /
+    ``.n``), its shards primed and each opened once, and every shard's
+    header read and checked (span ``ckpt.headers``; ``ckpt.headers.s``
+    / ``.n``; the first read of a shard fetches its first block, which
+    its tensors then find held): a shard the index names that is not
+    there, a tensor whose bytes lie outside its file or over another
+    tensor's, a dtype this reader does not know, or a tensor the index
+    names and its shard's header lacks fails the restore whole, as one
+    ValueError naming the shard and the tensor, before any tensor is
+    placed. Every selected tensor
+    is a view of its byte range inside its shard (``mmap_view``: where
+    the shm rung serves the blocks, a slice of mappings the shard's
+    reader holds, each block granted, mapped and verified once however
+    many tensors lie in it or cross it), placed as it lands (span
+    ``ckpt.tensor``, attrs shard, offset, bytes, blocks, served_by;
+    ``ckpt.place``); then the ready sweep. ``ckpt.bytes`` counts the
+    bytes placed, not the shards'."""
+    import asyncio
+    c = client.counters
+    readers: dict[str, object] = {}
+    async with _restore(client, root):
+        try:
+            with Timed(c, "ckpt.index",
+                       client.tracer.span("ckpt.index", detail=True)):
+                weight_map = await _st_index(client, root)
+            shards = sorted(set(weight_map.values()))
+            await _prime(client, root, shards)
+            for shard in shards:
+                readers[shard] = await _st_open(client, root, shard,
+                                                weight_map)
+            with Timed(c, "ckpt.headers",
+                       client.tracer.span("ckpt.headers", detail=True)):
+                headers = await _gather_all(
+                    _st_header(readers[s], s) for s in shards)
+                where = _st_locate(weight_map, dict(zip(shards, headers)))
+            names = [n for n in weight_map if select is None or select(n)]
+            flat = await _gather_all(
+                _load_range(client, readers[weight_map[n]], weight_map[n],
+                            n, where[n], placer) for n in names)
+            if placer is not None:
+                flat = _wait_ready(client, flat)
+            c["ckpt.bytes"] = c.get("ckpt.bytes", 0) + sum(
+                where[n][3] - where[n][2] for n in names)
+        finally:
+            for reader in readers.values():
+                await reader.close()
+    return dict(zip(names, flat))
+
+
+async def load_safetensors_to_device(client: CurvineClient, root: str,
+                                     device, select=None) -> dict:
+    """`load_safetensors` onto one device: each selected tensor's
+    transfer dispatched as its bytes land."""
+    return await load_safetensors(
+        client, root, placer=lambda a: jax.device_put(a, device),
+        select=select)
+
+
+async def _gather_all(aws) -> list:
+    """`asyncio.gather` that lets every awaitable end before the first
+    error is raised: nothing of a failed restore is still reading when
+    its readers close."""
+    import asyncio
+    got = await asyncio.gather(*aws, return_exceptions=True)
+    for res in got:
+        if isinstance(res, BaseException):
+            raise res
+    return got
+
+
+async def _st_index(client: CurvineClient, root: str) -> dict:
+    """The index's ``weight_map``: tensor name → shard file name."""
+    path = f"{root}/{SAFETENSORS_INDEX}"
+    raw = json.loads(await _read_all(client, path))
+    wm = raw.get("weight_map") if isinstance(raw, dict) else None
+    if not isinstance(wm, dict) or not all(
+            isinstance(v, str) and v and "/" not in v for v in wm.values()):
+        raise ValueError(f"{path}: no weight_map of tensor name → shard "
+                         f"file beside it")
+    return wm
+
+
+async def _st_open(client: CurvineClient, root: str, shard: str,
+                   weight_map: dict):
+    try:
+        return await client.open(f"{root}/{shard}")
+    except err.FileNotFound as e:
+        tensor = next(n for n, s in weight_map.items() if s == shard)
+        raise ValueError(f"safetensors shard {shard!r} of {root!r} is not "
+                         f"there (the index names it for tensor "
+                         f"{tensor!r}, among others)") from e
+
+
+async def _st_header(reader, shard: str) -> tuple[int, int, dict]:
+    """(where the tensors' bytes start, the file's length, the parsed
+    header) of one shard, read through its reader."""
+    head = await reader.pread(0, 8)
+    n = int.from_bytes(head, "little") if len(head) == 8 else -1
+    if not 0 < n <= reader.len - 8:
+        raise ValueError(f"safetensors shard {shard!r}: a header length "
+                         f"of {n} bytes in a file of {reader.len}")
+    try:
+        header = json.loads(await reader.pread(8, n))
+    except ValueError as e:
+        raise ValueError(f"safetensors shard {shard!r}: its header is "
+                         f"not JSON: {e}") from e
+    if not isinstance(header, dict):
+        raise ValueError(f"safetensors shard {shard!r}: its header is "
+                         f"not a JSON object")
+    return 8 + n, reader.len, header
+
+
+def _st_locate(weight_map: dict, headers: dict) -> dict:
+    """tensor name → (dtype, shape, first byte, end) in its shard file,
+    for every tensor the index names, every shard's header checked
+    whole: dtype known, shape and bytes agreeing, bytes inside the file
+    and over no other tensor's."""
+    entries = {}
+    for shard, (start, length, header) in headers.items():
+        spans = []
+        for name, t in header.items():
+            if name == "__metadata__":
+                continue
+
+            def refuse(why: str):
+                return ValueError(f"safetensors shard {shard!r}, tensor "
+                                  f"{name!r}: {why}")
+
+            try:
+                dtype = _ST_DTYPES.get(t["dtype"])
+                shape = tuple(int(d) for d in t["shape"])
+                begin, end = (int(x) for x in t["data_offsets"])
+            except (KeyError, TypeError, ValueError) as e:
+                raise refuse(f"a header entry without dtype, shape and "
+                             f"data_offsets ({e})") from e
+            if dtype is None:
+                raise refuse(f"dtype {t['dtype']!r} is not one this "
+                             f"reader knows")
+            dtype = np.dtype(dtype)
+            if not 0 <= begin <= end or start + end > length:
+                raise refuse(f"bytes [{begin}, {end}) after the header lie "
+                             f"outside the file ({length - start} there)")
+            if min(shape, default=0) < 0 or \
+                    end - begin != math.prod(shape) * dtype.itemsize:
+                raise refuse(f"{end - begin} bytes for shape {list(shape)} "
+                             f"of {t['dtype']}")
+            entries[(shard, name)] = (dtype, shape, start + begin,
+                                      start + end)
+            spans.append((begin, end, name))
+        spans.sort()
+        for (_, prev_end, prev), (begin, _, name) in zip(spans, spans[1:]):
+            if begin < prev_end:
+                raise ValueError(f"safetensors shard {shard!r}, tensor "
+                                 f"{name!r}: its bytes overlap tensor "
+                                 f"{prev!r}'s")
+    out = {}
+    for name, shard in weight_map.items():
+        t = entries.get((shard, name))
+        if t is None:
+            raise ValueError(f"safetensors shard {shard!r}, tensor "
+                             f"{name!r}: the index names it and the "
+                             f"shard's header does not")
+        out[name] = t
+    return out
+
+
+async def _load_range(client: CurvineClient, reader, shard: str, name: str,
+                      t: tuple, place):
+    """One tensor as a byte range of its shard, from the cache to where
+    ``place`` puts it, under the span ``ckpt.tensor``: a view where the
+    shm rung serves its blocks, else a copy through ``read_range``."""
+    dtype, shape, begin, end = t
+    with client.tracer.span("ckpt.tensor", attrs={
+            "name": name, "shard": shard, "offset": begin},
+            detail=True) as sp:
+        arr = await reader.mmap_view(begin, end - begin)
+        if arr is None:
+            arr = await reader.read_range(begin, end - begin)
+        sp.set_attr("blocks", reader.blocks_under(begin, end - begin))
+        sp.set_attr("served_by", reader.served_by())
+        sp.set_attr("bytes", arr.nbytes)
+        return _placed(client, arr, dtype, shape, place)
 
 
 @asynccontextmanager
@@ -179,11 +395,12 @@ async def _restore(client: CurvineClient, path: str):
     c["ckpt.restores"] = c.get("ckpt.restores", 0) + 1
 
 
-async def _prime(client: CurvineClient, path: str, manifest: list) -> None:
-    """A restore knows every file it will open once it has the manifest:
-    name them to the client at once, so that locations, block info and
-    read reports cross once a peer and not once a tensor."""
-    await client.prime([f"{path}/{t['name']}" for t in manifest])
+async def _prime(client: CurvineClient, path: str, names) -> None:
+    """A restore knows every file it will open once it has the manifest
+    (or the index): name them to the client at once, so that locations,
+    block info and read reports cross once a peer and not once a
+    file."""
+    await client.prime([f"{path}/{name}" for name in names])
 
 
 async def _load_tensor(client: CurvineClient, path: str, t: dict, place,
@@ -215,17 +432,22 @@ async def _load_tensor(client: CurvineClient, path: str, t: dict, place,
             sp.set_attr("blocks", len(reader.blocks.block_locs))
             sp.set_attr("served_by", reader.served_by())
         sp.set_attr("bytes", arr.nbytes)
-        arr = arr.view(np.dtype(t["dtype"])).reshape(t["shape"])
-        if place is None:
-            out = _host_copy(client, arr)
-        else:
-            with Timed(client.counters, "ckpt.place",
-                       client.tracer.span("ckpt.place", detail=True),
-                       cpu=True):
-                out = place(arr)
+        out = _placed(client, arr, np.dtype(t["dtype"]), t["shape"], place)
         if reader is not None:
             await reader.close()
         return out
+
+
+def _placed(client: CurvineClient, arr: np.ndarray, dtype, shape, place):
+    """A tensor's bytes as its dtype and shape, handed to ``place``
+    (timed as ckpt.place: an async dispatch) or, with no ``place``,
+    copied into host memory that outlives the reader."""
+    arr = arr.view(dtype).reshape(shape)
+    if place is None:
+        return _host_copy(client, arr)
+    with Timed(client.counters, "ckpt.place",
+               client.tracer.span("ckpt.place", detail=True), cpu=True):
+        return place(arr)
 
 
 def _host_copy(client: CurvineClient, arr: np.ndarray) -> np.ndarray:
@@ -329,7 +551,7 @@ async def _distribute_sharded(client: CurvineClient, path: str, mesh: Mesh,
                                                        allow_pickle)
         shardings = _leaf_shardings(path, manifest, skel, treedef, mesh,
                                     spec_tree)
-        await _prime(client, path, manifest)
+        await _prime(client, path, (t["name"] for t in manifest))
         host = await asyncio.gather(*(
             _load_tensor(client, path, t, None) for t in manifest))
         flat, once, placed = [], 0, 0
@@ -409,7 +631,7 @@ async def _distribute_tree(client: CurvineClient, path: str, mesh: Mesh,
     async with _restore(client, path):
         manifest, skel, treedef = await _load_manifest(client, path,
                                                        allow_pickle)
-        await _prime(client, path, manifest)
+        await _prime(client, path, (t["name"] for t in manifest))
         counters = client.counters
         devs = mesh.devices.reshape(-1)
         sched = ici_plane.broadcast_schedule(

@@ -164,8 +164,7 @@ async def test_shm_fd_lru_churn_no_leak(tmp_path):
         async def churn(rounds: int) -> None:
             for i in range(rounds):
                 off = (i % n_blocks) * blk
-                # two at once: each first read of a block is a race of
-                # two fetches, and the loser's mapping must go too
+                # two at once: the second waits for the first's fetch
                 for got in await asyncio.gather(r.pread_view(off, 4096),
                                                 r.pread_view(off, 4096)):
                     assert bytes(got) == payload[off:off + 4096]
@@ -288,9 +287,10 @@ async def test_shm_corrupt_export_is_refused(tmp_path, monkeypatch):
 
 
 async def test_shm_concurrent_first_reads_share_one_mapping(tmp_path):
-    """Concurrent first reads of one block each fetch, map and verify
-    on a thread of their own; one mapping is kept, the losers unmap and
-    close theirs, and nothing outlives close()."""
+    """Concurrent first reads of one block share one fetch: the first
+    grants, maps and verifies it on a fetch thread, the others wait for
+    that fetch and take its mapping; one mapping is kept, and nothing
+    outlives close()."""
     conf = ClusterConf()
     conf.data_dir = str(tmp_path)
     async with MiniCluster(workers=1, conf=conf, base_dir=str(tmp_path),
@@ -312,7 +312,9 @@ async def test_shm_concurrent_first_reads_share_one_mapping(tmp_path):
         maps = await asyncio.gather(*(r._shm_map(lb) for _ in range(6)))
         assert all(m is maps[0] for m in maps) and maps[0] is not None
         assert list(r._shm_maps) == [lb.block.id]
-        assert c.counters.get("read.phase.grant.n", 0) == 6   # a real race
+        assert c.counters.get("read.phase.grant.n", 0) == 1   # one fetch
+        assert c.counters["read.block_fetches"] == 1
+        assert c.counters["read.blocks_mapped"] == 1
         assert c.counters.get("read.verify.copied_bytes", 0) == 0
         assert bytes(maps[0][:4096]) == payload[:4096]
         del maps
@@ -438,14 +440,26 @@ async def test_span_view_is_one_zero_copy_view(tmp_path, offset, n):
                  if s["op"] == "shm_view"]
         assert sp["attrs"]["blocks"] == k
         assert sp["attrs"]["served_by"] == "shm"
-        # each block is mapped once, and only in the range
-        assert _memfd_maps() == k and not r._shm_maps
+        # each block is mapped once, in its place in the file's range,
+        # and the reader holds it there
+        under = r.blocks.block_locs[offset // MB:offset // MB + k]
+        assert _memfd_maps() == k
+        assert set(r._shm_maps) == {lb.block.id for lb in under}
+        assert grew("read.block_fetches") == k == grew("read.blocks_mapped")
+        assert "kept" not in sp["attrs"]
         assert mc.workers[0].metrics.counters.get("bytes.read", 0) == 0
-        # read_range goes through the same door
+        # read_range goes through the same door, and finds every block
+        # of the range held: nothing is granted, mapped or hashed again
         again = await r.read_range(offset, n, parallel=4)
         assert not again.flags.writeable
         assert bytes(again) == payload[offset:offset + n]
         assert grew("read.span_views") == 2
+        assert grew("read.block_fetches") == k
+        assert grew("read.verify.bytes") == covered
+        assert _memfd_maps() == k
+        (sp,) = [s for s in c.tracer.store.drain(4096)
+                 if s["op"] == "shm_view"]
+        assert sp["attrs"]["kept"] is True
         del view, again
         await r.close()
         await c.close()
@@ -502,8 +516,10 @@ async def test_span_view_outlives_close_and_eviction_no_leak(tmp_path):
 async def test_span_view_refuses_a_corrupt_block(tmp_path, monkeypatch):
     """One export of the four differs from its commit-time checksum: no
     view (and no byte) of the range reaches the caller, the replica is
-    flagged, the range is unmapped, and `read_all` serves the right
-    bytes through the verified remote path."""
+    flagged, its bytes are unmapped (the three good blocks stay held by
+    the reader until it closes), and `read_all` serves the right bytes:
+    the good blocks from what is held, the bad one through the verified
+    remote path."""
     from curvine_tpu.rpc import RpcCode
     async with _span_cluster(tmp_path) as mc:
         c = mc.client()
@@ -539,7 +555,8 @@ async def test_span_view_refuses_a_corrupt_block(tmp_path, monkeypatch):
         assert bad_bid not in r._shm_sock
         assert r._local_paths[bad_bid] is None
         gc.collect()
-        assert _memfd_maps() == 0
+        assert _memfd_maps() == 3 and bad_bid not in r._shm_maps
+        assert len(r._shm_maps) == 3
         assert not [fd for fd in os.listdir("/proc/self/fd")
                     if "cv-test-bad" in _fd_target(fd)]
         assert c.counters.get("read.checksum_mismatch", 0) == 1
@@ -548,17 +565,22 @@ async def test_span_view_refuses_a_corrupt_block(tmp_path, monkeypatch):
         assert await r.read_all() == payload
         assert c.counters.get("read.checksum_mismatch", 0) == 1
         assert mc.workers[0].metrics.counters.get("bytes.read", 0) == MB
+        assert c.counters["read.block_fetches"] == 4
+        assert c.counters["read.blocks_mapped"] == 3
         await asyncio.sleep(0.05)            # the report is fire-and-forget
         assert [m["block_ids"] for m in reported] == [[bad_bid]]
         await r.close()
+        gc.collect()
+        assert _memfd_maps() == 0
         await c.close()
 
 
 @pytest.mark.parametrize("case", ["hole", "shm_off", "grant_fails",
                                   "stale_grant"])
 async def test_span_view_falls_back_whole(tmp_path, monkeypatch, case):
-    """Anything but four shm-served blocks in a row: None, nothing left
-    mapped, and the caller's `read_all` returns the file."""
+    """Anything but four shm-served blocks in a row: None, nothing held
+    but the blocks that were served (kept by the reader for its next
+    read), and the caller's `read_all` returns the file."""
     conf = ClusterConf()
     conf.worker.shm_reads = case != "shm_off"
     async with _span_cluster(tmp_path, conf, 256 * 1024) as mc:
@@ -584,7 +606,9 @@ async def test_span_view_falls_back_whole(tmp_path, monkeypatch, case):
         assert await r.mmap_view(0, r.len) is None
         assert r.served_by() == "none"
         gc.collect()
-        assert _memfd_maps() == 0 and not r._shm_maps
+        held = 3 if case in ("grant_fails", "stale_grant") else 0
+        assert _memfd_maps() == len(r._shm_maps) == held
+        assert victim not in r._shm_maps
         assert c.counters.get("read.span_views", 0) == 0
         assert c.counters.get("read.zero_copy_bytes", 0) == 0
         if case in ("grant_fails", "stale_grant"):
@@ -599,6 +623,86 @@ async def test_span_view_falls_back_whole(tmp_path, monkeypatch, case):
             assert _export_fds() == len(mc.workers[0].shm)
         monkeypatch.setattr(wshm, "fetch_block_fd", real_fetch)
         assert await r.read_all() == payload
+        await r.close()
+        await c.close()
+
+
+async def test_a_held_place_is_never_mapped_over(tmp_path, monkeypatch):
+    """A block the reader forgot (its probe FIFO, a stale probe) while
+    views still lie over its place in the file's range: fetched again,
+    it is mapped on its own, so a refused checksum takes nothing from
+    the views, whose bytes stay as they were. Once the last view is
+    collected the place is empty and takes the block again."""
+    blk = 256 * 1024
+    async with _span_cluster(tmp_path, block_size=blk) as mc:
+        c = mc.client()
+        payload = os.urandom(4 * blk)
+        await c.write_all("/shm/held.bin", payload)
+        r = await c.open("/shm/held.bin")
+        lb = r.blocks.block_locs[1]
+        one = await r.mmap_view(blk + 10, 100)             # inside block 1
+        both = await r.mmap_view(2 * blk - 50, 100)        # straddles 1|2
+        assert c.counters["read.block_fetches"] == 2
+        r._drop_local(lb.block.id)
+        assert lb.block.id not in r._shm_maps
+        real_fetch = wshm.fetch_block_fd
+
+        def tampered(sock_path, block_id, timeout=5.0):
+            fd, n = real_fetch(sock_path, block_id, timeout)
+            if block_id != lb.block.id:
+                return fd, n
+            data = bytearray(os.pread(fd, n, 0))
+            os.close(fd)
+            data[7] ^= 0x01
+            bad = os.memfd_create("cv-test-bad")
+            os.write(bad, data)
+            return bad, n
+
+        monkeypatch.setattr(wshm, "fetch_block_fd", tampered)
+        assert await r.mmap_view(blk + 10, 100) is None
+        assert c.counters["read.block_fetches"] == 3
+        assert c.counters["read.checksum_mismatch"] == 1
+        gc.collect()
+        assert bytes(one) == payload[blk + 10:blk + 110]
+        assert bytes(both) == payload[2 * blk - 50:2 * blk + 50]
+        monkeypatch.setattr(wshm, "fetch_block_fd", real_fetch)
+        r._drop_local(lb.block.id)                # forget the refusal
+        del one, both
+        gc.collect()
+        assert _memfd_maps() == 1                 # block 2, held by r
+        again = await r.mmap_view(2 * blk - 50, 100)
+        assert bytes(again) == payload[2 * blk - 50:2 * blk + 50]
+        assert c.counters["read.span_views"] == 2
+        assert c.counters["read.block_fetches"] == 4
+        del again
+        await r.close()
+        gc.collect()
+        assert _memfd_maps() == 0
+        await c.close()
+
+
+async def test_a_span_view_follows_the_rule_of_its_own_blocks(tmp_path):
+    """The rule is the range's, not the file's: in a file of more blocks
+    than the probe cache holds, a view over two of them is one zero-copy
+    view all the same, and only a view over more blocks than that falls
+    back to a copy."""
+    blk = 256 * 1024
+    async with _span_cluster(tmp_path, block_size=blk) as mc:
+        c = mc.client()
+        payload = os.urandom(6 * blk)
+        await c.write_all("/shm/rule.bin", payload)
+        r = await c.open("/shm/rule.bin")
+        r._SC_CACHE_CAP = 3                  # fewer than the file's blocks
+        for at in (blk - 50, 5 * blk - 50):  # straddle 0|1 and 4|5
+            view = await r.mmap_view(at, 100)
+            assert bytes(view) == payload[at:at + 100]
+        assert c.counters["read.span_views"] == 2
+        assert c.counters["read.zero_copy_bytes"] == 200
+        assert await r.mmap_view(blk - 50, 3 * blk) is None    # 4 blocks
+        got = await r.read_range(blk - 50, 3 * blk)
+        assert bytes(got) == payload[blk - 50:4 * blk - 50]
+        assert c.counters["read.span_views"] == 2
+        del view
         await r.close()
         await c.close()
 

@@ -3,8 +3,9 @@
 The reference's "LLM model distribution acceleration" use case
 (README.md Case 3): pull checkpoint bytes once from the cache (warmed from
 S3 by a load job) and fan them out to all devices. Replicated params are
-dispatched to the mesh tensor by tensor as their views land (device_put
-with a replicated NamedSharding, no host copy). Params restored under a
+dispatched tensor by tensor as their views land, no host copy: each
+crosses the host link to one chip and is copied chip to chip onto the
+others (on a mesh this process addresses whole). Params restored under a
 layout (``spec_tree``) are first loaded whole into host memory — one
 full owning copy of the checkpoint — and then placed leaf by leaf, each
 chip receiving its own shard only; the layout is checked against the
@@ -19,6 +20,7 @@ read through CurvineClient.
 
 from __future__ import annotations
 
+import asyncio
 import json
 import logging
 import math
@@ -32,7 +34,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from curvine_tpu.client import CurvineClient
 from curvine_tpu.common import errors as err
-from curvine_tpu.obs.trace import Timed
+from curvine_tpu.obs.trace import Timed, current_ctx
 
 log = logging.getLogger(__name__)
 
@@ -147,7 +149,6 @@ async def load_checkpoint(client: CurvineClient, path: str,
     transfer fn), each tensor's host→device transfer is dispatched as
     soon as its bytes land — cache reads overlap device transfers instead
     of the round-2 read-everything-then-transfer-everything sequence."""
-    import asyncio
     async with _restore(client, path):
         manifest, skel, treedef = await _load_manifest(client, path,
                                                        allow_pickle)
@@ -209,7 +210,6 @@ async def load_safetensors(client: CurvineClient, root: str, placer=None,
     ``ckpt.tensor``, attrs shard, offset, bytes, blocks, served_by;
     ``ckpt.place``); then the ready sweep. ``ckpt.bytes`` counts the
     bytes placed, not the shards'."""
-    import asyncio
     c = client.counters
     readers: dict[str, object] = {}
     async with _restore(client, root):
@@ -254,7 +254,6 @@ async def _gather_all(aws) -> list:
     """`asyncio.gather` that lets every awaitable end before the first
     error is raised: nothing of a failed restore is still reading when
     its readers close."""
-    import asyncio
     got = await asyncio.gather(*aws, return_exceptions=True)
     for res in got:
         if isinstance(res, BaseException):
@@ -544,7 +543,6 @@ async def _distribute_sharded(client: CurvineClient, path: str, mesh: Mesh,
     shard only), then the ready sweep. ckpt.bytes counts the checkpoint
     once, ckpt.placed_bytes what all chips together received, from the
     shardings' shard shapes."""
-    import asyncio
     c = client.counters
     async with _restore(client, path):
         manifest, skel, treedef = await _load_manifest(client, path,
@@ -612,34 +610,97 @@ async def _hbm_source(client: CurvineClient, path: str,
     return parts[0] if len(parts) == 1 else np.concatenate(parts)
 
 
+class _Fanout:
+    """Replicated placement onto a fully addressable mesh of more than
+    one device. A tensor crosses the host link once, to the mesh device
+    that has received the fewest host bytes of this restore so far
+    (``place``, timed as ckpt.place: the one-chip path's transfer). The
+    tensors placed within one turn of the loop are then copied chip to
+    chip onto every other device together, by one ``jax.device_put`` of
+    their list onto the replicated sharding, dispatched once a turn on
+    the loop (``flush``: counters ckpt.fanout.s, ckpt.fanout.n — tensors
+    fanned out — and ckpt.fanout.bytes — bytes dispatched chip to chip,
+    (devices − 1) × a tensor's bytes; detail span ``ckpt.fanout``, attrs
+    tensors and bytes, under ``ckpt.restore``). ``place`` hands back a
+    one-element list that ``flush`` fills with the replicated array, and
+    ``results`` reads them out; the source array goes with its last
+    reference (the replicated result holds its buffer as the source
+    device's copy)."""
+
+    def __init__(self, client: CurvineClient, mesh: Mesh):
+        self.client = client
+        self.devices = list(mesh.devices.flat)
+        self.sharding = NamedSharding(mesh, P())
+        self.host_bytes = [0] * len(self.devices)
+        self.parent = current_ctx()
+        self.pending: list[list] = []
+        self.error: Exception | None = None
+
+    def place(self, arr: np.ndarray) -> list:
+        i = self.host_bytes.index(min(self.host_bytes))
+        self.host_bytes[i] += arr.nbytes
+        slot = [jax.device_put(arr, self.devices[i])]
+        if not self.pending:
+            asyncio.get_running_loop().call_soon(self.flush)
+        self.pending.append(slot)
+        return slot
+
+    def flush(self) -> None:
+        slots, self.pending = self.pending, []
+        if not slots:
+            return
+        c = self.client.counters
+        nbytes = (len(self.devices) - 1) * sum(s[0].nbytes for s in slots)
+        t0 = time.perf_counter()
+        try:
+            with self.client.tracer.span(
+                    "ckpt.fanout", parent=self.parent, detail=True,
+                    attrs={"tensors": len(slots), "bytes": nbytes}):
+                out = jax.device_put([s[0] for s in slots], self.sharding)
+        except Exception as e:  # noqa: BLE001 — a loop callback: `results`
+            self.error = e      # raises it in the restore
+            return
+        for slot, arr in zip(slots, out):
+            slot[0] = arr
+        c["ckpt.fanout.s"] = c.get("ckpt.fanout.s", 0.0) + \
+            time.perf_counter() - t0
+        c["ckpt.fanout.n"] = c.get("ckpt.fanout.n", 0) + len(slots)
+        c["ckpt.fanout.bytes"] = c.get("ckpt.fanout.bytes", 0) + nbytes
+
+    def results(self, slots: list) -> list:
+        """The replicated arrays, the last turn's tensors fanned out
+        first; a fan-out that failed fails the restore."""
+        self.flush()
+        if self.error is not None:
+            raise self.error
+        return [slot[0] for slot in slots]
+
+
 async def _distribute_tree(client: CurvineClient, path: str, mesh: Mesh,
                            allow_pickle: bool = False):
-    """Topology-scheduled replicated distribution (docs/ici-plane.md):
+    """Replicated distribution (docs/ici-plane.md):
 
-    * the broadcast plan is derived from the mesh (one reader per host,
-      binomial ICI fan-out after — ici_plane.broadcast_schedule);
-      on a single-host mesh this process is that one reader
     * tensors dispatch in LPT order (largest first) so the longest
-      read→fan-out chains start earliest and the pipeline drains evenly
+      read→place chains start earliest and the pipeline drains evenly
+    * on a fully addressable mesh of more than one device each tensor
+      crosses the host link once, to one chip, and is copied chip to
+      chip onto the others (`_Fanout`); a one-device mesh, or one with
+      devices of other processes, takes one replicated ``device_put``
+      of the host view a tensor
     * tensor bytes come from peer HBM over the device domain when the
       blocks are advertised (zero TCP block reads), with a transparent
       fallback to the mmap/RPC rail
 
-    Bit-exact with the flat path — only the sourcing and order differ."""
-    import asyncio
-    from curvine_tpu.tpu import ici_plane
+    Bit-exact with the flat path — only the sourcing, order and route
+    of the copies differ."""
     async with _restore(client, path):
         manifest, skel, treedef = await _load_manifest(client, path,
                                                        allow_pickle)
         await _prime(client, path, (t["name"] for t in manifest))
         counters = client.counters
-        devs = mesh.devices.reshape(-1)
-        sched = ici_plane.broadcast_schedule(
-            len(devs), coords=[tuple(getattr(d, "coords", None) or (i,))
-                               for i, d in enumerate(devs)])
-        log.debug("broadcast schedule for %s: %d devices, depth %d",
-                  path, len(devs), sched.depth())
         sharding = NamedSharding(mesh, P())
+        fan = _Fanout(client, mesh) if mesh.devices.size > 1 \
+            and sharding.is_fully_addressable else None
         t_read = time.perf_counter()
 
         def place(arr):
@@ -654,8 +715,11 @@ async def _distribute_tree(client: CurvineClient, path: str, mesh: Mesh,
         lpt = sorted(range(len(manifest)),
                      key=lambda i: -size_of(manifest[i]))
         tasks = {i: asyncio.ensure_future(_load_tensor(
-            client, path, manifest[i], place, peer_hbm=True)) for i in lpt}
+            client, path, manifest[i], place if fan is None else fan.place,
+            peer_hbm=True)) for i in lpt}
         flat = [await tasks[i] for i in range(len(manifest))]
+        if fan is not None:
+            flat = fan.results(flat)
         flat = _wait_ready(client, flat)
         counters["ici.broadcast_bytes"] = \
             counters.get("ici.broadcast_bytes", 0) \
@@ -680,10 +744,12 @@ async def distribute_checkpoint(client: CurvineClient, path: str,
     receiving its own shard (_distribute_sharded: load, then place — not
     yet overlapped, and one full host copy).
 
-    ``schedule`` picks the replicated rail: "tree" (default) is the
-    topology-scheduled path — LPT tensor order, peer-HBM device-domain
-    sourcing, binomial fan-out plan; "flat" is the legacy read→put
-    baseline, kept for A/B measurement. Both are bit-exact."""
+    ``schedule`` picks the replicated rail: "tree" (default) is
+    `_distribute_tree` — LPT tensor order, peer-HBM device-domain
+    sourcing, one host transfer a tensor and chip-to-chip copies for
+    the other chips; "flat" is the legacy read→put baseline (a host
+    transfer a tensor a chip), kept for A/B measurement. Both are
+    bit-exact."""
     if spec_tree is None:
         if schedule == "tree":
             return await _distribute_tree(client, path, mesh,

@@ -182,7 +182,7 @@ _ST_DTYPES = {
 
 
 async def load_safetensors(client: CurvineClient, root: str, placer=None,
-                           select=None) -> dict:
+                           select=None, dequant=None) -> dict:
     """A checkpoint in the Hugging Face safetensors layout, as one
     restore: the index ``model.safetensors.index.json`` (``weight_map``:
     tensor name → shard file) beside its shard files, each an 8-byte
@@ -209,14 +209,39 @@ async def load_safetensors(client: CurvineClient, root: str, placer=None,
     many tensors lie in it or cross it), placed as it lands (span
     ``ckpt.tensor``, attrs shard, offset, bytes, blocks, served_by;
     ``ckpt.place``); then the ready sweep. ``ckpt.bytes`` counts the
-    bytes placed, not the shards'."""
+    bytes placed, not the shards'.
+
+    ``dequant`` (a dtype, e.g. ``jnp.bfloat16``; needs a ``placer``)
+    reads a block-scaled float8 checkpoint as DeepSeek-V3 publishes it:
+    every ``F8_E4M3`` tensor ``X.weight`` comes with an ``F32`` tensor
+    ``X.weight_scale_inv`` of ⌈o/b⌉ × ⌈i/b⌉ scales, ``b`` the
+    ``quantization_config.weight_block_size`` of the ``config.json``
+    beside the index (read with it). Both ranges are read and placed
+    as two transfers, a kernel expands them on the weight's device
+    (`pallas_ops.fp8_block_dequant`: span ``ckpt.dequant`` under the
+    weight's ``ckpt.tensor``, attrs name, shape, blocks;
+    ``ckpt.dequant.s`` / ``.n`` its dispatch, ``.bytes_in`` the fp8 and
+    scale bytes, ``.bytes_out`` what it writes) and the fp8 and scale
+    buffers are dropped once it is dispatched. ``X.weight`` is handed
+    back in ``dequant``; the scale is not handed back, and ``select``
+    is asked of weights alone: a weight brings its scale. An fp8 weight
+    without its scale, a scale without its fp8 weight, a scale of
+    another dtype or shape, or an fp8 weight with no block size fails
+    the restore whole, before any tensor is placed. Tensors of other
+    dtypes are placed as they are stored; without ``dequant`` fp8
+    weights and their scales are handed back raw."""
+    if dequant is not None and placer is None:
+        raise ValueError("dequant expands on the device: give a placer")
     c = client.counters
     readers: dict[str, object] = {}
+    block = None
     async with _restore(client, root):
         try:
             with Timed(c, "ckpt.index",
                        client.tracer.span("ckpt.index", detail=True)):
                 weight_map = await _st_index(client, root)
+                if dequant is not None:
+                    block = await _st_block(client, root)
             shards = sorted(set(weight_map.values()))
             await _prime(client, root, shards)
             for shard in shards:
@@ -227,14 +252,28 @@ async def load_safetensors(client: CurvineClient, root: str, placer=None,
                 headers = await _gather_all(
                     _st_header(readers[s], s) for s in shards)
                 where = _st_locate(weight_map, dict(zip(shards, headers)))
-            names = [n for n in weight_map if select is None or select(n)]
-            flat = await _gather_all(
-                _load_range(client, readers[weight_map[n]], weight_map[n],
-                            n, where[n], placer) for n in names)
+                pairs = {} if dequant is None else _st_pairs(
+                    where, block, root)
+            scales = set(pairs.values())
+            names = [n for n in weight_map if n not in scales
+                     and (select is None or select(n))]
+
+            def load(n: str):
+                shard = weight_map[n]
+                if n not in pairs:
+                    return _load_range(client, readers[shard], shard, n,
+                                       where[n], placer)
+                s = pairs[n]
+                return _load_pair(client, readers, weight_map, n, s,
+                                  where[n], where[s], placer, block,
+                                  dequant)
+
+            flat = await _gather_all(load(n) for n in names)
             if placer is not None:
                 flat = _wait_ready(client, flat)
             c["ckpt.bytes"] = c.get("ckpt.bytes", 0) + sum(
-                where[n][3] - where[n][2] for n in names)
+                where[m][3] - where[m][2] for n in names
+                for m in ((n, pairs[n]) if n in pairs else (n,)))
         finally:
             for reader in readers.values():
                 await reader.close()
@@ -242,12 +281,16 @@ async def load_safetensors(client: CurvineClient, root: str, placer=None,
 
 
 async def load_safetensors_to_device(client: CurvineClient, root: str,
-                                     device, select=None) -> dict:
+                                     device, select=None,
+                                     dequant=None) -> dict:
     """`load_safetensors` onto one device: each selected tensor's
-    transfer dispatched as its bytes land."""
+    transfer dispatched as its bytes land (with ``dequant``, each fp8
+    weight expanded there)."""
+    # without dequant, the call a bf16 load has always made
+    extra = {} if dequant is None else {"dequant": dequant}
     return await load_safetensors(
         client, root, placer=lambda a: jax.device_put(a, device),
-        select=select)
+        select=select, **extra)
 
 
 async def _gather_all(aws) -> list:
@@ -357,11 +400,75 @@ def _st_locate(weight_map: dict, headers: dict) -> dict:
     return out
 
 
+SAFETENSORS_CONFIG = "config.json"
+_FP8 = np.dtype(ml_dtypes.float8_e4m3fn)
+_SCALE = "_scale_inv"          # X.weight's scales are X.weight_scale_inv
+
+
+async def _st_block(client: CurvineClient, root: str):
+    """``quantization_config.weight_block_size`` of the ``config.json``
+    beside the index, as (rows, cols), or None where there is none."""
+    path = f"{root}/{SAFETENSORS_CONFIG}"
+    try:
+        raw = json.loads(await _read_all(client, path))
+    except err.FileNotFound:
+        return None
+    q = raw.get("quantization_config") if isinstance(raw, dict) else None
+    b = q.get("weight_block_size") if isinstance(q, dict) else None
+    if b is None:
+        return None
+    if not (isinstance(b, list) and len(b) == 2 and all(
+            isinstance(x, int) and x > 0 for x in b)):
+        raise ValueError(f"{path}: weight_block_size {b!r} is not two "
+                         f"positive integers")
+    return tuple(b)
+
+
+def _st_pairs(where: dict, block, root: str) -> dict:
+    """fp8 weight name → its scale's name, for every ``F8_E4M3`` tensor
+    the index names, each pair checked: the scale there, ``F32``, of
+    ⌈o/b⌉ × ⌈i/b⌉ for a block size there; and no scale without its fp8
+    weight."""
+    pairs = {}
+    for name, (dtype, shape, _, _) in where.items():
+        if name.endswith(_SCALE):
+            w = where.get(name[:-len(_SCALE)])
+            if w is None or w[0] != _FP8:
+                raise ValueError(f"safetensors tensor {name!r}: a scale "
+                                 f"with no F8_E4M3 weight "
+                                 f"{name[:-len(_SCALE)]!r} beside it")
+            continue
+        if dtype != _FP8:
+            continue
+        scale = name + _SCALE
+        if scale not in where:
+            raise ValueError(f"safetensors tensor {name!r}: an F8_E4M3 "
+                             f"weight with no scale {scale!r}")
+        if block is None:
+            raise ValueError(f"safetensors tensor {name!r}: an F8_E4M3 "
+                             f"weight and no quantization_config."
+                             f"weight_block_size in "
+                             f"{root}/{SAFETENSORS_CONFIG}")
+        if len(shape) != 2:
+            raise ValueError(f"safetensors tensor {name!r}: an F8_E4M3 "
+                             f"weight of shape {list(shape)}, not a matrix")
+        want = (-(-shape[0] // block[0]), -(-shape[1] // block[1]))
+        s_dtype, s_shape, _, _ = where[scale]
+        if s_dtype != np.float32 or s_shape != want:
+            raise ValueError(f"safetensors tensor {scale!r}: scales of "
+                             f"{s_dtype} {list(s_shape)} for weight "
+                             f"{name!r} of {list(shape)} in blocks of "
+                             f"{list(block)}; float32 {list(want)} wanted")
+        pairs[name] = scale
+    return pairs
+
+
 async def _load_range(client: CurvineClient, reader, shard: str, name: str,
-                      t: tuple, place):
+                      t: tuple, place, then=None):
     """One tensor as a byte range of its shard, from the cache to where
     ``place`` puts it, under the span ``ckpt.tensor``: a view where the
-    shm rung serves its blocks, else a copy through ``read_range``."""
+    shm rung serves its blocks, else a copy through ``read_range``.
+    ``then(placed)``, where given, is awaited inside the span."""
     dtype, shape, begin, end = t
     with client.tracer.span("ckpt.tensor", attrs={
             "name": name, "shard": shard, "offset": begin},
@@ -372,7 +479,55 @@ async def _load_range(client: CurvineClient, reader, shard: str, name: str,
         sp.set_attr("blocks", reader.blocks_under(begin, end - begin))
         sp.set_attr("served_by", reader.served_by())
         sp.set_attr("bytes", arr.nbytes)
-        return _placed(client, arr, dtype, shape, place)
+        out = _placed(client, arr, dtype, shape, place)
+        if then is not None:
+            out = await then(out)
+        return out
+
+
+async def _load_pair(client: CurvineClient, readers: dict, weight_map: dict,
+                     name: str, scale: str, tw: tuple, ts: tuple, place,
+                     block: tuple, dtype):
+    """An fp8 weight and its scales as two ranges (each shard's own
+    reader), placed as two transfers and expanded where they lie by
+    `_expand`, inside the weight's ``ckpt.tensor``. The weight is placed
+    as its bytes (uint8 codes, which the kernel decodes), the scales
+    flat (it reads them as scalars)."""
+    s_shard = weight_map[scale]
+    scales = asyncio.ensure_future(_load_range(
+        client, readers[s_shard], s_shard, scale,
+        (ts[0], (math.prod(ts[1]),), ts[2], ts[3]), place))
+    tw = (np.dtype(np.uint8),) + tuple(tw[1:])
+
+    async def expand(wq):
+        return _expand(client, name, wq, await scales, block, dtype)
+
+    try:
+        return await _load_range(client, readers[weight_map[name]],
+                                 weight_map[name], name, tw, place, expand)
+    except BaseException:
+        await asyncio.gather(scales, return_exceptions=True)
+        raise
+
+
+def _expand(client: CurvineClient, name: str, wq, scales, block: tuple,
+            dtype):
+    """Dispatch the block dequant of one placed weight (``ckpt.dequant``)
+    and drop its fp8 and scale buffers: the device frees them once the
+    kernel has read them."""
+    from curvine_tpu.tpu.pallas_ops import fp8_block_dequant
+    c = client.counters
+    with Timed(c, "ckpt.dequant", client.tracer.span(
+            "ckpt.dequant", attrs={"name": name, "shape": list(wq.shape),
+                                   "blocks": scales.size}, detail=True)):
+        out = fp8_block_dequant(wq, scales, block, dtype)
+    c["ckpt.dequant.bytes_in"] = c.get("ckpt.dequant.bytes_in", 0) \
+        + wq.nbytes + scales.nbytes
+    c["ckpt.dequant.bytes_out"] = c.get("ckpt.dequant.bytes_out", 0) \
+        + out.nbytes
+    wq.delete()
+    scales.delete()
+    return out
 
 
 @asynccontextmanager
